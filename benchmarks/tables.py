@@ -28,7 +28,6 @@ from repro.models import paper_models as pm
 from repro.optim import optimizers as opt_lib
 
 ROUNDS = {"emnist": 15, "cifar": 4, "so": 25, "dp": 20}
-jax.config.update("jax_platform_name", "cpu")
 
 
 def _img_loss(fwd):

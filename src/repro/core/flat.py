@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops as kernel_ops
+
 # default block size: one f32 (8, 128) TPU tile, and a CPU reduction
 # chunk small enough to vectorize.
 ALIGN = 1024
@@ -176,14 +178,11 @@ def expand_block_mask(mask: jnp.ndarray, align: int = ALIGN) -> jnp.ndarray:
 # on TPU, reshaped pure-JAX fallback (kernels/ref.py) elsewhere.
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def sumsq(vec: jnp.ndarray, align: int = ALIGN) -> jnp.ndarray:
     """Sum of squares of a flat vector (scalar, fp32)."""
     from repro.kernels import ref
-    if _on_tpu() and vec.ndim == 1 and vec.shape[0] % align == 0:
+    if (kernel_ops.use_kernels() and vec.ndim == 1
+            and vec.shape[0] % align == 0):
         from repro.kernels import dp_clip
         return dp_clip.sumsq(vec)
     return ref.flat_sumsq_ref(vec, chunk=align)
@@ -204,9 +203,9 @@ def clip(vec: jnp.ndarray, clip_norm: float,
     """Per-vector L2 clip: vec * min(1, C/||vec||). Returns (clipped,
     pre-clip norm). Fused two-pass kernel on TPU (kernels/dp_clip.py)."""
     align = layout.align if layout is not None else ALIGN
-    if _on_tpu() and vec.shape[0] and vec.shape[0] % align == 0:
-        from repro.kernels import ops
-        return ops.flat_clip(vec, clip_norm)
+    if (kernel_ops.use_kernels() and vec.shape[0]
+            and vec.shape[0] % align == 0):
+        return kernel_ops.flat_clip(vec, clip_norm)
     from repro.kernels import ref
     return ref.flat_clip_ref(vec, clip_norm, chunk=align)
 
@@ -224,12 +223,10 @@ def fake_quantize(mat: jnp.ndarray, layout: FlatLayout, bits: int = 8):
     if squeeze:
         mat = mat[None]
     block_leaf = layout.block_leaf()
-    if _on_tpu() and bits == 8:
-        from repro.kernels import ops
-        out = jax.lax.map(
-            lambda row: ops.fake_quantize_flat(row, block_leaf,
-                                               len(layout.sizes),
-                                               block=layout.align), mat)
+    if kernel_ops.use_kernels() and bits == 8:
+        out = kernel_ops.fake_quantize_flat(mat, block_leaf,
+                                            len(layout.sizes),
+                                            block=layout.align)
     else:
         from repro.kernels import ref
         out = ref.fake_quantize_flat_ref(mat, block_leaf, bits=bits,

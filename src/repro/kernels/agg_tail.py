@@ -19,10 +19,11 @@ reads plus one (size,) write:
    with the pre-drawn (size,) DP noise vector as the accumulator's
    starting value, and one write of the update.
 
-On TPU each stage is a Pallas kernel (grid over align-blocks, same
-layout contract as kernels/quantize.py: leaves own whole blocks, so a
-block never straddles leaves). On CPU each stage is a separately jitted
-wrapper of the `kernels/ref.py` oracle, orchestrated from Python:
+On TPU each stage is a Pallas kernel (grid over (8 rows, 128 blocks)
+tiles, same layout contract as kernels/quantize.py: leaves own whole
+blocks, so a block never straddles leaves). On CPU each stage is a
+separately jitted wrapper of the `kernels/ref.py` oracle, orchestrated
+from Python:
 composing the stages into ONE XLA:CPU program costs +300-650ms at 10M
 params x 16 clients (the fusion pass re-materializes producers across
 stage boundaries), so the concrete-buffer path deliberately keeps the
@@ -71,57 +72,71 @@ from repro.kernels import ref
 
 BLOCK = 1024  # one f32 (8, 128) TPU tile; must equal the layout's align
 
-
 # ---------------------------------------------------------------------------
-# Pallas TPU kernels. Grid over align-blocks, one (K, block) tile per step;
-# the sequential TPU grid makes SMEM scratch accumulation race-free (same
-# trick as quantize.py / dp_clip.py).
+# Pallas TPU kernels over the (K, NB, block) view of the buffer. One grid
+# step holds up to 8 rows x 128 align-blocks, so every per-(row, block)
+# result is an (8, 128) lane-dense tile of a (K, NB) array: the TPU
+# lowering wants the last two block dims divisible by (8, 128) or equal
+# to the whole array's, which a (K, 1) per-block column is not.
+_ROWS = 8
+_BLOCKS = 128
+
+
+def tile(K: int, nb: int):
+    """(rows, blocks) of one grid step for a (K, nb, block) buffer: whole
+    dims when smaller than a tile. Ragged edge tiles read padding that
+    only ever lands in masked-out output positions."""
+    return min(K, _ROWS), min(nb, _BLOCKS)
 
 
 def _stats_kernel(x_ref, bmax_ref, bsumsq_ref):
+    x = x_ref[...].astype(jnp.float32)           # (rows, blocks, block)
+    bmax_ref[...] = jnp.max(jnp.abs(x), axis=-1)
+    bsumsq_ref[...] = jnp.sum(x * x, axis=-1)
+
+
+def _pack_kernel(x_ref, s_ref, q_ref, qsq_ref, *, qmax: float):
     x = x_ref[...].astype(jnp.float32)
-    bmax_ref[...] = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    bsumsq_ref[...] = jnp.sum(x * x, axis=-1, keepdims=True)
+    q = jnp.clip(jnp.round(x / s_ref[...][:, :, None]), -qmax, qmax)
+    q_ref[...] = q.astype(jnp.int8)
+    # per-block sum of squared codes: integers below 2^24, exact in f32
+    qsq_ref[...] = jnp.sum(q * q, axis=-1)
 
 
-def _pack_kernel(x_ref, s_ref, q_ref, qss_ref, acc_ref, *, qmax: float):
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
+def _apply_kernel(q_ref, c_ref, noise_ref, o_ref, *, rows: int):
+    r = pl.program_id(1)
 
-    @pl.when(i == 0)
+    @pl.when(r == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_ref[...] = noise_ref[...]
 
-    x = x_ref[...].astype(jnp.float32)
-    s = s_ref[...]                                       # (K, 1)
-    q = jnp.clip(jnp.round(x / s), -qmax, qmax)
-    q_ref[...] = q.astype(jnp.int8)[:, None]
-    acc_ref[...] += jnp.sum(q * q, axis=-1) * (s[:, 0] * s[:, 0])
-
-    @pl.when(i == n - 1)
-    def _out():
-        qss_ref[...] = acc_ref[...]
-
-
-def _apply_kernel(q_ref, a_ref, noise_ref, o_ref):
-    qf = q_ref[...][:, 0].astype(jnp.float32)            # (K, block)
-    o_ref[...] = noise_ref[...] + jnp.sum(qf * a_ref[...], axis=0)
+    rb = q_ref.shape[0]
+    c = c_ref[...]                                       # (rows, blocks)
+    acc = o_ref[...]
+    for k in range(rb):          # row order = agg_apply_ref's order
+        part = q_ref[k].astype(jnp.float32) * c[k][:, None]
+        if rows % rb:            # ragged last row tile: padding rows
+            part = jnp.where(r * rb + k < rows, part, 0.0)
+        acc = acc + part
+    o_ref[...] = acc
 
 
 def block_stats(mat, block: int = BLOCK, interpret: bool = False):
     """(K, N) -> per-(row, block) (max-abs, sumsq), one HBM read."""
     K, N = mat.shape
     nb = N // block
+    rb, gb = tile(K, nb)
+    spec = pl.BlockSpec((rb, gb), lambda r, j: (r, j))
     return pl.pallas_call(
         _stats_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((K, block), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((K, 1), lambda i: (0, i)),
-                   pl.BlockSpec((K, 1), lambda i: (0, i))],
+        name="agg_tail_stats",
+        grid=(pl.cdiv(K, rb), pl.cdiv(nb, gb)),
+        in_specs=[pl.BlockSpec((rb, gb, block), lambda r, j: (r, j, 0))],
+        out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((K, nb), jnp.float32),
                    jax.ShapeDtypeStruct((K, nb), jnp.float32)],
         interpret=interpret,
-    )(mat)
+    )(mat.reshape(K, nb, block))
 
 
 def pack(mat, sblock, bits: int = 8, block: int = BLOCK,
@@ -131,36 +146,43 @@ def pack(mat, sblock, bits: int = 8, block: int = BLOCK,
     qmax = 2.0 ** (bits - 1) - 1
     K, N = mat.shape
     nb = N // block
-    q, qss = pl.pallas_call(
+    rb, gb = tile(K, nb)
+    spec = pl.BlockSpec((rb, gb), lambda r, j: (r, j))
+    spec3 = pl.BlockSpec((rb, gb, block), lambda r, j: (r, j, 0))
+    q, qsq = pl.pallas_call(
         functools.partial(_pack_kernel, qmax=qmax),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((K, block), lambda i: (0, i)),
-                  pl.BlockSpec((K, 1), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((K, 1, block), lambda i: (0, i, 0)),
-                   pl.BlockSpec(memory_space=pltpu.SMEM)],
+        name="agg_tail_pack",
+        grid=(pl.cdiv(K, rb), pl.cdiv(nb, gb)),
+        in_specs=[spec3, spec],
+        out_specs=[spec3, spec],
         out_shape=[jax.ShapeDtypeStruct((K, nb, block), jnp.int8),
-                   jax.ShapeDtypeStruct((K,), jnp.float32)],
-        scratch_shapes=[pltpu.SMEM((K,), jnp.float32)],
+                   jax.ShapeDtypeStruct((K, nb), jnp.float32)],
         interpret=interpret,
-    )(mat, sblock)
-    return q, qss
+    )(mat.reshape(K, nb, block), sblock)
+    return q, ref.quant_sumsq_fold(qsq, sblock)
 
 
 def apply_coeff(q, coeff, noise, block: int = BLOCK,
                 interpret: bool = False):
     """(K, NB, block) codes x (K, NB) coefficients -> (N,), starting the
-    accumulator from ``noise`` — one codes read, one update write."""
+    accumulator from ``noise`` — one codes read, one update write. The
+    row tiles are the grid's inner (accumulating) axis."""
     K, nb, _ = q.shape
-    return pl.pallas_call(
-        _apply_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((K, 1, block), lambda i: (0, i, 0)),
-                  pl.BlockSpec((K, 1), lambda i: (0, i)),
-                  pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb * block,), jnp.float32),
+    rb, gb = tile(K, nb)
+    out = pl.pallas_call(
+        functools.partial(_apply_kernel, rows=K),
+        name="agg_tail_apply",
+        grid=(pl.cdiv(nb, gb), pl.cdiv(K, rb)),
+        in_specs=[pl.BlockSpec((rb, gb, block), lambda j, r: (r, j, 0)),
+                  pl.BlockSpec((rb, gb), lambda j, r: (r, j)),
+                  pl.BlockSpec((gb, block), lambda j, r: (j, 0))],
+        out_specs=pl.BlockSpec((gb, block), lambda j, r: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q.reshape(K, nb, block), coeff, noise)
+    )(q, coeff, noise.reshape(nb, block))
+    return out.reshape(-1)
 
 
 # ---------------------------------------------------------------------------

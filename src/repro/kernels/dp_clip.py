@@ -6,13 +6,12 @@ min(1, C/‖Δ_i‖), and accumulate into the aggregation buffer. Done naively
 this is 3 HBM sweeps (square-reduce, scale, add); the kernel pair fuses
 it into 2: a block-tiled sum-of-squares reduction, then a single
 read-modify-write pass `acc += x * scale` with the scalar prefetched to
-SMEM. The norm reduction accumulates across the 1-D block grid in an
-SMEM scratch cell (TPU grid iterations are sequential, so scratch
-accumulation is race-free).
+SMEM. The norm reduction accumulates across the block grid into one
+resident (8, 128) VMEM tile (TPU grid iterations are sequential, so the
+accumulation is race-free). Vectors are viewed as (rows, 128) tiles, so
+both kernels also lower when vmapped over clients.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,22 +19,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 8 * 128 * 32  # 32768 f32 elements = 128 KiB per tile
+_LANES = 128
+_ROWS = BLOCK // _LANES
 
 
-def _sumsq_kernel(x_ref, o_ref, acc_ref):
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
-
-    @pl.when(i == 0)
+def _sumsq_kernel(x_ref, o_ref):
+    # one resident (8, 128) partial-sum tile across the sequential grid;
+    # summed to a scalar outside. No scalar store, so the kernel batches
+    # (vmap adds a grid axis) like any tiled kernel.
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_ref[0] = jnp.zeros((), jnp.float32)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    acc_ref[0] = acc_ref[0] + jnp.sum(x * x)
-
-    @pl.when(i == n - 1)
-    def _out():
-        o_ref[0] = acc_ref[0]
+    o_ref[...] += jnp.sum((x * x).reshape(-1, 8, _LANES), axis=0)
 
 
 def _scale_add_kernel(scale_ref, x_ref, acc_ref, o_ref):
@@ -43,28 +40,33 @@ def _scale_add_kernel(scale_ref, x_ref, acc_ref, o_ref):
     o_ref[...] = acc_ref[...] + x_ref[...].astype(jnp.float32) * scale_ref[0]
 
 
-def _pad_to_block(x, block):
+def _tiles(x, block):
+    """(N,) -> zero-padded (rows, 128) view, rows a multiple of the
+    block's row count: lane-dense tiles that stay tiled under vmap."""
     n = x.shape[0]
     npad = (n + block - 1) // block * block - n
     if npad:
         x = jnp.pad(x, (0, npad))
-    return x
+    return x.reshape(-1, _LANES)
+
+
+def _row_spec(block):
+    return pl.BlockSpec((block // _LANES, _LANES), lambda i, *_: (i, 0))
 
 
 def sumsq(x, block: int = BLOCK, interpret: bool = False):
     """Sum of squares of a 1-D vector via a grid-accumulated reduction."""
-    xp = _pad_to_block(x, block)
-    grid = (xp.shape[0] // block,)
-    out = pl.pallas_call(
+    xt = _tiles(x, block)
+    part = pl.pallas_call(
         _sumsq_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
+        name="dp_clip_sumsq",
+        grid=(xt.shape[0] * _LANES // block,),
+        in_specs=[_row_spec(block)],
+        out_specs=pl.BlockSpec((8, _LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((8, _LANES), jnp.float32),
         interpret=interpret,
-    )(xp)
-    return out[0]
+    )(xt)
+    return jnp.sum(part)
 
 
 def _scale_kernel(scale_ref, x_ref, o_ref):
@@ -80,20 +82,20 @@ def clip_flat(x, clip_norm: float, block: int = BLOCK,
     n = x.shape[0]
     nrm = jnp.sqrt(sumsq(x, block=block, interpret=interpret))
     scale = jnp.minimum(1.0, clip_norm / jnp.maximum(nrm, 1e-12))
-    xp = _pad_to_block(x, block)
-    grid = (xp.shape[0] // block,)
+    xt = _tiles(x, block)
     out = pl.pallas_call(
         _scale_kernel,
+        name="dp_clip_scale",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((block,), lambda i, s: (i,))],
-            out_specs=pl.BlockSpec((block,), lambda i, s: (i,)),
+            grid=(xt.shape[0] * _LANES // block,),
+            in_specs=[_row_spec(block)],
+            out_specs=_row_spec(block),
         ),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[0],), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(xt.shape, jnp.float32),
         interpret=interpret,
-    )(scale.reshape(1), xp)
-    return out[:n], nrm
+    )(scale.reshape(1), xt)
+    return out.reshape(-1)[:n], nrm
 
 
 def clip_accumulate(acc, x, clip_norm: float, block: int = BLOCK,
@@ -103,22 +105,19 @@ def clip_accumulate(acc, x, clip_norm: float, block: int = BLOCK,
     Returns (new_acc, norm). Two fused HBM passes instead of three.
     """
     n = x.shape[0]
-    ss = sumsq(x, block=block, interpret=interpret)
-    nrm = jnp.sqrt(ss)
+    nrm = jnp.sqrt(sumsq(x, block=block, interpret=interpret))
     scale = jnp.minimum(1.0, clip_norm / jnp.maximum(nrm, 1e-12))
-    xp = _pad_to_block(x, block)
-    ap = _pad_to_block(acc.astype(jnp.float32), block)
-    grid = (xp.shape[0] // block,)
+    xt = _tiles(x, block)
     out = pl.pallas_call(
-        functools.partial(_scale_add_kernel),
+        _scale_add_kernel,
+        name="dp_clip_scale_add",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((block,), lambda i, s: (i,)),
-                      pl.BlockSpec((block,), lambda i, s: (i,))],
-            out_specs=pl.BlockSpec((block,), lambda i, s: (i,)),
+            grid=(xt.shape[0] * _LANES // block,),
+            in_specs=[_row_spec(block), _row_spec(block)],
+            out_specs=_row_spec(block),
         ),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[0],), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(xt.shape, jnp.float32),
         interpret=interpret,
-    )(scale.reshape(1), xp, ap)
-    return out[:n], nrm
+    )(scale.reshape(1), xt, _tiles(acc.astype(jnp.float32), block))
+    return out.reshape(-1)[:n], nrm

@@ -1,9 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On this CPU container the wrappers run with interpret=True (the kernel
-body executes in Python under the Pallas interpreter); on TPU they lower
-to Mosaic. `use_pallas` flags let the model code swap the pure-jnp path
-for the kernel path at config time.
+Each wrapper decides when it is traced (:func:`use_kernels`): on a TPU
+the kernels lower to Mosaic; elsewhere the dispatchers take the pure-jnp
+refs, and the kernels that have no ref path run under the Pallas
+interpreter.
 """
 from __future__ import annotations
 
@@ -19,8 +19,17 @@ from repro.kernels import ref as _ref
 from repro.kernels import seed_reconstruct as _sr
 from repro.kernels import swa_attention as _swa
 
-_ON_TPU = jax.default_backend() == "tpu"
-_INTERPRET = not _ON_TPU
+
+def use_kernels() -> bool:
+    """True when the trace in progress lowers for ONE TPU device.
+
+    Mosaic kernels are not auto-partitioned, so under an ambient mesh of
+    several devices (``jax.set_mesh``, which the grid enters for
+    ``GridConfig.mesh``) the dispatchers take the jnp refs, which GSPMD
+    partitions like any other op."""
+    return (jax.default_backend() == "tpu"
+            and jax.sharding.get_abstract_mesh().size <= 1)
+
 
 # agg_tail dispatcher: the fused stats/pack/apply path engages by
 # default only when BOTH hold —
@@ -45,20 +54,21 @@ def swa_attention(q, k, v, window: int = 0, causal: bool = True,
                   bq: int = 128, bk: int = 128):
     """(B, H, S, D) sliding-window flash attention (see swa_attention.py)."""
     return _swa.swa_attention(q, k, v, window=window, causal=causal,
-                              bq=bq, bk=bk, interpret=_INTERPRET)
+                              bq=bq, bk=bk, interpret=not use_kernels())
 
 
 @functools.partial(jax.jit, static_argnames=("clip_norm",))
 def clip_accumulate(acc, x, clip_norm: float):
     """Fused DP clip-and-accumulate over flat f32 vectors."""
-    return _dp.clip_accumulate(acc, x, clip_norm, interpret=_INTERPRET)
+    return _dp.clip_accumulate(acc, x, clip_norm,
+                               interpret=not use_kernels())
 
 
 @functools.partial(jax.jit, static_argnames=("clip_norm",))
 def flat_clip(x, clip_norm: float):
     """Per-vector L2 clip over a flat f32 delta: (clipped, pre-clip
     norm). Fused two-pass kernel on TPU, reshaped pure-jnp elsewhere."""
-    if _ON_TPU:
+    if use_kernels():
         return _dp.clip_flat(x, clip_norm)
     return _ref.flat_clip_ref(x, clip_norm)
 
@@ -66,9 +76,10 @@ def flat_clip(x, clip_norm: float):
 @functools.partial(jax.jit, static_argnames=("n_leaves", "bits", "block"))
 def fake_quantize_flat(x, block_leaf, n_leaves: int = 0, bits: int = 8,
                        block: int = _q.BLOCK):
-    """Fused per-leaf int8 fake-quantize of a block-aligned flat delta
-    (see quantize.py). Kernel on TPU, segment-reduction ref elsewhere."""
-    if _ON_TPU:
+    """Fused per-leaf int8 fake-quantize of block-aligned flat deltas,
+    (N,) or (K, N) (see quantize.py). Kernel on TPU, segment-reduction
+    ref elsewhere."""
+    if use_kernels():
         return _q.fake_quantize_flat(x, block_leaf, n_leaves, bits=bits,
                                      block=block)
     return _ref.fake_quantize_flat_ref(x, block_leaf, bits=bits, block=block,
@@ -81,7 +92,7 @@ def seed_reconstruct(seed, leaf_id: int, shape, stddev: float,
                      dtype=jnp.float32):
     """Deterministic on-chip Gaussian tensor from (seed, leaf_id)."""
     return _sr.seed_reconstruct(seed, leaf_id, shape, stddev, dtype=dtype,
-                                interpret=_INTERPRET)
+                                interpret=not use_kernels())
 
 
 # ---------------------------------------------------------------------------
@@ -91,10 +102,8 @@ def seed_reconstruct(seed, leaf_id: int, shape, stddev: float,
 
 def _fake_quantize(mat, block_leaf, n_leaves, bits, align):
     # same dispatch as core.flat.fake_quantize, without needing a layout
-    if _ON_TPU and bits == 8:
-        return jax.lax.map(
-            lambda row: fake_quantize_flat(row, block_leaf, n_leaves,
-                                           block=align), mat)
+    if use_kernels() and bits == 8:
+        return _q.fake_quantize_flat(mat, block_leaf, n_leaves, block=align)
     return _ref.fake_quantize_flat_ref(mat, block_leaf, bits=bits,
                                        block=align, n_leaves=n_leaves)
 
@@ -147,6 +156,16 @@ _staged_tail_jit = jax.jit(
                      "screen"))
 
 
+def agg_route(K: int, size: int, bits: int, threshold=None) -> str:
+    """The route :func:`agg_tail` takes for a (K, size) buffer:
+    ``"fused"`` or ``"staged"`` (see the dispatch rule there)."""
+    if threshold is None:
+        fuse = bits > 0 and K * size >= AGG_FUSE_THRESHOLD
+    else:
+        fuse = K * size >= threshold
+    return "fused" if fuse else "staged"
+
+
 def agg_tail(mat, weights, *, block_leaf, n_leaves: int, align: int = 1024,
              bits: int = 0, clip_norm: float = 0.0, uniform: bool = False,
              wsum_fixed=None, sigma: float = 0.0, rng=None, bmask=None,
@@ -179,11 +198,7 @@ def agg_tail(mat, weights, *, block_leaf, n_leaves: int, align: int = 1024,
               remask_rows=remask_rows, screen=screen)
     K, size = mat.shape
     traced = isinstance(mat, jax.core.Tracer)
-    if threshold is None:
-        fuse = bits > 0 and K * size >= AGG_FUSE_THRESHOLD
-    else:
-        fuse = K * size >= threshold
-    if not fuse:
+    if agg_route(K, size, bits, threshold) == "staged":
         if traced or constrain_fn is not None:
             out, info = _staged_tail(mat, weights, block_leaf, bmask, rng,
                                      constrain_fn=constrain_fn, **kw)
@@ -193,7 +208,7 @@ def agg_tail(mat, weights, *, block_leaf, n_leaves: int, align: int = 1024,
                                          bmask, rng, **kw)
         info["route"] = "staged"
         return out, info
-    if _ON_TPU:
+    if use_kernels():
         engine = "tpu"
     elif traced:
         engine = "ref"

@@ -1,19 +1,18 @@
 """Fused per-leaf int8 fake-quantize — Pallas TPU kernel pair.
 
-The lossy-uplink hot-spot: quantize a client's flat trainable delta
-(one contiguous fp32 vector, block-aligned per leaf by
-``core.flat.FlatLayout``) with a symmetric per-leaf scale, then
-dequantize in place — the in-graph Q->DQ the round engine applies when
-``RoundConfig.uplink_bits > 0``. Done per leaf with tree ops this is
-2 sweeps *per leaf* plus a dispatch per leaf; the kernel pair fuses it
-into 2 total HBM sweeps over the whole buffer:
+The lossy-uplink hot-spot: quantize flat trainable deltas (one
+contiguous fp32 vector per client, block-aligned per leaf by
+``core.flat.FlatLayout``) with a symmetric per-(client, leaf) scale,
+then dequantize in place — the in-graph Q->DQ the round engine applies
+when ``RoundConfig.uplink_bits > 0``. Done per leaf with tree ops this
+is 2 sweeps *per leaf* plus a dispatch per leaf; the kernel pair fuses
+it into 2 total HBM sweeps over the whole buffer:
 
-1. a block-tiled max-abs reduction accumulating per-LEAF maxima in an
-   SMEM scratch vector, routed by a scalar-prefetched block->leaf map
-   (TPU grid iterations are sequential, so scratch accumulation is
-   race-free — same trick as dp_clip.py's sum-of-squares);
-2. a single read-modify-write pass ``round/clip/rescale`` with the
-   per-leaf scales prefetched to SMEM and indexed by the same map.
+1. the aggregation tail's block-stats kernel (``agg_tail.block_stats``):
+   per-(row, block) max-abs as lane-dense tiles, then a tiny segment-max
+   over the static block->leaf map gives each block its leaf's scale;
+2. a single read-modify-write pass ``round/clip/rescale`` with each
+   block's scale broadcast from a lane-dense (rows, blocks) tile.
 
 Because each leaf is padded to a whole number of blocks, a block never
 straddles leaves and the zero padding can never raise a leaf's max.
@@ -27,76 +26,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import agg_tail
+from repro.kernels import ref
 
 BLOCK = 1024  # one f32 (8, 128) tile; must equal the layout's `align`
 
 
-def _maxabs_kernel(block_leaf_ref, x_ref, o_ref, acc_ref):
-    i = pl.program_id(0)
-    n = pl.num_programs(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    lid = block_leaf_ref[i]
-    m = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)))
-    acc_ref[lid] = jnp.maximum(acc_ref[lid], m)
-
-    @pl.when(i == n - 1)
-    def _out():
-        o_ref[...] = acc_ref[...]
-
-
-def _qdq_kernel(block_leaf_ref, scales_ref, x_ref, o_ref, *, qmax: float):
-    s = scales_ref[block_leaf_ref[pl.program_id(0)]]
+def _qdq_kernel(x_ref, s_ref, o_ref, *, qmax: float):
+    s = s_ref[...][:, :, None]
     q = jnp.clip(jnp.round(x_ref[...].astype(jnp.float32) / s), -qmax, qmax)
     o_ref[...] = q * s
 
 
-def leaf_maxabs(x, block_leaf, n_leaves: int, block: int = BLOCK,
-                interpret: bool = False):
-    """Per-leaf max-abs of a block-aligned flat vector.
-
-    x: (N,) with N == len(block_leaf) * block; block_leaf: (K,) int32
-    mapping each block to its leaf. Returns (n_leaves,) f32.
-    """
-    grid = (x.shape[0] // block,)
-    return pl.pallas_call(
-        _maxabs_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((block,), lambda i, m: (i,))],
-            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-            scratch_shapes=[pltpu.SMEM((n_leaves,), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_leaves,), jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(block_leaf, jnp.int32), x)
-
-
 def fake_quantize_flat(x, block_leaf, n_leaves: int, bits: int = 8,
                        block: int = BLOCK, interpret: bool = False):
-    """Fused Q->DQ of a flat client delta with per-leaf symmetric scales.
+    """Fused Q->DQ of flat client deltas with per-leaf symmetric scales.
 
-    Semantics match `compress.fake_quantize_tree` on the unflattened
-    tree. Two HBM sweeps total, independent of the leaf count.
+    ``x`` is (N,) or (K, N) with N == len(block_leaf) * block; every row
+    gets its own per-leaf scales. Semantics match
+    `compress.fake_quantize_tree` on the unflattened tree. Two HBM sweeps
+    total, independent of the leaf count.
     """
     qmax = 2.0 ** (bits - 1) - 1
-    maxima = leaf_maxabs(x, block_leaf, n_leaves, block=block,
-                         interpret=interpret)
-    scales = jnp.maximum(maxima, 1e-12) / qmax
-    grid = (x.shape[0] // block,)
-    return pl.pallas_call(
+    mat = x.reshape(-1, x.shape[-1])
+    K, N = mat.shape
+    nb = N // block
+    bmax, _ = agg_tail.block_stats(mat, block=block, interpret=interpret)
+    sblock = ref.agg_scales_ref(bmax, block_leaf, bits, n_leaves)
+    rb, gb = agg_tail.tile(K, nb)
+    spec3 = pl.BlockSpec((rb, gb, block), lambda r, j: (r, j, 0))
+    out = pl.pallas_call(
         functools.partial(_qdq_kernel, qmax=qmax),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[pl.BlockSpec((block,), lambda i, m, s: (i,))],
-            out_specs=pl.BlockSpec((block,), lambda i, m, s: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
+        name="quantize_qdq",
+        grid=(pl.cdiv(K, rb), pl.cdiv(nb, gb)),
+        in_specs=[spec3, pl.BlockSpec((rb, gb), lambda r, j: (r, j))],
+        out_specs=spec3,
+        out_shape=jax.ShapeDtypeStruct((K, nb, block), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(block_leaf, jnp.int32), scales, x)
+    )(mat.reshape(K, nb, block), sblock)
+    return out.reshape(x.shape)

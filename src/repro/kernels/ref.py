@@ -271,8 +271,14 @@ def agg_quant_sumsq_ref(q, sblock):
     sum(q_b^2). Equal in value to row_sumsq of the dequantized buffer
     (fp-round-off: the per-block scale factors out of the block sum), at
     int8 read cost instead of another f32 sweep."""
-    return jnp.einsum("kb,kb->k", _last_axis_sumsq(q),
-                      sblock.astype(jnp.float32) ** 2)
+    return quant_sumsq_fold(_last_axis_sumsq(q), sblock)
+
+
+def quant_sumsq_fold(qsq, sblock):
+    """(K, NB) per-block sums of squared codes -> (K,) quantized row
+    sumsq. The block sums are exact integers in f32, so the Pallas pack
+    and this oracle agree bitwise through this shared fold."""
+    return jnp.einsum("kb,kb->k", qsq, sblock.astype(jnp.float32) ** 2)
 
 
 def agg_apply_ref(q, coeff, noise=None, block: int = 1024):

@@ -48,9 +48,12 @@ def _squirrel3(n, seed):
 
 
 def _uniform(bits):
-    """uint32 -> (0, 1): top 24 bits as mantissa, offset by half an ulp."""
-    return (jnp.right_shift(bits, jnp.uint32(8)).astype(jnp.float32)
-            + 0.5) * (1.0 / 16777216.0)
+    """uint32 -> (0, 1): top 24 bits as mantissa, offset by half an ulp.
+    The shifted value fits in int32, and the TPU lowering converts only
+    signed integers to float, so it goes through an int32 bitcast."""
+    top = jax.lax.bitcast_convert_type(jnp.right_shift(bits, jnp.uint32(8)),
+                                       jnp.int32)
+    return (top.astype(jnp.float32) + 0.5) * (1.0 / 16777216.0)
 
 
 def _seed_kernel(seed_ref, o_ref, *, stddev: float, rows: int, cols: int,
@@ -99,6 +102,7 @@ def seed_reconstruct(seed, leaf_id: int, shape, stddev: float,
     out = pl.pallas_call(
         functools.partial(_seed_kernel, stddev=float(stddev), rows=rows,
                           cols=cols, block_rows=br),
+        name="seed_reconstruct",
         grid=(nblocks,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((br, cpad), lambda i: (i, 0)),

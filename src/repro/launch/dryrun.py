@@ -89,13 +89,10 @@ def run_one(arch: str, shape: str, multi_pod: bool = False,
     if mesh is None:
         mesh = mesh_lib.make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    # jax.set_mesh only exists on newer jax; entering the Mesh object is
-    # the 0.4.x-compatible way to make it the ambient mesh
-    set_mesh = getattr(jax, "set_mesh", None) or (lambda m: m)
     try:
         job = specs_lib.build_job(arch, shape, mesh,
                                   cfg_override=cfg_override)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(job.fn, in_shardings=job.in_shardings)
             lowered = jitted.lower(*job.args)
             t_lower = time.time() - t0
@@ -103,9 +100,6 @@ def run_one(arch: str, shape: str, multi_pod: bool = False,
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            # older jax returns a one-element list of the per-device dict
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
         coll = collective_stats(hlo)
         res = {

@@ -14,7 +14,7 @@ from __future__ import annotations
 import math
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
 HW = {
     # TPU v5e per-chip constants used by the roofline (benchmarks/roofline.py)
@@ -23,6 +23,14 @@ HW = {
     "ici_bw": 50e9,              # B/s per link
     "hbm_bytes": 16 * 1024 ** 3,
 }
+
+
+def _mesh(shape, axes, devices):
+    """Every axis Auto: GSPMD propagates shardings from the explicit
+    constraints in launch/sharding.py, which only Auto axes accept
+    (``jax.make_mesh`` defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -34,19 +42,18 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devs)}; run via "
             "launch/dryrun.py which forces 512 host devices")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return _mesh(shape, axes, devs[:n])
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for tests (requires forced host device count >= prod)."""
     n = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _mesh(shape, axes, jax.devices()[:n])
 
 
 def make_single_device_mesh():
     """1x1 mesh so smoke tests exercise the pjit path on one CPU device."""
-    return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    return _mesh((1, 1), ("data", "model"), jax.devices()[:1])
 
 
 def data_axes(mesh) -> tuple:

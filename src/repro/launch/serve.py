@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import load_all
 from repro.configs.base import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import decoder_lm as dlm
 
 
@@ -56,6 +57,7 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     load_all()
     cfg = reduced_config(get_config(args.arch))

@@ -25,6 +25,7 @@ from repro.configs.base import get_config
 from repro.core import fedpt
 from repro.data import synthetic as syn
 from repro.fl import runtime
+from repro.launch.cache import enable_compile_cache
 from repro.models import decoder_lm as dlm
 from repro.models import paper_models as pm
 
@@ -61,7 +62,7 @@ def reduced_config(cfg, max_layers: int = 2, d_model: int = 256,
     )
 
 
-def run_paper_task(task: str, rounds: int, fully_trainable: bool,
+def run_paper_task(task: str, rounds: int, fully_trainable: bool = False,
                    seed: int = 0, log: bool = True):
     if task == "emnist":
         ds = syn.make_federated_images(60, 60, (28, 28, 1), 62, seed=seed)
@@ -140,6 +141,7 @@ def main(argv=None):
     ap.add_argument("--fully-trainable", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.task:
         res = run_paper_task(args.task, args.rounds, args.fully_trainable,

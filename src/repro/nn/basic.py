@@ -213,38 +213,35 @@ def mlp(x, p, act: str, compute_dtype):
 
 
 def maybe_constrain(x, spec):
-    """Best-effort GSPMD sharding constraint.
+    """GSPMD sharding hint against the ambient mesh.
 
     Filters the spec per-dimension: an axis that is absent from the
     ambient mesh, or that does not divide the dimension, degrades to None
-    for THAT dim only (instead of dropping the whole constraint — see
-    EXPERIMENTS.md §Perf H2/H3 iteration-1 lesson). No-ops entirely when
-    no ambient mesh is set (single-device smoke tests).
+    for THAT dim only (instead of dropping the whole constraint). No-ops
+    when no ambient mesh is set (single-device runs); any other failure
+    raises, so a broken mesh never silently runs unsharded.
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-        sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
-        filt = []
-        for d, ax in enumerate(spec):
-            if ax is None:
-                filt.append(None)
-                continue
-            axes = ax if isinstance(ax, tuple) else (ax,)
-            # keep the subset of axes that exist on the ambient mesh
-            present = tuple(a for a in axes if a in sizes)
-            total = 1
-            for a in present:
-                total *= sizes[a]
-            if present and d < x.ndim and x.shape[d] % total == 0 \
-                    and x.shape[d] >= total:
-                filt.append(present if len(present) > 1 else present[0])
-            else:
-                filt.append(None)
-        if all(f is None for f in filt):
-            return x
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*filt))
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
+    filt = []
+    for d, ax in enumerate(spec):
+        if ax is None:
+            filt.append(None)
+            continue
+        axes = ax if isinstance(ax, tuple) else (ax,)
+        # keep the subset of axes that exist on the ambient mesh
+        present = tuple(a for a in axes if a in sizes)
+        total = 1
+        for a in present:
+            total *= sizes[a]
+        if present and d < x.ndim and x.shape[d] % total == 0 \
+                and x.shape[d] >= total:
+            filt.append(present if len(present) > 1 else present[0])
+        else:
+            filt.append(None)
+    if all(f is None for f in filt):
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*filt))

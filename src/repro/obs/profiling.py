@@ -10,36 +10,23 @@ profile captured around the run (``jax.profiler.trace(...)`` or
 ``grid/server_apply`` blocks that line up with the virtual-time flush
 spans one-to-one.
 
-Everything degrades to a plain call when profiling is off or the
-installed jax lacks ``TraceAnnotation`` — the wrapper adds one function
-frame, never a device sync.
+Everything degrades to a plain call when profiling is off — the wrapper
+adds one function frame, never a device sync.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
-from typing import Callable, Optional
+from typing import Callable
 
-try:  # jax >= 0.3; absent under exotic stubs — degrade to no-op
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - depends on the installed jax
-    _TraceAnnotation = None
-
-
-def annotation(name: str):
-    """Context manager marking a named region in the jax profiler
-    timeline (no-op when TraceAnnotation is unavailable)."""
-    if _TraceAnnotation is None:  # pragma: no cover
-        return contextlib.nullcontext()
-    return _TraceAnnotation(name)
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 
 def annotate(fn: Callable, name: str,
              enabled: bool = True) -> Callable:
-    """Wrap ``fn`` so each call runs inside ``annotation(name)``.
+    """Wrap ``fn`` so each call runs inside a ``TraceAnnotation(name)``.
     With ``enabled=False`` (telemetry off, or profile not requested)
     returns ``fn`` unchanged — zero added frames on the default path."""
-    if not enabled or _TraceAnnotation is None:
+    if not enabled:
         return fn
 
     @functools.wraps(fn)
@@ -56,16 +43,3 @@ def annotate_map(fns: dict, name: str, enabled: bool = True) -> dict:
     if not enabled:
         return fns
     return {k: annotate(fn, f"{name}[{k}]") for k, fn in fns.items()}
-
-
-def capture(path: Optional[str]):
-    """Context manager: capture a jax wall-time profile into ``path``
-    (a TensorBoard logdir) for the enclosed block; no-op when ``path``
-    is None or the profiler is unavailable."""
-    if path is None:
-        return contextlib.nullcontext()
-    try:
-        import jax
-        return jax.profiler.trace(path)
-    except Exception:  # pragma: no cover - profiler backend missing
-        return contextlib.nullcontext()

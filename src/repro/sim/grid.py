@@ -20,6 +20,7 @@ code paths.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
@@ -380,14 +381,18 @@ def run_grid(init_fn: Callable[[int], Any], loss_fn: Callable, dataset,
                   policy=policy, registry=registry, tracer=tracer,
                   profile=profile, bfaults=bfaults, san=san,
                   topo=topo, bshocks=bshocks)
-    if grid.mode == "sync":
-        return _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid,
-                         server_opt, **common)
-    if grid.mode == "async":
-        return _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid,
-                          server_opt, **common)
-    raise ValueError(f"unknown grid mode {grid.mode!r} "
-                     "(expected 'sync' or 'async')")
+    runner = {"sync": _run_sync, "async": _run_async}.get(grid.mode)
+    if runner is None:
+        raise ValueError(f"unknown grid mode {grid.mode!r} "
+                         "(expected 'sync' or 'async')")
+    mesh = mesh_lib.resolve_mesh(grid.mesh)
+    # the mesh is the ambient one while the run traces: the model's
+    # maybe_constrain hints see it, and the kernel dispatchers
+    # (kernels/ops.use_kernels) keep Mosaic calls off a multi-device
+    # program, which GSPMD cannot partition
+    with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        return runner(y, frozen, loss_fn, dataset, rc, rounds, grid,
+                      server_opt, mesh=mesh, **common)
 
 
 # ---------------------------------------------------------------------------
@@ -452,8 +457,7 @@ def _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
               data_rng, dev_rng, seed, data_kind, eval_every, eval_fn, log,
               cplan, tier_of_client, tier_up, tier_compute, dyn, dyn_rng,
               policy, registry, tracer, profile, bfaults, san, topo,
-              bshocks):
-    mesh = mesh_lib.resolve_mesh(grid.mesh)
+              bshocks, mesh):
     constrain_flat = shard_lib.flat_constrainer(mesh) if mesh else None
     constrain_batch = shard_lib.cohort_constrainer(mesh) if mesh else None
     # a trivial (one-tier, nothing-extra-frozen) plan routes through the
@@ -709,7 +713,7 @@ def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                data_rng, dev_rng, seed, data_kind, eval_every, eval_fn, log,
                cplan, tier_of_client, tier_up, tier_compute, dyn, dyn_rng,
                policy, registry, tracer, profile, bfaults, san, topo,
-               bshocks):
+               bshocks, mesh):
     if server_opt is None:
         server_opt = fedpt.resolve_server_opt(rc)
     # trivial plans keep the pre-plan engine (lane-exact acceptance);
@@ -728,7 +732,6 @@ def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
             noise_multiplier=rc.dp_noise_multiplier,
             goal_count=grid.goal_count)
         accountant = dp_lib.FlushAccountant(flush_dp, tracer=tracer)
-    mesh = mesh_lib.resolve_mesh(grid.mesh)
     constrain_flat = shard_lib.flat_constrainer(mesh) if mesh else None
     lane = grid.goal_count if grid.lanes is None else int(grid.lanes)
     # one engine per tier: lanes are tier-homogeneous (pending clients
