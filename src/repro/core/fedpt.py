@@ -29,6 +29,13 @@ from repro.core import sanitize as sanitize_lib
 from repro.kernels import ops as kernel_ops
 from repro.optim import optimizers as opt_lib
 
+# ``jax.named_scope`` names of the round programs' three stages: HLO
+# metadata only (each instruction's ``op_name``), so a device trace can
+# put every operation's time on a stage; the computation is unchanged
+SCOPE_CLIENT_STEP = "fedpt/client_step"
+SCOPE_AGG_TAIL = "fedpt/agg_tail"
+SCOPE_SERVER_APPLY = "fedpt/server_apply"
+
 
 @dataclasses.dataclass(frozen=True)
 class RoundConfig:
@@ -193,24 +200,26 @@ def make_round_fn(loss_fn: Callable, rc: RoundConfig,
 
         # --- local training on every sampled client (vmapped over the
         # client axis; under pjit that axis is sharded over `data`) -----
-        if constrain_fn is not None:
-            C = weights.shape[0]
-            yb = jax.tree_util.tree_map(
-                lambda a: jnp.broadcast_to(a[None], (C,) + a.shape), y)
-            yb = constrain_fn(yb, clients=True)
-            if tiered:
-                deltas, metrics = jax.vmap(flat_client)(yb, batch, tiers)
+        with jax.named_scope(SCOPE_CLIENT_STEP):
+            if constrain_fn is not None:
+                C = weights.shape[0]
+                yb = jax.tree_util.tree_map(
+                    lambda a: jnp.broadcast_to(a[None], (C,) + a.shape), y)
+                yb = constrain_fn(yb, clients=True)
+                if tiered:
+                    deltas, metrics = jax.vmap(flat_client)(yb, batch,
+                                                            tiers)
+                else:
+                    deltas, metrics = jax.vmap(
+                        lambda yc, cb: flat_client(yc, cb, None))(yb, batch)
+            elif tiered:
+                deltas, metrics = jax.vmap(
+                    lambda cb, t: flat_client(y, cb, t))(batch, tiers)
             else:
                 deltas, metrics = jax.vmap(
-                    lambda yc, cb: flat_client(yc, cb, None))(yb, batch)
-        elif tiered:
-            deltas, metrics = jax.vmap(
-                lambda cb, t: flat_client(y, cb, t))(batch, tiers)
-        else:
-            deltas, metrics = jax.vmap(
-                lambda cb: flat_client(y, cb, None))(batch)
-        if constrain_flat_fn is not None:
-            deltas = constrain_flat_fn(deltas, clients=True)
+                    lambda cb: flat_client(y, cb, None))(batch)
+            if constrain_flat_fn is not None:
+                deltas = constrain_flat_fn(deltas, clients=True)
 
         # --- the whole server tail — quarantine screen, lossy uplink
         # quantize, clip fold, weighted/fixed-denominator mean, output
@@ -224,39 +233,41 @@ def make_round_fn(loss_fn: Callable, rc: RoundConfig,
         noised = rc.dp_clip_norm > 0 and rc.dp_noise_multiplier > 0
         sigma = (rc.dp_noise_multiplier * rc.dp_clip_norm
                  / rc.clients_per_round) if noised else 0.0
-        flat_delta, ainfo = kernel_ops.agg_tail(
-            deltas, weights,
-            block_leaf=layout.block_leaf(),
-            n_leaves=len(layout.sizes),
-            align=layout.align,
-            bits=rc.uplink_bits or 0,
-            clip_norm=rc.dp_clip_norm if rc.dp_clip_norm > 0 else 0.0,
-            # uniform among *participants*: zero weights mark clients the
-            # grid scheduler dropped and must stay excluded even under
-            # DP's fixed weighting
-            uniform=bool(rc.uniform_weights or rc.dp_clip_norm > 0),
-            wsum_fixed=(float(rc.clients_per_round)
-                        if rc.dp_clip_norm > 0 else None),
-            sigma=sigma, rng=rng if noised else None,
-            # per-block mask-weighted mean for tiers (blocks a tier froze
-            # carry zero weight for its clients); under DP/clip the mean
-            # keeps the fixed denominator instead
-            bmask=(jnp.asarray(plan.block_masks())[tiers]
-                   if tiered and rc.dp_clip_norm <= 0 else None),
-            block_denom=tiered and rc.dp_clip_norm <= 0,
-            screen=sanitize,
-            constrain_fn=(None if constrain_flat_fn is None else
-                          lambda v: constrain_flat_fn(v, clients=False)),
-            threshold=fused_threshold)
+        with jax.named_scope(SCOPE_AGG_TAIL):
+            flat_delta, ainfo = kernel_ops.agg_tail(
+                deltas, weights,
+                block_leaf=layout.block_leaf(),
+                n_leaves=len(layout.sizes),
+                align=layout.align,
+                bits=rc.uplink_bits or 0,
+                clip_norm=rc.dp_clip_norm if rc.dp_clip_norm > 0 else 0.0,
+                # uniform among *participants*: zero weights mark clients
+                # the grid scheduler dropped and must stay excluded even
+                # under DP's fixed weighting
+                uniform=bool(rc.uniform_weights or rc.dp_clip_norm > 0),
+                wsum_fixed=(float(rc.clients_per_round)
+                            if rc.dp_clip_norm > 0 else None),
+                sigma=sigma, rng=rng if noised else None,
+                # per-block mask-weighted mean for tiers (blocks a tier
+                # froze carry zero weight for its clients); under DP/clip
+                # the mean keeps the fixed denominator instead
+                bmask=(jnp.asarray(plan.block_masks())[tiers]
+                       if tiered and rc.dp_clip_norm <= 0 else None),
+                block_denom=tiered and rc.dp_clip_norm <= 0,
+                screen=sanitize,
+                constrain_fn=(None if constrain_flat_fn is None else
+                              lambda v: constrain_flat_fn(v, clients=False)),
+                threshold=fused_threshold)
 
         # --- ServerOpt on the pseudo-gradient ---------------------------
-        delta = layout.unflatten(flat_delta, dtype=jnp.float32)
-        neg = jax.tree_util.tree_map(lambda d: -d, delta)
-        y_new, server_state = server_opt.update(y, neg, server_state)
-        out_metrics = {"loss": jnp.mean(metrics["client_loss"]),
-                       "delta_norm": opt_lib.tree_global_norm(delta)
-                       if noised else jnp.sqrt(
-                           flat_lib.sumsq(flat_delta, layout.align))}
+        with jax.named_scope(SCOPE_SERVER_APPLY):
+            delta = layout.unflatten(flat_delta, dtype=jnp.float32)
+            neg = jax.tree_util.tree_map(lambda d: -d, delta)
+            y_new, server_state = server_opt.update(y, neg, server_state)
+            out_metrics = {"loss": jnp.mean(metrics["client_loss"]),
+                           "delta_norm": opt_lib.tree_global_norm(delta)
+                           if noised else jnp.sqrt(
+                               flat_lib.sumsq(flat_delta, layout.align))}
         if "update_norms" in ainfo:
             out_metrics["update_norm"] = jnp.mean(ainfo["update_norms"])
         if sanitize is not None:
@@ -348,6 +359,7 @@ def make_client_step(loss_fn: Callable, rc: RoundConfig,
         raise ValueError("a tiered client step needs the owning "
                          "CompiledPlan (plan=...)")
 
+    @jax.named_scope(SCOPE_CLIENT_STEP)
     def client_step(y, frozen, client_batch):
         if tier is None:
             layout = flat_lib.FlatLayout.of(y)
@@ -398,10 +410,12 @@ def make_lane_step(loss_fn: Callable, rc: RoundConfig, lane: int,
     def lane_step(y, frozen, lane_batch):
         flat_deltas, metrics = jax.vmap(
             lambda cb: step(y, frozen, cb))(lane_batch)
-        if tier is not None:
-            flat_deltas = plan.scatter(flat_deltas, tier)
-        if constrain_flat_fn is not None:
-            flat_deltas = constrain_flat_fn(flat_deltas, clients=True)
+        # ``step`` carries the scope itself; this covers the widening
+        with jax.named_scope(SCOPE_CLIENT_STEP):
+            if tier is not None:
+                flat_deltas = plan.scatter(flat_deltas, tier)
+            if constrain_flat_fn is not None:
+                flat_deltas = constrain_flat_fn(flat_deltas, clients=True)
         return flat_deltas, metrics["client_loss"]
 
     return lane_step
@@ -474,32 +488,34 @@ def make_buffered_apply(server_opt: opt_lib.Optimizer,
         # for tiers) -> constraint -> per-flush Gaussian, as ONE
         # dispatched op — staged per-op sequence below the threshold,
         # fused stats/apply sweep above it
-        flat_delta, ainfo = kernel_ops.agg_tail(
-            flat_deltas, weights,
-            block_leaf=layout.block_leaf(),
-            n_leaves=len(layout.sizes),
-            align=layout.align,
-            wsum_fixed=(float(flush_dp.goal_count)
-                        if flush_dp is not None else None),
-            sigma=flush_dp.sigma if noised else 0.0,
-            rng=rng if noised else None,
-            bmask=(jnp.asarray(plan.block_masks())[tier_ids]
-                   if tiered else None),
-            remask_rows=tiered,
-            block_denom=tiered and flush_dp is None,
-            screen=sanitize,
-            constrain_fn=(None if constrain_flat_fn is None else
-                          lambda v: constrain_flat_fn(v, clients=False)),
-            threshold=fused_threshold)
+        with jax.named_scope(SCOPE_AGG_TAIL):
+            flat_delta, ainfo = kernel_ops.agg_tail(
+                flat_deltas, weights,
+                block_leaf=layout.block_leaf(),
+                n_leaves=len(layout.sizes),
+                align=layout.align,
+                wsum_fixed=(float(flush_dp.goal_count)
+                            if flush_dp is not None else None),
+                sigma=flush_dp.sigma if noised else 0.0,
+                rng=rng if noised else None,
+                bmask=(jnp.asarray(plan.block_masks())[tier_ids]
+                       if tiered else None),
+                remask_rows=tiered,
+                block_denom=tiered and flush_dp is None,
+                screen=sanitize,
+                constrain_fn=(None if constrain_flat_fn is None else
+                              lambda v: constrain_flat_fn(v, clients=False)),
+                threshold=fused_threshold)
         qinfo = ainfo if sanitize is not None else None
-        delta = layout.unflatten(flat_delta, dtype=jnp.float32)
-        neg = jax.tree_util.tree_map(lambda d: -d, delta)
-        y_new, server_state = server_opt.update(y, neg, server_state)
-        # with noise on pad slots, the flat vector's norm overstates the
-        # model update — report the unflattened norm instead (sync engine
-        # does the same)
-        norm = (opt_lib.tree_global_norm(delta) if noised
-                else jnp.sqrt(flat_lib.sumsq(flat_delta, layout.align)))
+        with jax.named_scope(SCOPE_SERVER_APPLY):
+            delta = layout.unflatten(flat_delta, dtype=jnp.float32)
+            neg = jax.tree_util.tree_map(lambda d: -d, delta)
+            y_new, server_state = server_opt.update(y, neg, server_state)
+            # with noise on pad slots, the flat vector's norm overstates
+            # the model update — report the unflattened norm instead
+            # (sync engine does the same)
+            norm = (opt_lib.tree_global_norm(delta) if noised
+                    else jnp.sqrt(flat_lib.sumsq(flat_delta, layout.align)))
         out = {"delta_norm": norm}
         if qinfo is not None:
             out["quarantine_nonfinite"] = qinfo["nonfinite"]

@@ -1,33 +1,59 @@
-"""Optional ``jax.profiler`` annotation hooks for traced grid runs.
+"""Wall-clock spans for the grid's host loop, always on.
 
 Virtual-time spans (obs/trace.py) say *when the simulated fleet* was
-busy; a wall-time profile says where the *host* actually spent its
-compute. With ``TelemetryConfig(profile=True)`` the grid wraps its two
-jitted hot paths — the vmapped client lane step and the buffered-apply
-server tail — in named ``jax.profiler.TraceAnnotation`` scopes, so a
-profile captured around the run (``jax.profiler.trace(...)`` or
-``start_trace``/``stop_trace``) shows ``grid/lane_step`` /
-``grid/server_apply`` blocks that line up with the virtual-time flush
-spans one-to-one.
+busy; these say where the *host* spends wall-clock time. They are
+``jax.profiler`` annotations: with no profiler session active each one
+costs a single check, and under ``jax.profiler.trace(...)`` (or
+``start_trace``/``stop_trace``) they land on the ``/host:CPU`` plane, on
+the same clock as the device's operations. They draw from no PRNG
+stream and add no device sync, so a run's history is bit-identical with
+and without a profiler session.
 
-Everything degrades to a plain call when profiling is off — the wrapper
-adds one function frame, never a device sync.
+The grid's names (``sim/grid.py``):
+
+* sync, one ``grid/round`` per round (``step_num`` = round index) with
+  the children ``grid/plan`` (tier map, cohort selection,
+  ``plan_sync_round``), ``grid/cohort_batch`` (the host batch and its
+  weights; args ``clients``, ``bytes``), ``grid/round_fn`` (the
+  dispatch), ``grid/wait`` (the round's one blocking host sync),
+  ``grid/bookkeeping`` (quarantine, billing, counters, the policy),
+  ``grid/eval_fn`` and ``grid/checkpoint``;
+* async, one ``grid/flush`` per server update (``step_num`` = updates
+  applied before it) with ``grid/restack`` (lane batches and the
+  ``(K, size)`` buffer), ``grid/lane_step[k]``, ``grid/server_apply``,
+  ``grid/wait``, ``grid/bookkeeping`` and ``grid/eval_fn``;
+  ``grid/client_step[k]`` (no lanes) runs at dispatch, between flushes,
+  and ``grid/checkpoint`` right after its flush.
+
+The device side carries ``jax.named_scope`` paths instead
+(``core/fedpt.py``: ``fedpt/client_step``, ``fedpt/agg_tail``,
+``fedpt/server_apply``), which reach each HLO instruction's
+``metadata.op_name``.
 """
 from __future__ import annotations
 
 import functools
 from typing import Callable
 
+from jax.profiler import StepTraceAnnotation as _StepTraceAnnotation
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 
-def annotate(fn: Callable, name: str,
-             enabled: bool = True) -> Callable:
-    """Wrap ``fn`` so each call runs inside a ``TraceAnnotation(name)``.
-    With ``enabled=False`` (telemetry off, or profile not requested)
-    returns ``fn`` unchanged — zero added frames on the default path."""
-    if not enabled:
-        return fn
+def span(name: str, **args) -> _TraceAnnotation:
+    """A wall-clock span: ``with span("grid/plan"): ...``. ``args`` ride
+    on the trace event; more can be added inside the block with the
+    span's ``set_metadata(**args)``."""
+    return _TraceAnnotation(name, **args)
+
+
+def round_span(name: str, step: int) -> _StepTraceAnnotation:
+    """The span of one round or flush: every span opened inside it
+    belongs to step ``step``."""
+    return _StepTraceAnnotation(name, step_num=int(step))
+
+
+def annotate(fn: Callable, name: str) -> Callable:
+    """Wrap ``fn`` so each call runs inside ``span(name)``."""
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
@@ -37,9 +63,7 @@ def annotate(fn: Callable, name: str,
     return wrapped
 
 
-def annotate_map(fns: dict, name: str, enabled: bool = True) -> dict:
+def annotate_map(fns: dict, name: str) -> dict:
     """``annotate`` over a dict of callables (the grid's per-tier lane
     step / client step tables), tagging each with its key."""
-    if not enabled:
-        return fns
     return {k: annotate(fn, f"{name}[{k}]") for k, fn in fns.items()}

@@ -52,10 +52,12 @@ class TelemetryConfig:
 
     With both paths ``None`` the events just accumulate on
     ``Tracer.events`` (and ``GridResult.telemetry``) for in-process
-    inspection/export. ``profile=True`` additionally wraps the jitted
-    lane step and the server tail in ``jax.profiler`` annotations
-    (``obs/profiling.py``) so a wall-time profile captured around the
-    run lines up with the virtual-time spans."""
+    inspection/export. The grid's wall-clock spans
+    (``obs/profiling.py``) need no setting here: they are always on.
+
+    ``profile`` does nothing. It stays so that callers written when it
+    switched the wall-clock spans on (``bench/harness.py``) still
+    construct."""
     jsonl_path: Optional[str] = None
     perfetto_path: Optional[str] = None
     profile: bool = False

@@ -266,7 +266,6 @@ def run_grid(init_fn: Callable[[int], Any], loss_fn: Callable, dataset,
     tel_cfg = trace_lib.resolve_telemetry(grid.telemetry)
     tracer = (trace_lib.Tracer(tel_cfg, registry) if tel_cfg is not None
               else trace_lib.NULL_TRACER)
-    profile = bool(tel_cfg and tel_cfg.profile)
 
     report = comm.report_for(y, frozen, uplink_bits=rc.uplink_bits)
     report.tracer = tracer                       # tier_upload billing
@@ -379,7 +378,7 @@ def run_grid(init_fn: Callable[[int], Any], loss_fn: Callable, dataset,
                   tier_of_client=tier_of_client, tier_up=tier_up,
                   tier_compute=tier_compute, dyn=dyn, dyn_rng=dyn_rng,
                   policy=policy, registry=registry, tracer=tracer,
-                  profile=profile, bfaults=bfaults, san=san,
+                  bfaults=bfaults, san=san,
                   topo=topo, bshocks=bshocks)
     runner = {"sync": _run_sync, "async": _run_async}.get(grid.mode)
     if runner is None:
@@ -452,11 +451,69 @@ def _tier_stats(report, cplan, tier_of_client,
     return out
 
 
+def _sync_quarantine(rmetrics, sel, tiers_now, cplan, mc, tracer, vt0,
+                     rseq, r) -> None:
+    """Quarantined cohort rows -> traced events + counter (the masks are
+    tiny (C,) vectors, read after the round's loss has synced)."""
+    nonf = np.asarray(rmetrics["quarantine_nonfinite"])
+    outl = np.asarray(rmetrics["quarantine_outlier"])
+    norms = np.asarray(rmetrics["quarantine_norms"])
+    for i in np.nonzero(nonf | outl)[0]:
+        mc("quarantined").inc()
+        tracer.instant(
+            "quarantine", vt0, parent=rseq,
+            cause="nonfinite" if nonf[i] else "norm-outlier",
+            cid=int(sel[i]),
+            tier=(int(tiers_now[sel[i]]) if cplan is not None else None),
+            norm=float(norms[i]), round=r)
+
+
+def _bill_tiers(report, cplan, cohort_tiers, plan, down_bytes, tier_up, vt,
+                rseq) -> None:
+    """Bill a sync round per tier: dispatches pay the (tier-invariant)
+    downlink, uploads pay the tier-sliced uplink."""
+    uploaded = np.isfinite(plan.arrival)
+    for t in cplan.tiers:
+        sel_t = cohort_tiers == t.index
+        nd = int(np.sum(plan.dispatched & sel_t))
+        nu = int(np.sum(uploaded & sel_t))
+        if nd or nu:
+            report.add_tier_measured(
+                t.name, down_bytes * nd, int(tier_up[t.index]) * nu,
+                transfers=nd, uploads=nu, now=vt, parent=rseq)
+
+
+def _bill_edges(report, topo, cohort_regions, plan, mc, tracer, down_bytes,
+                edge_bytes, vt, rseq, r) -> None:
+    """Hierarchical hop billing of a sync round: every region with a
+    dispatch downloads one model payload server->edge (the edge fans it
+    out on the client hop); every region with a completed upload
+    pre-reduces its members' deltas and flushes one flat buffer
+    upstream."""
+    disp_counts = np.bincount(cohort_regions[plan.dispatched],
+                              minlength=topo.num_regions)
+    up_counts = np.bincount(cohort_regions[plan.completed],
+                            minlength=topo.num_regions)
+    for k in np.nonzero(disp_counts)[0]:
+        mc("region_dispatches").inc(int(disp_counts[k]), label=int(k))
+    active = np.nonzero(up_counts)[0]
+    for k in active:
+        mc("region_uploads").inc(int(up_counts[k]), label=int(k))
+        mc("edge_flushes").inc(label=int(k))
+        mc("edge_up_bytes").inc(edge_bytes, label=int(k))
+        tracer.instant("edge_flush", vt, parent=rseq, region=int(k),
+                       fill=int(up_counts[k]), up_bytes=edge_bytes, round=r)
+    n_down = int(np.sum(disp_counts > 0))
+    report.add_hop("edge_server", down_bytes=down_bytes * n_down,
+                   up_bytes=edge_bytes * len(active), transfers=n_down,
+                   uploads=len(active))
+
+
 def _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
               fleet, report, down_bytes, up_bytes, compute_seconds,
               data_rng, dev_rng, seed, data_kind, eval_every, eval_fn, log,
               cplan, tier_of_client, tier_up, tier_compute, dyn, dyn_rng,
-              policy, registry, tracer, profile, bfaults, san, topo,
+              policy, registry, tracer, bfaults, san, topo,
               bshocks, mesh):
     constrain_flat = shard_lib.flat_constrainer(mesh) if mesh else None
     constrain_batch = shard_lib.cohort_constrainer(mesh) if mesh else None
@@ -468,8 +525,7 @@ def _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                                          constrain_batch_fn=constrain_batch,
                                          plan=cplan, sanitize=san,
                                          fused_threshold=grid.agg_tail_threshold)
-    round_fn = prof_lib.annotate(jax.jit(round_fn, donate_argnums=(0, 1)),
-                                 "grid/round_fn", enabled=profile)
+    round_fn = jax.jit(round_fn, donate_argnums=(0, 1))
     sstate = sopt.init(y)
     N = num_clients(dataset)
     C = rc.clients_per_round
@@ -498,164 +554,145 @@ def _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
         last_ckpt = grid.resume_from
     t0 = None
     for r in range(start_round, rounds):
-        if bfaults is not None and vt > bfaults.kill_at:
-            raise faults_lib.ServerKilled(at=vt, applied=r,
-                                          checkpoint=last_ckpt)
-        # the policy's tier map can move between rounds (tier-rotation,
-        # adaptive-capability); static policies return the bound map
-        tiers_now = policy.current_tiers() if cplan is not None else None
-        cids = policy.select_cohort(data_rng, m)
-        # tier-sliced uplink payloads + per-tier compute feed the
-        # virtual clock: a lite client's smaller delta clears the
-        # 0.25 MB/s uplink sooner AND its backward pass is cheaper
-        cohort_up = (tier_up[tiers_now[cids]] if cplan is not None
-                     else up_bytes)
-        cohort_comp = (tier_compute[tiers_now[cids]] if cplan is not None
-                       else compute_seconds)
-        cohort_regions = topo.region_of[cids] if topo is not None else None
-        plan = sched_lib.plan_sync_round(
-            fleet, cids, down_bytes, cohort_up, cohort_comp, C, dev_rng,
-            deadline=grid.straggler_deadline, dynamics=dyn,
-            dyn_rng=dyn_rng, now=vt, tracer=tracer,
-            tiers=tiers_now[cids] if cplan is not None else None,
-            faults=bfaults, shocks=bshocks, regions=cohort_regions)
-        # the C slots the compiled round engine sees: participants in
-        # arrival order, padded (weight 0) with the remaining cohort in
-        # dispatch order when drops leave the round short
-        kept_cids = plan.participant_cids()
-        pad = plan.cids[~plan.participant][:C - len(kept_cids)]
-        sel = np.concatenate([kept_cids, pad]).astype(np.int64)
-        kept = np.arange(C) < len(kept_cids)
+        # wall-clock spans (obs/profiling.py): the round's host work in
+        # named children of grid/round
+        with prof_lib.round_span("grid/round", r):
+            if bfaults is not None and vt > bfaults.kill_at:
+                raise faults_lib.ServerKilled(at=vt, applied=r,
+                                              checkpoint=last_ckpt)
+            with prof_lib.span("grid/plan"):
+                # the policy's tier map can move between rounds
+                # (tier-rotation, adaptive-capability); static policies
+                # return the bound map
+                tiers_now = (policy.current_tiers() if cplan is not None
+                             else None)
+                cids = policy.select_cohort(data_rng, m)
+                # tier-sliced uplink payloads + per-tier compute feed the
+                # virtual clock: a lite client's smaller delta clears the
+                # 0.25 MB/s uplink sooner AND its backward pass is cheaper
+                cohort_up = (tier_up[tiers_now[cids]] if cplan is not None
+                             else up_bytes)
+                cohort_comp = (tier_compute[tiers_now[cids]]
+                               if cplan is not None else compute_seconds)
+                cohort_regions = (topo.region_of[cids] if topo is not None
+                                  else None)
+                plan = sched_lib.plan_sync_round(
+                    fleet, cids, down_bytes, cohort_up, cohort_comp, C,
+                    dev_rng, deadline=grid.straggler_deadline,
+                    dynamics=dyn, dyn_rng=dyn_rng, now=vt, tracer=tracer,
+                    tiers=tiers_now[cids] if cplan is not None else None,
+                    faults=bfaults, shocks=bshocks, regions=cohort_regions)
+                # the C slots the compiled round engine sees: participants
+                # in arrival order, padded (weight 0) with the remaining
+                # cohort in dispatch order when drops leave the round short
+                kept_cids = plan.participant_cids()
+                pad = plan.cids[~plan.participant][:C - len(kept_cids)]
+                sel = np.concatenate([kept_cids, pad]).astype(np.int64)
+                kept = np.arange(C) < len(kept_cids)
 
-        batch, w = syn.cohort_batch(dataset, sel, rc.local_steps,
-                                    rc.local_batch, data_rng, kind=data_kind)
-        w = np.where(kept, w, 0.0).astype(np.float32)
-        if not policy.trivial and not (rc.uniform_weights
-                                       or rc.dp_clip_norm > 0):
-            # importance-unbiased selection weights multiply into the
-            # aggregation weights; under DP the engine forces uniform
-            # weighting with a fixed denominator (sigma calibration),
-            # so the correction is dropped there by design
-            iw = policy.cohort_weights(sel)
-            if iw is not None:
-                w = (w * iw).astype(np.float32)
-        args = (y, sstate, frozen, batch, jnp.asarray(w))
-        if tiered:
-            args += (jnp.asarray(tiers_now[sel], jnp.int32),)
-        y, sstate, rmetrics = round_fn(*args,
-                                       jax.random.key(seed * 100_003 + r))
-        if t0 is None:
-            jax.block_until_ready(y)
-            t0 = time.time()  # exclude compile from the per-round timing
-        vt0, vt = vt, vt + plan.round_seconds
-        # the round span goes out as soon as its wall time is known —
-        # before the quarantine/billing/edge instants it causally
-        # precedes, so they can parent on it. Its own parent is the
-        # upload that closed the round (plan.bound_seq), which links
-        # round -> bounding upload -> dispatch for analyze.py's
-        # critical-path walk.
-        rseq = tracer.span("round", vt0, plan.round_seconds,
-                           parent=plan.bound_seq, round=r,
-                           participants=float(len(kept_cids)),
-                           cohort=int(m), loss=float(rmetrics["loss"]))
-        if san is not None:
-            # quarantined cohort rows -> traced events + counter (the
-            # masks are tiny (C,) vectors; one host sync per round)
-            nonf = np.asarray(rmetrics["quarantine_nonfinite"])
-            outl = np.asarray(rmetrics["quarantine_outlier"])
-            norms = np.asarray(rmetrics["quarantine_norms"])
-            for i in np.nonzero(nonf | outl)[0]:
-                mc("quarantined").inc()
-                tracer.instant(
-                    "quarantine", vt0, parent=rseq,
-                    cause="nonfinite" if nonf[i] else "norm-outlier",
-                    cid=int(sel[i]),
-                    tier=(int(tiers_now[sel[i]]) if cplan is not None
-                          else None),
-                    norm=float(norms[i]), round=r)
-        registry.histogram("round_seconds").observe(plan.round_seconds)
-        n_dispatched = int(np.sum(plan.dispatched))
-        n_uploads = n_dispatched - plan.dropouts
-        # observed round trips flow back to the policy (adaptive
-        # re-tiering) and into the per-tier timing stats
-        for i in np.nonzero(plan.completed)[0]:
-            rtt = float(plan.arrival[i])
-            policy.observe(int(plan.cids[i]), rtt)
-            registry.histogram("upload_rtt").observe(rtt)
-            if cplan is not None:
-                t_idx = int(tiers_now[plan.cids[i]])
-                mc("tier_rtt_sum").inc(rtt, label=t_idx)
-                mc("tier_rtt_n").inc(label=t_idx)
-        if cplan is not None:
-            # bill per tier: dispatches pay the (tier-invariant)
-            # downlink, uploads pay the tier-sliced uplink
-            cohort_tiers = tiers_now[plan.cids]
-            uploaded = np.isfinite(plan.arrival)
-            for t in cplan.tiers:
-                sel_t = cohort_tiers == t.index
-                nd = int(np.sum(plan.dispatched & sel_t))
-                nu = int(np.sum(uploaded & sel_t))
-                if nd or nu:
-                    report.add_tier_measured(
-                        t.name, down_bytes * nd, int(tier_up[t.index]) * nu,
-                        transfers=nd, uploads=nu, now=vt, parent=rseq)
-        else:
-            report.add_measured(down_bytes * n_dispatched,
-                                up_bytes * n_uploads,
-                                transfers=n_dispatched)
-        if topo is not None:
-            # hierarchical hop billing: every region with a dispatch
-            # downloads one model payload server->edge (the edge fans it
-            # out on the client hop); every region with a completed
-            # upload pre-reduces its members' deltas and flushes one
-            # flat buffer upstream
-            disp_counts = np.bincount(cohort_regions[plan.dispatched],
-                                      minlength=topo.num_regions)
-            up_counts = np.bincount(cohort_regions[plan.completed],
-                                    minlength=topo.num_regions)
-            for k in np.nonzero(disp_counts)[0]:
-                mc("region_dispatches").inc(int(disp_counts[k]),
-                                            label=int(k))
-            active = np.nonzero(up_counts)[0]
-            for k in active:
-                mc("region_uploads").inc(int(up_counts[k]), label=int(k))
-                mc("edge_flushes").inc(label=int(k))
-                mc("edge_up_bytes").inc(edge_bytes, label=int(k))
-                tracer.instant("edge_flush", vt, parent=rseq,
-                               region=int(k), fill=int(up_counts[k]),
-                               up_bytes=edge_bytes, round=r)
-            n_down = int(np.sum(disp_counts > 0))
-            report.add_hop("edge_server", down_bytes=down_bytes * n_down,
-                           up_bytes=edge_bytes * len(active),
-                           transfers=n_down, uploads=len(active))
-        mc("dispatches").inc(n_dispatched)
-        mc("uploads").inc(n_uploads)
-        mc("offline").inc(plan.offline)
-        mc("dropouts").inc(plan.dropouts)
-        mc("deadline_drops").inc(plan.deadline_drops)
-        mc("excess").inc(plan.excess)
-        mc("retries").inc(plan.retries)
-        mc("crashes").inc(plan.crashes)
+            with prof_lib.span("grid/cohort_batch", clients=len(sel)) as sp:
+                batch, w = syn.cohort_batch(dataset, sel, rc.local_steps,
+                                            rc.local_batch, data_rng,
+                                            kind=data_kind)
+                w = np.where(kept, w, 0.0).astype(np.float32)
+                if not policy.trivial and not (rc.uniform_weights
+                                               or rc.dp_clip_norm > 0):
+                    # importance-unbiased selection weights multiply into
+                    # the aggregation weights; under DP the engine forces
+                    # uniform weighting with a fixed denominator (sigma
+                    # calibration), so the correction is dropped there by
+                    # design
+                    iw = policy.cohort_weights(sel)
+                    if iw is not None:
+                        w = (w * iw).astype(np.float32)
+                sp.set_metadata(bytes=sum(int(v.nbytes)
+                                          for v in batch.values()))
 
-        rec = {"round": r, "loss": float(rmetrics["loss"])}
-        if eval_fn and eval_every and (r + 1) % eval_every == 0:
-            rec.update(eval_fn(part.merge(y, frozen)))
-        rec["virtual_seconds"] = vt
-        rec["participants"] = float(len(kept_cids))
-        history.append(rec)
-        policy.end_round(r)
-        if grid.checkpoint_every > 0 \
-                and (r + 1) % grid.checkpoint_every == 0:
-            meta, arrays = gstate_lib.encode_sync(
-                y=y, sstate=sstate, round_idx=r, now=vt, history=history,
-                rngs=rngs, policy=policy, registry=registry, report=report,
-                shocks=bshocks, topo=topo)
-            last_ckpt = gstate_lib.save_state(
-                gstate_lib.checkpoint_path(grid.checkpoint_dir, r + 1,
-                                           "sync"), meta, arrays)
-            mc("checkpoints").inc()
-            tracer.instant("checkpoint", vt, parent=rseq, path=last_ckpt,
-                           round=r, mode="sync")
+            with prof_lib.span("grid/round_fn"):
+                args = (y, sstate, frozen, batch, jnp.asarray(w))
+                if tiered:
+                    args += (jnp.asarray(tiers_now[sel], jnp.int32),)
+                y, sstate, rmetrics = round_fn(
+                    *args, jax.random.key(seed * 100_003 + r))
+
+            with prof_lib.span("grid/wait"):
+                if t0 is None:
+                    jax.block_until_ready(y)
+                    t0 = time.time()  # exclude compile from the timing
+                # the round's one blocking host sync
+                loss = float(rmetrics["loss"])
+
+            with prof_lib.span("grid/bookkeeping"):
+                vt0, vt = vt, vt + plan.round_seconds
+                # the round span goes out as soon as its wall time is
+                # known — before the quarantine/billing/edge instants it
+                # causally precedes, so they can parent on it. Its own
+                # parent is the upload that closed the round
+                # (plan.bound_seq), which links round -> bounding upload
+                # -> dispatch for analyze.py's critical-path walk.
+                rseq = tracer.span("round", vt0, plan.round_seconds,
+                                   parent=plan.bound_seq, round=r,
+                                   participants=float(len(kept_cids)),
+                                   cohort=int(m), loss=loss)
+                if san is not None:
+                    _sync_quarantine(rmetrics, sel, tiers_now, cplan, mc,
+                                     tracer, vt0, rseq, r)
+                registry.histogram("round_seconds").observe(
+                    plan.round_seconds)
+                n_dispatched = int(np.sum(plan.dispatched))
+                n_uploads = n_dispatched - plan.dropouts
+                # observed round trips flow back to the policy (adaptive
+                # re-tiering) and into the per-tier timing stats
+                for i in np.nonzero(plan.completed)[0]:
+                    rtt = float(plan.arrival[i])
+                    policy.observe(int(plan.cids[i]), rtt)
+                    registry.histogram("upload_rtt").observe(rtt)
+                    if cplan is not None:
+                        t_idx = int(tiers_now[plan.cids[i]])
+                        mc("tier_rtt_sum").inc(rtt, label=t_idx)
+                        mc("tier_rtt_n").inc(label=t_idx)
+                if cplan is not None:
+                    _bill_tiers(report, cplan, tiers_now[plan.cids], plan,
+                                down_bytes, tier_up, vt, rseq)
+                else:
+                    report.add_measured(down_bytes * n_dispatched,
+                                        up_bytes * n_uploads,
+                                        transfers=n_dispatched)
+                if topo is not None:
+                    _bill_edges(report, topo, cohort_regions, plan, mc,
+                                tracer, down_bytes, edge_bytes, vt, rseq, r)
+                mc("dispatches").inc(n_dispatched)
+                mc("uploads").inc(n_uploads)
+                mc("offline").inc(plan.offline)
+                mc("dropouts").inc(plan.dropouts)
+                mc("deadline_drops").inc(plan.deadline_drops)
+                mc("excess").inc(plan.excess)
+                mc("retries").inc(plan.retries)
+                mc("crashes").inc(plan.crashes)
+                policy.end_round(r)
+
+            rec = {"round": r, "loss": loss}
+            if eval_fn and eval_every and (r + 1) % eval_every == 0:
+                with prof_lib.span("grid/eval_fn"):
+                    rec.update(eval_fn(part.merge(y, frozen)))
+            rec["virtual_seconds"] = vt
+            rec["participants"] = float(len(kept_cids))
+            history.append(rec)
+            if grid.checkpoint_every > 0 \
+                    and (r + 1) % grid.checkpoint_every == 0:
+                with prof_lib.span("grid/checkpoint"):
+                    meta, arrays = gstate_lib.encode_sync(
+                        y=y, sstate=sstate, round_idx=r, now=vt,
+                        history=history, rngs=rngs, policy=policy,
+                        registry=registry, report=report, shocks=bshocks,
+                        topo=topo)
+                    last_ckpt = gstate_lib.save_state(
+                        gstate_lib.checkpoint_path(grid.checkpoint_dir,
+                                                   r + 1, "sync"),
+                        meta, arrays)
+                mc("checkpoints").inc()
+                tracer.instant("checkpoint", vt, parent=rseq,
+                               path=last_ckpt, round=r, mode="sync")
         if log and (r % max(1, rounds // 10) == 0):
             print(f"  round {r}: " + " ".join(
                 f"{k}={v:.4f}" for k, v in rec.items() if k != "round"))
@@ -708,11 +745,52 @@ class _LaneCell:
         return self.delta, self.loss
 
 
+def _flush_quarantine(m, entries, registry, tracer, now, parent,
+                      applied) -> None:
+    """Quarantined buffer rows of a flush -> traced events + counter
+    (the masks are tiny (K,) vectors, synced with the flush's losses)."""
+    nonf = np.asarray(m["quarantine_nonfinite"])
+    outl = np.asarray(m["quarantine_outlier"])
+    norms = np.asarray(m["quarantine_norms"])
+    for i in np.nonzero((nonf | outl)[:len(entries)])[0]:
+        registry.counter("quarantined").inc()
+        w = entries[i].work
+        tracer.instant(
+            "quarantine", now, parent=parent,
+            cause="nonfinite" if nonf[i] else "norm-outlier",
+            cid=int(w["cid"]),
+            tier=None if w.get("tier") is None else int(w["tier"]),
+            norm=float(norms[i]), flush=applied)
+
+
+def _edge_reduce_flush(topo, flat_deltas, wts, entries, registry, tracer,
+                       down_bytes, edge_bytes, now, parent,
+                       applied) -> None:
+    """Edge pre-reduce of a flush: its rows grouped by uploader region —
+    each edge's (size,) buffer is what it transmits upstream (billed on
+    the edge_server hop at end of run). The authoritative server reduce
+    consumed the same rows fused, so the model path is
+    topology-invariant."""
+    regs = topo.region_of[[int(e.work["cid"]) for e in entries]]
+    ebuf = topo_lib.edge_reduce(
+        np.asarray(flat_deltas)[:len(entries)],
+        np.asarray(wts[:len(entries)], np.float32),
+        regs, topo.num_regions)
+    counts = np.bincount(regs, minlength=topo.num_regions)
+    for k in np.nonzero(counts)[0]:
+        registry.counter("edge_flushes").inc(label=int(k))
+        registry.counter("edge_up_bytes").inc(edge_bytes, label=int(k))
+        registry.counter("edge_down_bytes").inc(down_bytes, label=int(k))
+        tracer.instant("edge_flush", now, parent=parent, region=int(k),
+                       fill=int(counts[k]), up_bytes=edge_bytes,
+                       norm=float(np.linalg.norm(ebuf[k])), flush=applied)
+
+
 def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                fleet, report, down_bytes, up_bytes, compute_seconds,
                data_rng, dev_rng, seed, data_kind, eval_every, eval_fn, log,
                cplan, tier_of_client, tier_up, tier_compute, dyn, dyn_rng,
-               policy, registry, tracer, profile, bfaults, san, topo,
+               policy, registry, tracer, bfaults, san, topo,
                bshocks, mesh):
     if server_opt is None:
         server_opt = fedpt.resolve_server_opt(rc)
@@ -745,10 +823,7 @@ def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                 tier=None if k is None else cplan.tiers[k],
                 plan=None if k is None else cplan))
             for k in tier_keys}
-        # jax.profiler annotations around the jitted hot paths so a
-        # wall-time profile lines up with the virtual-time spans
-        lane_steps = prof_lib.annotate_map(lane_steps, "grid/lane_step",
-                                           enabled=profile)
+        lane_steps = prof_lib.annotate_map(lane_steps, "grid/lane_step")
     else:
         client_steps = {
             k: jax.jit(fedpt.make_client_step(
@@ -757,14 +832,13 @@ def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                 plan=None if k is None else cplan))
             for k in tier_keys}
         client_steps = prof_lib.annotate_map(client_steps,
-                                             "grid/client_step",
-                                             enabled=profile)
+                                             "grid/client_step")
     apply_fn = prof_lib.annotate(
         jax.jit(fedpt.make_buffered_apply(
             server_opt, flush_dp=flush_dp, constrain_flat_fn=constrain_flat,
             plan=cplan, sanitize=san,
             fused_threshold=grid.agg_tail_threshold), donate_argnums=(0, 1)),
-        "grid/server_apply", enabled=profile)
+        "grid/server_apply")
     staleness_fn = fedpt.get_staleness_fn(grid.staleness, **grid.staleness_kw)
     if flush_dp is not None:
         # the per-flush sensitivity bound (clip_norm / goal_count)
@@ -808,9 +882,10 @@ def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                 n = len(chunk)
                 # pad short lanes with a repeat of the last real batch:
                 # one fixed (lane, ...) shape -> lane_step never re-traces
-                stacked = {k: np.stack([b[k] for b, _ in chunk]
-                                       + [chunk[-1][0][k]] * (lane - n))
-                           for k in chunk[0][0]}
+                with prof_lib.span("grid/restack", clients=n):
+                    stacked = {k: np.stack([b[k] for b, _ in chunk]
+                                           + [chunk[-1][0][k]] * (lane - n))
+                               for k in chunk[0][0]}
                 deltas, losses = lane_steps[key](state["y"], frozen, stacked)
                 for i, (_, cell) in enumerate(chunk):
                     cell.delta, cell.loss = deltas[i], losses[i]
@@ -857,35 +932,44 @@ def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
         return e.work["delta"], e.work["loss"]
 
     def apply_update(entries, now, version):
+        # wall-clock spans (obs/profiling.py): one grid/flush per server
+        # update, its host work in named children
+        with prof_lib.round_span("grid/flush", state["applied"]):
+            return flush(entries, now)
+
+    def flush(entries, now):
         if lane > 0:
             run_pending()
-        rows, losses = [], []
-        for e in entries:
-            d, l = entry_arrays(e)
-            f = e.work.get("fault")
-            if f is not None and f["kind"] in ("nan", "bitflip"):
-                # materialize the wire corruption from the per-event
-                # seed (duplicate rows share the work dict and damage
-                # identically; the client's reported loss predates the
-                # wire, so it stays intact)
-                d = jnp.asarray(faults_lib.corrupt_row(
-                    np.asarray(d), f["kind"], f["seed"], bfaults.cfg))
-            rows.append(d)
-            losses.append(l)
-        wts = [e.weight for e in entries]
-        # pad a short (drained) flush to the fixed goal_count shape with
-        # zero-weight rows, so apply_fn never re-traces — and under DP
-        # the fixed-denominator mean and per-flush sigma never change
-        flat_deltas = flat_lib.pad_rows(jnp.stack(rows), grid.goal_count)
-        wts = wts + [0.0] * (grid.goal_count - len(entries))
-        args = (state["y"], state["sstate"], flat_deltas,
-                jnp.asarray(wts, jnp.float32))
-        if tiered:
-            # per-row tier ids drive the apply's block masks; padding
-            # rows carry tier 0 + weight 0 and fall out of both means
-            tids = ([e.work["tier"] for e in entries]
-                    + [0] * (grid.goal_count - len(entries)))
-            args += (jnp.asarray(tids, jnp.int32),)
+        with prof_lib.span("grid/restack", clients=len(entries)):
+            rows, losses = [], []
+            for e in entries:
+                d, l = entry_arrays(e)
+                f = e.work.get("fault")
+                if f is not None and f["kind"] in ("nan", "bitflip"):
+                    # materialize the wire corruption from the per-event
+                    # seed (duplicate rows share the work dict and damage
+                    # identically; the client's reported loss predates
+                    # the wire, so it stays intact)
+                    d = jnp.asarray(faults_lib.corrupt_row(
+                        np.asarray(d), f["kind"], f["seed"], bfaults.cfg))
+                rows.append(d)
+                losses.append(l)
+            wts = [e.weight for e in entries]
+            # pad a short (drained) flush to the fixed goal_count shape
+            # with zero-weight rows, so apply_fn never re-traces — and
+            # under DP the fixed-denominator mean and per-flush sigma
+            # never change
+            flat_deltas = flat_lib.pad_rows(jnp.stack(rows),
+                                            grid.goal_count)
+            wts = wts + [0.0] * (grid.goal_count - len(entries))
+            args = (state["y"], state["sstate"], flat_deltas,
+                    jnp.asarray(wts, jnp.float32))
+            if tiered:
+                # per-row tier ids drive the apply's block masks; padding
+                # rows carry tier 0 + weight 0 and fall out of both means
+                tids = ([e.work["tier"] for e in entries]
+                        + [0] * (grid.goal_count - len(entries)))
+                args += (jnp.asarray(tids, jnp.int32),)
         if flush_dp is not None:
             # one PRNG key per flush, from the same stream family as the
             # sync engine's per-round keys
@@ -903,51 +987,24 @@ def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                                     parent=sched.last_flush_seq)
         y_new, ss, m = apply_fn(*args)
         state["y"], state["sstate"] = y_new, ss
-        # ONE host sync per flush for the buffered losses
-        out = {"loss": float(jnp.mean(jnp.stack(losses))),
-               "delta_norm": float(m["delta_norm"])}
+        with prof_lib.span("grid/wait"):
+            # ONE host sync per flush for the buffered losses
+            out = {"loss": float(jnp.mean(jnp.stack(losses))),
+                   "delta_norm": float(m["delta_norm"])}
         applied = state["applied"]
-        if san is not None:
-            # quarantined buffer rows -> traced events + counter (the
-            # masks are tiny (K,) vectors, synced with the losses above)
-            nonf = np.asarray(m["quarantine_nonfinite"])
-            outl = np.asarray(m["quarantine_outlier"])
-            norms = np.asarray(m["quarantine_norms"])
-            for i in np.nonzero((nonf | outl)[:len(entries)])[0]:
-                registry.counter("quarantined").inc()
-                w = entries[i].work
-                tracer.instant(
-                    "quarantine", now, parent=sched.last_flush_seq,
-                    cause="nonfinite" if nonf[i] else "norm-outlier",
-                    cid=int(w["cid"]),
-                    tier=None if w.get("tier") is None else int(w["tier"]),
-                    norm=float(norms[i]), flush=applied)
-        if topo is not None and entries:
-            # edge pre-reduce: this flush's rows grouped by uploader
-            # region — each edge's (size,) buffer is what it transmits
-            # upstream (billed on the edge_server hop at end of run).
-            # The authoritative server reduce above consumed the same
-            # rows fused, so the model path is topology-invariant.
-            regs = topo.region_of[[int(e.work["cid"]) for e in entries]]
-            ebuf = topo_lib.edge_reduce(
-                np.asarray(flat_deltas)[:len(entries)],
-                np.asarray(wts[:len(entries)], np.float32),
-                regs, topo.num_regions)
-            counts = np.bincount(regs, minlength=topo.num_regions)
-            for k in np.nonzero(counts)[0]:
-                registry.counter("edge_flushes").inc(label=int(k))
-                registry.counter("edge_up_bytes").inc(edge_bytes,
-                                                      label=int(k))
-                registry.counter("edge_down_bytes").inc(down_bytes,
-                                                        label=int(k))
-                tracer.instant("edge_flush", now,
-                               parent=sched.last_flush_seq, region=int(k),
-                               fill=int(counts[k]), up_bytes=edge_bytes,
-                               norm=float(np.linalg.norm(ebuf[k])),
-                               flush=applied)
+        with prof_lib.span("grid/bookkeeping"):
+            if san is not None:
+                _flush_quarantine(m, entries, registry, tracer, now,
+                                  sched.last_flush_seq, applied)
+            if topo is not None and entries:
+                _edge_reduce_flush(topo, flat_deltas, wts, entries,
+                                   registry, tracer, down_bytes,
+                                   edge_bytes, now, sched.last_flush_seq,
+                                   applied)
         state["applied"] = applied + 1
         if eval_fn and eval_every and state["applied"] % eval_every == 0:
-            out.update(eval_fn(part.merge(y_new, frozen)))
+            with prof_lib.span("grid/eval_fn"):
+                out.update(eval_fn(part.merge(y_new, frozen)))
         # a flush is the async "round": rotation/adaptive policies step
         # their tier maps here
         policy.end_round(applied)
@@ -965,13 +1022,14 @@ def _run_async(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
         # one boundary where run_pending() has resolved every lane cell
         if state["applied"] % grid.checkpoint_every != 0:
             return
-        meta, arrays = gstate_lib.encode_async(
-            state=state, sched=s, rngs=rngs, accountant=accountant,
-            policy=policy, registry=registry, shocks=bshocks, topo=topo)
-        path = gstate_lib.save_state(
-            gstate_lib.checkpoint_path(grid.checkpoint_dir,
-                                       state["applied"], "async"),
-            meta, arrays)
+        with prof_lib.span("grid/checkpoint"):
+            meta, arrays = gstate_lib.encode_async(
+                state=state, sched=s, rngs=rngs, accountant=accountant,
+                policy=policy, registry=registry, shocks=bshocks, topo=topo)
+            path = gstate_lib.save_state(
+                gstate_lib.checkpoint_path(grid.checkpoint_dir,
+                                           state["applied"], "async"),
+                meta, arrays)
         last_ckpt["path"] = path
         registry.counter("checkpoints").inc()
         tracer.instant("checkpoint", now, parent=s.last_flush_seq,

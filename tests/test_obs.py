@@ -4,8 +4,11 @@ off is bit-identical (sync) / lane-exact (async), and telemetry on emits
 a schema-valid stream whose virtual timestamps cross-check against
 GridResult's own totals."""
 import dataclasses
+import glob
 import json
 import math
+import os
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +17,7 @@ import pytest
 
 import repro.core.partition as part
 from repro.core import comm, fedpt
+from repro.core import flat as flat_lib
 from repro.data import synthetic as syn
 from repro.nn import basic
 from repro.obs import export as export_lib
@@ -206,20 +210,85 @@ def test_perfetto_track_layout():
     assert names[("thread_name", 1, 7)] == "client 7"
 
 
-def test_profiling_annotation_wrappers():
+def _host_spans(trace_dir):
+    """(name, start_ns, end_ns, stats) of every span on the host plane of
+    the one trace ``jax.profiler`` wrote under ``trace_dir``."""
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1, paths
+    pd = jax.profiler.ProfileData.from_file(paths[0])
+    out = []
+    with warnings.catch_warnings():
+        # the profiler's stats type warns on iteration (no __module__)
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in pd.planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return out
+
+
+def _check_nested(spans, parent, children, steps, after=()):
+    """Every ``parent`` span carries its ``step_num``, one per step in
+    order, and holds each of ``children`` at least once; every child span
+    lies inside a parent. ``after`` spans follow their parent step's
+    span and precede the next one."""
+    rounds = sorted((s for s in spans if s[0] == parent),
+                    key=lambda s: s[1])
+    assert [s[3].get("step_num") for s in rounds] == list(steps)
+    for name in children:
+        found = [s for s in spans if s[0] == name]
+        assert found, name
+        for s in found:
+            assert any(p[1] <= s[1] and s[2] <= p[2] for p in rounds), name
+        for p in rounds:
+            assert any(p[1] <= s[1] and s[2] <= p[2] for s in found), \
+                (name, p[3])
+    for name in after:
+        found = sorted((s for s in spans if s[0] == name),
+                       key=lambda s: s[1])
+        assert found, name
+        for s in found:
+            assert not any(p[1] <= s[1] < p[2] for p in rounds), name
+
+
+def test_profiling_annotation_wrappers(tmp_path):
+    """``annotate``/``annotate_map`` wrap calls, ``span`` and
+    ``round_span`` are plain profiler annotations: all land on the host
+    plane of a trace, with their args and step number."""
     calls = []
 
     def fn(x):
         calls.append(x)
         return x + 1
 
-    assert prof_lib.annotate(fn, "test", enabled=False) is fn
-    wrapped = prof_lib.annotate(fn, "test", enabled=True)
-    assert wrapped(2) == 3 and calls == [2]
-    m = prof_lib.annotate_map({"a": fn}, "test", enabled=False)
-    assert m["a"] is fn
-    m = prof_lib.annotate_map({"a": fn}, "test", enabled=True)
-    assert m["a"](5) == 6
+    wrapped = prof_lib.annotate(fn, "test/fn")
+    m = prof_lib.annotate_map({"a": fn}, "test/map")
+    assert wrapped(2) == 3 and m["a"](5) == 6 and calls == [2, 5]
+    with jax.profiler.trace(str(tmp_path)):
+        with prof_lib.round_span("test/round", 7):
+            assert wrapped(1) == 2 and m["a"](2) == 3
+            with prof_lib.span("test/span", clients=3) as sp:
+                sp.set_metadata(bytes=12)
+    spans = {s[0]: s for s in _host_spans(str(tmp_path))}
+    for name in ("test/round", "test/fn", "test/map[a]", "test/span"):
+        assert name in spans, name
+    assert spans["test/round"][3]["step_num"] == 7
+    assert spans["test/span"][3]["clients"] == 3
+    assert spans["test/span"][3]["bytes"] == 12
+    _check_nested(list(spans.values()), "test/round",
+                  ("test/fn", "test/map[a]", "test/span"), [7])
+
+
+SYNC_SPANS = ("grid/plan", "grid/cohort_batch", "grid/round_fn",
+              "grid/wait", "grid/bookkeeping", "grid/eval_fn",
+              "grid/checkpoint")
+ASYNC_SPANS = ("grid/restack", "grid/lane_step[None]", "grid/server_apply",
+               "grid/wait", "grid/bookkeeping", "grid/eval_fn")
 
 
 # ---------------------------------------------------------------------------
@@ -254,18 +323,83 @@ def test_async_telemetry_off_lane_exact():
         assert ha["staleness_mean"] == hb["staleness_mean"]
 
 
-def test_async_profile_annotations_run():
-    """TelemetryConfig(profile=True) wraps the jitted lane step and the
-    server apply in jax.profiler annotations — the run must behave
-    identically (same history), just with named profiler scopes."""
+def _eval(params):
+    return {"norm": float(jnp.sum(params["dense"]["kernel"] ** 2))}
+
+
+def test_sync_profile_spans_run(tmp_path):
+    """The sync round loop's wall-clock spans are always on: under a
+    profiler session each round is one grid/round (step_num = round)
+    holding every named child span, and the run is bit-identical to
+    one with no profiler session."""
     ds = make_ds()
-    gc = simgrid.GridConfig(mode="async", concurrency=4, goal_count=2)
-    ref = simgrid.run_grid(init_fn, loss_fn, ds, RC, 3, grid=gc, seed=1)
-    prof = simgrid.run_grid(
-        init_fn, loss_fn, ds, RC, 3, seed=1,
-        grid=dataclasses.replace(
-            gc, telemetry=trace_lib.TelemetryConfig(profile=True)))
+    gc = simgrid.GridConfig(fleet="pareto-mobile", over_selection=1.3,
+                            straggler_deadline=120.0, checkpoint_every=1,
+                            checkpoint_dir=str(tmp_path / "ckpt"))
+    kw = dict(grid=gc, seed=3, eval_every=1, eval_fn=_eval)
+    ref = simgrid.run_grid(init_fn, loss_fn, ds, RC, 3, **kw)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        prof = simgrid.run_grid(init_fn, loss_fn, ds, RC, 3, **kw)
     _assert_same_run(ref, prof)
+    assert ref.history == prof.history
+    spans = _host_spans(str(tmp_path / "trace"))
+    _check_nested(spans, "grid/round", SYNC_SPANS, [0, 1, 2])
+    batch = [s for s in spans if s[0] == "grid/cohort_batch"][0][3]
+    assert batch["clients"] == RC.clients_per_round
+    # images (C, tau, batch, 8, 8, 1) float32 + labels (C, tau, batch) int32
+    assert batch["bytes"] == RC.clients_per_round * RC.local_steps \
+        * RC.local_batch * (64 * 4 + 4)
+
+
+def test_async_profile_annotations_run(tmp_path):
+    """The async flush's wall-clock spans are always on: under a
+    profiler session each flush is one grid/flush (step_num = updates
+    applied before it) holding every named child span, each checkpoint
+    follows its flush, and the run is bit-identical to one with no
+    profiler session."""
+    ds = make_ds()
+    gc = simgrid.GridConfig(mode="async", concurrency=4, goal_count=2,
+                            checkpoint_every=1,
+                            checkpoint_dir=str(tmp_path / "ckpt"))
+    kw = dict(grid=gc, seed=1, eval_every=1, eval_fn=_eval)
+    ref = simgrid.run_grid(init_fn, loss_fn, ds, RC, 3, **kw)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        prof = simgrid.run_grid(init_fn, loss_fn, ds, RC, 3, **kw)
+    _assert_same_run(ref, prof)
+    assert ref.history == prof.history
+    spans = _host_spans(str(tmp_path / "trace"))
+    _check_nested(spans, "grid/flush", ASYNC_SPANS, [0, 1, 2],
+                  after=("grid/checkpoint",))
+
+
+def test_round_programs_carry_scopes():
+    """The round programs name their three stages with jax.named_scope:
+    the sync round, and the async lane step and buffered apply."""
+    rc = dataclasses.replace(RC, dp_clip_norm=1.0, dp_noise_multiplier=0.1,
+                             uplink_bits=8)
+    y = init_fn(0)
+    ds = make_ds()
+    batch, w = syn.cohort_batch(ds, np.arange(rc.clients_per_round),
+                                rc.local_steps, rc.local_batch,
+                                np.random.default_rng(0))
+    round_fn, sopt = fedpt.make_round_fn(loss_fn, rc)
+    key = jax.random.key(0)
+    text = jax.jit(round_fn).lower(y, sopt.init(y), {}, batch,
+                                   jnp.asarray(w), key).as_text(
+                                       debug_info=True)
+    for scope in (fedpt.SCOPE_CLIENT_STEP, fedpt.SCOPE_AGG_TAIL,
+                  fedpt.SCOPE_SERVER_APPLY):
+        assert scope in text, scope
+    lane = {k: v[:2] for k, v in batch.items()}
+    text = jax.jit(fedpt.make_lane_step(loss_fn, rc, 2)).lower(
+        y, {}, lane).as_text(debug_info=True)
+    assert fedpt.SCOPE_CLIENT_STEP in text
+    deltas = jnp.zeros((2, flat_lib.FlatLayout.of(y).size))
+    apply_fn = fedpt.make_buffered_apply(sopt)
+    text = jax.jit(apply_fn).lower(y, sopt.init(y), deltas,
+                                   jnp.ones(2)).as_text(debug_info=True)
+    for scope in (fedpt.SCOPE_AGG_TAIL, fedpt.SCOPE_SERVER_APPLY):
+        assert scope in text, scope
 
 
 # ---------------------------------------------------------------------------
