@@ -161,18 +161,15 @@ def flat_constrainer(mesh):
     return constrain_flat
 
 
-def cohort_constrainer(mesh):
-    """``constrain_batch_fn(tree)`` for SYNC-mode cohort inputs — the
-    input-plane twin of :func:`flat_constrainer`'s rule: every batch
-    leaf pins its leading (client/lane) axis to the data axes
-    (``("pod", "data")`` when both exist), so the cohort's microbatches
-    land data-parallel inside the jitted round instead of replicated
-    per device. Divisibility-guarded per leaf (a cohort that does not
-    divide the data axes replicates, exactly like
-    :func:`batch_sharding`); trailing dims always replicate.
-
-    Also applied to tier-grouped lane batches: the rule only names the
-    leading axis, so tier-sliced shapes share it unchanged."""
+def cohort_sharding(mesh):
+    """``sharding(x)``: the ``NamedSharding`` of one SYNC-mode cohort
+    input leaf — its leading (client/lane) axis on the data axes
+    (``("pod", "data")`` when both exist), trailing dims replicated.
+    Divisibility-guarded per leaf: a cohort that does not divide the
+    data axes replicates, exactly like :func:`batch_sharding`. The grid
+    uploads each round's batch with it, and :func:`cohort_constrainer`
+    pins the same layout inside the round program, so the upload is
+    never resharded."""
     dax = mesh_lib.data_axes(mesh)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     total = 1
@@ -180,15 +177,29 @@ def cohort_constrainer(mesh):
         total *= sizes[a]
     axes = dax if len(dax) > 1 else (dax[0] if dax else None)
 
+    def sharding(x):
+        if axes is not None and x.ndim >= 1 and x.shape[0] % total == 0:
+            return NamedSharding(mesh, P(axes, *([None] * (x.ndim - 1))))
+        return NamedSharding(mesh, P())
+
+    return sharding
+
+
+def cohort_constrainer(mesh):
+    """``constrain_batch_fn(tree)`` for SYNC-mode cohort inputs — the
+    input-plane twin of :func:`flat_constrainer`'s rule: every batch
+    leaf takes :func:`cohort_sharding`'s layout, so the cohort's
+    microbatches land data-parallel inside the jitted round instead of
+    replicated per device.
+
+    Also applied to tier-grouped lane batches: the rule only names the
+    leading axis, so tier-sliced shapes share it unchanged."""
+    sharding = cohort_sharding(mesh)
+
     def constrain_batch(tree):
-        def one(x):
-            if axes is not None and x.ndim >= 1 and x.shape[0] % total == 0:
-                spec = P(axes, *([None] * (x.ndim - 1)))
-            else:
-                spec = P()
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, spec))
-        return jax.tree_util.tree_map(one, tree)
+        return jax.tree_util.tree_map(
+            lambda x: jax.lax.with_sharding_constraint(x, sharding(x)),
+            tree)
 
     return constrain_batch
 

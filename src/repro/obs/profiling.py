@@ -14,10 +14,14 @@ The grid's names (``sim/grid.py``):
 * sync, one ``grid/round`` per round (``step_num`` = round index) with
   the children ``grid/plan`` (tier map, cohort selection,
   ``plan_sync_round``), ``grid/cohort_batch`` (the host batch and its
-  weights; args ``clients``, ``bytes``), ``grid/round_fn`` (the
-  dispatch), ``grid/wait`` (the round's one blocking host sync),
-  ``grid/bookkeeping`` (quarantine, billing, counters, the policy),
-  ``grid/eval_fn`` and ``grid/checkpoint``;
+  weights, put on the device; args ``clients``, ``bytes``),
+  ``grid/round_fn`` (the dispatch), ``grid/wait`` (the round's one
+  blocking host sync), ``grid/bookkeeping`` (quarantine, billing,
+  counters, the policy), ``grid/prefetch`` (arg ``round`` = r+1: round
+  r+1's ``grid/plan`` and ``grid/cohort_batch``, made while round r
+  runs), ``grid/eval_fn`` and ``grid/checkpoint``. Only the first round
+  of a call, and a round after one that could not look ahead, opens
+  its own ``grid/plan`` and ``grid/cohort_batch``;
 * async, one ``grid/flush`` per server update (``step_num`` = updates
   applied before it) with ``grid/restack`` (lane batches and the
   ``(K, size)`` buffer), ``grid/lane_step[k]``, ``grid/server_apply``,

@@ -509,6 +509,19 @@ def _bill_edges(report, topo, cohort_regions, plan, mc, tracer, down_bytes,
                    uploads=len(active))
 
 
+def _prefetch_next(r: int, rounds: int, grid: GridConfig, san) -> bool:
+    """Whether round r+1 is drawn and uploaded while round r runs, before
+    the host reads round r's result. Not when there is no round r+1, and
+    not when what is left of round r must read that result before round
+    r+1's draws: the quarantine screen (its instants read round r's
+    masks and parent on round r's span, before round r's billing), and a
+    checkpoint after round r (its snapshot holds the RNG states round
+    r+1 advances)."""
+    return (r + 1 < rounds and san is None
+            and not (grid.checkpoint_every > 0
+                     and (r + 1) % grid.checkpoint_every == 0))
+
+
 def _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
               fleet, report, down_bytes, up_bytes, compute_seconds,
               data_rng, dev_rng, seed, data_kind, eval_every, eval_fn, log,
@@ -552,88 +565,118 @@ def _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
             policy=policy, registry=registry, report=report,
             shocks=bshocks, topo=topo)
         last_ckpt = grid.resume_from
+    # each round's batch goes up in the layout the round program pins on
+    # the mesh (launch/sharding.cohort_sharding), so nothing reshards it
+    batch_sharding = shard_lib.cohort_sharding(mesh) if mesh else None
+
+    def prepare(r):
+        """Round r's host work before its dispatch: cohort, plan, batch,
+        weights, tier ids and key. Returns what round r's bookkeeping
+        reads and the round program's arguments after ``(y, sstate,
+        frozen)``, already on the device."""
+        with prof_lib.span("grid/plan"):
+            # the policy's tier map can move between rounds
+            # (tier-rotation, adaptive-capability); static policies
+            # return the bound map
+            tiers_now = (policy.current_tiers() if cplan is not None
+                         else None)
+            cids = policy.select_cohort(data_rng, m)
+            # tier-sliced uplink payloads + per-tier compute feed the
+            # virtual clock: a lite client's smaller delta clears the
+            # 0.25 MB/s uplink sooner AND its backward pass is cheaper
+            cohort_up = (tier_up[tiers_now[cids]] if cplan is not None
+                         else up_bytes)
+            cohort_comp = (tier_compute[tiers_now[cids]]
+                           if cplan is not None else compute_seconds)
+            cohort_regions = (topo.region_of[cids] if topo is not None
+                              else None)
+            plan = sched_lib.plan_sync_round(
+                fleet, cids, down_bytes, cohort_up, cohort_comp, C,
+                dev_rng, deadline=grid.straggler_deadline,
+                dynamics=dyn, dyn_rng=dyn_rng, now=vt, tracer=tracer,
+                tiers=tiers_now[cids] if cplan is not None else None,
+                faults=bfaults, shocks=bshocks, regions=cohort_regions)
+            # the C slots the compiled round engine sees: participants
+            # in arrival order, padded (weight 0) with the remaining
+            # cohort in dispatch order when drops leave the round short
+            kept_cids = plan.participant_cids()
+            pad = plan.cids[~plan.participant][:C - len(kept_cids)]
+            sel = np.concatenate([kept_cids, pad]).astype(np.int64)
+            kept = np.arange(C) < len(kept_cids)
+
+        with prof_lib.span("grid/cohort_batch", clients=len(sel)) as sp:
+            batch, w = syn.cohort_batch(dataset, sel, rc.local_steps,
+                                        rc.local_batch, data_rng,
+                                        kind=data_kind)
+            w = np.where(kept, w, 0.0).astype(np.float32)
+            if not policy.trivial and not (rc.uniform_weights
+                                           or rc.dp_clip_norm > 0):
+                # importance-unbiased selection weights multiply into
+                # the aggregation weights; under DP the engine forces
+                # uniform weighting with a fixed denominator (sigma
+                # calibration), so the correction is dropped there by
+                # design
+                iw = policy.cohort_weights(sel)
+                if iw is not None:
+                    w = (w * iw).astype(np.float32)
+            sp.set_metadata(bytes=sum(int(v.nbytes)
+                                      for v in batch.values()))
+            batch = jax.device_put(
+                batch, None if batch_sharding is None else
+                jax.tree_util.tree_map(batch_sharding, batch))
+            args = (batch, jnp.asarray(w))
+            if tiered:
+                args += (jnp.asarray(tiers_now[sel], jnp.int32),)
+            args += (jax.random.key(seed * 100_003 + r),)
+        return plan, sel, kept_cids, tiers_now, cohort_regions, args
+
     t0 = None
+
+    def wait(rmetrics) -> float:
+        """The round's one blocking host sync: its loss."""
+        nonlocal t0
+        with prof_lib.span("grid/wait"):
+            if t0 is None:
+                jax.block_until_ready(rmetrics)
+                t0 = time.time()  # exclude compile from the timing
+            return float(rmetrics["loss"])
+
+    ahead_inputs = None
     for r in range(start_round, rounds):
         # wall-clock spans (obs/profiling.py): the round's host work in
         # named children of grid/round
         with prof_lib.round_span("grid/round", r):
-            if bfaults is not None and vt > bfaults.kill_at:
-                raise faults_lib.ServerKilled(at=vt, applied=r,
-                                              checkpoint=last_ckpt)
-            with prof_lib.span("grid/plan"):
-                # the policy's tier map can move between rounds
-                # (tier-rotation, adaptive-capability); static policies
-                # return the bound map
-                tiers_now = (policy.current_tiers() if cplan is not None
-                             else None)
-                cids = policy.select_cohort(data_rng, m)
-                # tier-sliced uplink payloads + per-tier compute feed the
-                # virtual clock: a lite client's smaller delta clears the
-                # 0.25 MB/s uplink sooner AND its backward pass is cheaper
-                cohort_up = (tier_up[tiers_now[cids]] if cplan is not None
-                             else up_bytes)
-                cohort_comp = (tier_compute[tiers_now[cids]]
-                               if cplan is not None else compute_seconds)
-                cohort_regions = (topo.region_of[cids] if topo is not None
-                                  else None)
-                plan = sched_lib.plan_sync_round(
-                    fleet, cids, down_bytes, cohort_up, cohort_comp, C,
-                    dev_rng, deadline=grid.straggler_deadline,
-                    dynamics=dyn, dyn_rng=dyn_rng, now=vt, tracer=tracer,
-                    tiers=tiers_now[cids] if cplan is not None else None,
-                    faults=bfaults, shocks=bshocks, regions=cohort_regions)
-                # the C slots the compiled round engine sees: participants
-                # in arrival order, padded (weight 0) with the remaining
-                # cohort in dispatch order when drops leave the round short
-                kept_cids = plan.participant_cids()
-                pad = plan.cids[~plan.participant][:C - len(kept_cids)]
-                sel = np.concatenate([kept_cids, pad]).astype(np.int64)
-                kept = np.arange(C) < len(kept_cids)
-
-            with prof_lib.span("grid/cohort_batch", clients=len(sel)) as sp:
-                batch, w = syn.cohort_batch(dataset, sel, rc.local_steps,
-                                            rc.local_batch, data_rng,
-                                            kind=data_kind)
-                w = np.where(kept, w, 0.0).astype(np.float32)
-                if not policy.trivial and not (rc.uniform_weights
-                                               or rc.dp_clip_norm > 0):
-                    # importance-unbiased selection weights multiply into
-                    # the aggregation weights; under DP the engine forces
-                    # uniform weighting with a fixed denominator (sigma
-                    # calibration), so the correction is dropped there by
-                    # design
-                    iw = policy.cohort_weights(sel)
-                    if iw is not None:
-                        w = (w * iw).astype(np.float32)
-                sp.set_metadata(bytes=sum(int(v.nbytes)
-                                          for v in batch.values()))
+            if ahead_inputs is None:
+                if bfaults is not None and vt > bfaults.kill_at:
+                    raise faults_lib.ServerKilled(at=vt, applied=r,
+                                                  checkpoint=last_ckpt)
+                ahead_inputs = prepare(r)
+            (plan, sel, kept_cids, tiers_now, cohort_regions,
+             args), ahead_inputs = ahead_inputs, None
 
             with prof_lib.span("grid/round_fn"):
-                args = (y, sstate, frozen, batch, jnp.asarray(w))
-                if tiered:
-                    args += (jnp.asarray(tiers_now[sel], jnp.int32),)
-                y, sstate, rmetrics = round_fn(
-                    *args, jax.random.key(seed * 100_003 + r))
+                y, sstate, rmetrics = round_fn(y, sstate, frozen, *args)
 
-            with prof_lib.span("grid/wait"):
-                if t0 is None:
-                    jax.block_until_ready(y)
-                    t0 = time.time()  # exclude compile from the timing
-                # the round's one blocking host sync
-                loss = float(rmetrics["loss"])
+            # one-round lookahead: round r+1's host work runs while the
+            # device runs round r, unless round r must be read first
+            ahead = _prefetch_next(r, rounds, grid, san)
+            loss = None if ahead else wait(rmetrics)
 
             with prof_lib.span("grid/bookkeeping"):
                 vt0, vt = vt, vt + plan.round_seconds
                 # the round span goes out as soon as its wall time is
                 # known — before the quarantine/billing/edge instants it
-                # causally precedes, so they can parent on it. Its own
-                # parent is the upload that closed the round
-                # (plan.bound_seq), which links round -> bounding upload
-                # -> dispatch for analyze.py's critical-path walk.
+                # causally precedes, and before round r+1's plan records,
+                # so they can parent on it; a round read ahead of its
+                # loss gets the loss into the same record after the
+                # wait. Its own parent is the upload that closed the
+                # round (plan.bound_seq), which links round -> bounding
+                # upload -> dispatch for analyze.py's critical-path walk.
                 rseq = tracer.span("round", vt0, plan.round_seconds,
                                    parent=plan.bound_seq, round=r,
                                    participants=float(len(kept_cids)),
                                    cohort=int(m), loss=loss)
+                round_rec = tracer.events[-1] if tracer.enabled else None
                 if san is not None:
                     _sync_quarantine(rmetrics, sel, tiers_now, cplan, mc,
                                      tracer, vt0, rseq, r)
@@ -670,6 +713,17 @@ def _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                 mc("retries").inc(plan.retries)
                 mc("crashes").inc(plan.crashes)
                 policy.end_round(r)
+
+            if ahead:
+                with prof_lib.span("grid/prefetch", round=r + 1):
+                    # a kill due before round r+1 raises at its start,
+                    # after round r's record
+                    if bfaults is None or vt <= bfaults.kill_at:
+                        ahead_inputs = prepare(r + 1)
+                        mc("prefetched_rounds").inc()
+                loss = wait(rmetrics)
+                if round_rec is not None:
+                    round_rec.payload["loss"] = loss
 
             rec = {"round": r, "loss": loss}
             if eval_fn and eval_every and (r + 1) % eval_every == 0:

@@ -327,11 +327,13 @@ def _eval(params):
     return {"norm": float(jnp.sum(params["dense"]["kernel"] ** 2))}
 
 
-def test_sync_profile_spans_run(tmp_path):
+def test_sync_profile_spans_run(tmp_path, monkeypatch):
     """The sync round loop's wall-clock spans are always on: under a
     profiler session each round is one grid/round (step_num = round)
     holding every named child span, and the run is bit-identical to
-    one with no profiler session."""
+    one with no profiler session. With the lookahead on, each round r
+    but the last holds a grid/prefetch (arg ``round`` = r+1) holding
+    round r+1's grid/plan and grid/cohort_batch."""
     ds = make_ds()
     gc = simgrid.GridConfig(fleet="pareto-mobile", over_selection=1.3,
                             straggler_deadline=120.0, checkpoint_every=1,
@@ -349,6 +351,58 @@ def test_sync_profile_spans_run(tmp_path):
     # images (C, tau, batch, 8, 8, 1) float32 + labels (C, tau, batch) int32
     assert batch["bytes"] == RC.clients_per_round * RC.local_steps \
         * RC.local_batch * (64 * 4 + 4)
+    # checkpoints after every round: the loop never looks ahead
+    assert not [s for s in spans if s[0] == "grid/prefetch"]
+
+    # without checkpoints round r+1's plan and batch run inside round
+    # r's grid/prefetch, and every round's batch reaches the round
+    # program already on the device
+    gc = dataclasses.replace(gc, checkpoint_every=0, checkpoint_dir=None)
+    kw = dict(kw, grid=gc)
+    ref = simgrid.run_grid(init_fn, loss_fn, ds, RC, 3, **kw)
+    batches = []
+
+    class _JitSpy:
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+        def jit(self, fn, **jit_kw):
+            compiled = jax.jit(fn, **jit_kw)
+
+            def call(*args):
+                batches.append(args[3])
+                return compiled(*args)
+            return call
+
+    with monkeypatch.context() as mp:
+        mp.setattr(simgrid, "jax", _JitSpy())
+        with jax.profiler.trace(str(tmp_path / "trace_ahead")):
+            prof = simgrid.run_grid(init_fn, loss_fn, ds, RC, 3, **kw)
+    _assert_same_run(ref, prof)
+    assert ref.history == prof.history
+    assert len(batches) == 3
+    for b in batches:
+        for leaf in jax.tree_util.tree_leaves(b):
+            assert isinstance(leaf, jax.Array)
+            assert leaf.devices() <= set(jax.devices())
+    spans = _host_spans(str(tmp_path / "trace_ahead"))
+    rounds = sorted((s for s in spans if s[0] == "grid/round"),
+                    key=lambda s: s[1])
+    ahead = sorted((s for s in spans if s[0] == "grid/prefetch"),
+                   key=lambda s: s[1])
+    assert [s[3]["round"] for s in ahead] == [1, 2]
+    for r, s in enumerate(ahead):
+        assert rounds[r][1] <= s[1] and s[2] <= rounds[r][2]
+        assert rounds[r][3]["step_num"] == r
+    for name in ("grid/plan", "grid/cohort_batch"):
+        found = sorted((s for s in spans if s[0] == name),
+                       key=lambda s: s[1])
+        assert len(found) == 3, name
+        # round 0 prepares itself; rounds 1 and 2 were prepared ahead
+        assert rounds[0][1] <= found[0][1] and found[0][2] <= rounds[0][2]
+        assert not any(a[1] <= found[0][1] < a[2] for a in ahead)
+        for s, a in zip(found[1:], ahead):
+            assert a[1] <= s[1] and s[2] <= a[2], name
 
 
 def test_async_profile_annotations_run(tmp_path):

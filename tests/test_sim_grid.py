@@ -3,6 +3,7 @@ loop, byte-exact wire metering, straggler/dropout handling, and buffered
 async aggregation with staleness weighting."""
 import dataclasses
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -544,3 +545,136 @@ def test_summarize_delegates_to_comm_report():
     y, z = part.partition(params, spec)
     assert s["comm_reduction"] == comm.report_for(y, z).reduction
     assert s["trainable_bytes"] == basic.tree_bytes(y)
+
+
+# ---------------------------------------------------------------------------
+# One-round lookahead: round r+1's host work runs while round r runs, and
+# changes nothing a run reports
+
+
+def _lookahead_cases():
+    from repro.sim import dynamics as dyn_lib
+    from repro.sim import selection as sel_lib
+    base = dict(fleet="pareto-mobile", over_selection=1.5,
+                straggler_deadline=120.0, telemetry="memory")
+    return {
+        "plain": dict(base),
+        # the policy re-tiers from the RTTs round r observes, which round
+        # r+1's plan reads
+        "tiered": dict(base, plan=TIER_PLAN,
+                       selection=sel_lib.AdaptiveCapabilityPolicy(
+                           refit_every=2)),
+        # region shocks draw inside the plan; crash faults add the kill
+        # check to every round
+        "topology": dict(base, topology=3, faults={"crash_compute": 0.1},
+                         dynamics=dyn_lib.DynamicsConfig(
+                             shocks=dyn_lib.RegionShocks(
+                                 every=0.005, duration=0.05,
+                                 residual=0.0))),
+    }
+
+
+def _records(res):
+    return [(e.kind, e.seq, e.parent, e.t, e.dur, list(e.payload.items()))
+            for e in res.telemetry.events]
+
+
+@pytest.mark.parametrize("case", ["plain", "tiered", "topology"])
+def test_sync_lookahead_bit_identical(case, monkeypatch):
+    """The lookahead run equals the same run with the lookahead forced
+    off: weights, history, virtual clock, registry and wire ledger, and
+    the tracer's records in kind, order, seq, parent and payload."""
+    ds = make_ds()
+    rounds = 5
+
+    def run():
+        return simgrid.run_grid(
+            init_fn, loss_fn, ds, RC, rounds, seed=3,
+            grid=simgrid.GridConfig(**_lookahead_cases()[case]))
+
+    ahead = run()
+    monkeypatch.setattr(simgrid, "_prefetch_next", lambda *a: False)
+    plain = run()
+    _assert_same_run(ahead, plain)
+    assert ahead.history == plain.history
+    assert ahead.virtual_seconds == plain.virtual_seconds
+    assert ahead.tier_stats == plain.tier_stats
+    assert ahead.comm.tier_traffic == plain.comm.tier_traffic
+    assert ahead.comm.hop_traffic == plain.comm.hop_traffic
+    snap_a, snap_p = ahead.metrics.snapshot(), plain.metrics.snapshot()
+    assert snap_a["counters"].pop("prefetched_rounds")["value"] \
+        == rounds - 1
+    assert "prefetched_rounds" not in snap_p["counters"]
+    assert snap_a == snap_p
+    assert _records(ahead) == _records(plain)
+    assert all(e.payload["loss"] == h["loss"] for e, h in zip(
+        ahead.telemetry.of_kind("round"), ahead.history))
+
+
+@pytest.mark.parametrize("extra", [dict(sanitize=True),
+                                   dict(checkpoint_every=1)],
+                         ids=["sanitize", "checkpoint"])
+def test_sync_lookahead_waits_when_round_must_be_read(extra, tmp_path):
+    """The quarantine screen and a checkpoint after every round read
+    round r's result before round r+1 may be drawn: the loop never
+    looks ahead there."""
+    if "checkpoint_every" in extra:
+        extra = dict(extra, checkpoint_dir=str(tmp_path / "ckpt"))
+    res = simgrid.run_grid(init_fn, loss_fn, make_ds(), RC, 4, seed=3,
+                           grid=simgrid.GridConfig(**extra))
+    assert res.metrics.counter("prefetched_rounds").value == 0
+    assert len(res.history) == 4
+
+
+def test_sync_lookahead_kill_raises_after_round_record(tmp_path,
+                                                      monkeypatch):
+    """A kill due before round r+1 (seen by round r's lookahead) still
+    raises at round r+1's start, after round r, with the same position
+    and checkpoint as with the lookahead forced off."""
+    from repro.sim import faults as faults_lib
+    ds = make_ds()
+    gc = simgrid.GridConfig(fleet="pareto-mobile")
+    straight = simgrid.run_grid(init_fn, loss_fn, ds, RC, 6, grid=gc,
+                                seed=3)
+    T = 0.5 * (straight.history[2]["virtual_seconds"]
+               + straight.history[3]["virtual_seconds"])
+
+    def kill(sub):
+        # checkpoints after rounds 2 and 5: round 3 looks ahead
+        killed = dataclasses.replace(
+            gc, faults={"server_kill_at": T}, checkpoint_every=3,
+            checkpoint_dir=str(tmp_path / sub))
+        with pytest.raises(faults_lib.ServerKilled) as ei:
+            simgrid.run_grid(init_fn, loss_fn, ds, RC, 6, grid=killed,
+                             seed=3)
+        return ei.value
+
+    ahead = kill("ahead")
+    monkeypatch.setattr(simgrid, "_prefetch_next", lambda *a: False)
+    plain = kill("plain")
+    assert ahead.applied == plain.applied == 4
+    assert ahead.at == plain.at == straight.history[3]["virtual_seconds"]
+    assert os.path.basename(ahead.checkpoint) \
+        == os.path.basename(plain.checkpoint)
+
+
+def test_sync_lookahead_checkpoint_resume_bitwise(tmp_path):
+    """A snapshot taken between looked-ahead rounds holds the RNG and
+    policy state before the next round's draws: resuming from a
+    mid-run checkpoint reproduces the straight run."""
+    from repro.checkpoint import grid_state as gstate
+    ds = make_ds()
+    gc = simgrid.GridConfig(fleet="pareto-mobile", over_selection=1.5,
+                            checkpoint_every=2,
+                            checkpoint_dir=str(tmp_path / "ckpt"))
+    straight = simgrid.run_grid(init_fn, loss_fn, ds, RC, 6, grid=gc,
+                                seed=3)
+    assert straight.metrics.counter("prefetched_rounds").value == 3
+    resumed = simgrid.run_grid(
+        init_fn, loss_fn, ds, RC, 6, seed=3,
+        grid=dataclasses.replace(
+            gc, checkpoint_dir=str(tmp_path / "again"),
+            resume_from=gstate.checkpoint_path(str(tmp_path / "ckpt"), 2,
+                                               "sync")))
+    _assert_same_run(straight, resumed)
+    assert straight.history == resumed.history
