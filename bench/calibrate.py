@@ -114,12 +114,11 @@ def program_readings(cell, seed: int, precision: str, data, host0,
     clock = harness.Clock(math.inf, 10 ** 9, trainable, None)
     with harness.program_precision(precision):
         simgrid.run_grid(
-            lambda _seed: params,
-            harness.loss_fn_for(cell.model.program_forward()), data,
+            lambda _seed: params, cell.model.program_loss(), data,
             harness.round_config(cell.mix), harness.CHECK_STEPS,
             grid=harness.grid_config(cell.mix, False),
-            freeze_spec=tuple(cell.freeze), seed=seed, eval_every=1,
-            eval_fn=clock)
+            freeze_spec=tuple(cell.freeze), seed=seed,
+            data_kind=cell.model.TASK.kind, eval_every=1, eval_fn=clock)
     return clock.captured
 
 
@@ -157,7 +156,7 @@ def main(argv=None) -> int:
     extra_r = [p for p in args.reference_precisions.split(",") if p]
     for seed in [int(s) for s in args.seeds.split(",")]:
         t = time.time()
-        data = harness.make_data(cell.cfg, seed)
+        data = cell.model.TASK.make(cell.cfg, seed)
         params = harness.make_params(cell, seed)
         trainable = harness.trainable_paths(cell, params)
         host0 = {p: np.asarray(v)
@@ -183,10 +182,10 @@ def main(argv=None) -> int:
         frozen = {p: v for p, v in host0.items() if p not in y0}
         rec = {"cell": cell.name, "seed": seed, "precision": precision,
                "client_lr": cell.mix["client_lr"]}
+        test = cell.model.TASK.test(data)
         for name, got in runs.items():
             rec[name] = ref_lib.compare(cell.model, cell.cfg, y0, frozen,
-                                        got, ys, noise, data.test_images,
-                                        data.test_labels)
+                                        got, ys, noise, test)
         rec["seconds"] = time.time() - t
         line = json.dumps(rec)
         print(line, flush=True)

@@ -9,6 +9,7 @@ such a dict into the nested tree the program's forward takes.
 from __future__ import annotations
 
 import contextlib
+import functools
 from typing import Dict, List, Sequence, Tuple
 
 import jax
@@ -74,6 +75,11 @@ def conv(x, w, stride: int, dtype):
 def dense(x, w, b, dtype):
     return _rounded(jnp.dot)(x.astype(dtype), w.astype(dtype)) + b.astype(
         dtype)
+
+
+def einsum(spec: str, a, b, dtype):
+    return _rounded(functools.partial(jnp.einsum, spec))(a.astype(dtype),
+                                                         b.astype(dtype))
 
 
 def groupnorm(x, scale, bias, groups: int, eps: float, dtype):

@@ -10,7 +10,9 @@ import jax
 import jax.numpy as jnp
 
 import counters
-from configs import common
+from configs import common, tasks
+
+TASK = tasks.IMAGES_TASK
 
 
 def _widths(cfg):
@@ -62,3 +64,17 @@ def reference_logits(p, x, cfg, dtype):
 def program_forward():
     from repro.models import paper_models
     return paper_models.emnist_cnn_forward
+
+
+def program_loss():
+    return tasks.classifier_loss(program_forward())
+
+
+def reference_loss(p, batch, cfg, dtype):
+    return tasks.cross_entropy(
+        reference_logits(p, batch["images"], cfg, dtype), batch["labels"])
+
+
+def small(cfg):
+    return dict(cfg, clients=16, examples_per_client=40, test_examples=64,
+                conv_channels=[4, 8], dense_width=32)

@@ -13,7 +13,9 @@ import jax
 import jax.numpy as jnp
 
 import counters
-from configs import common
+from configs import common, tasks
+
+TASK = tasks.IMAGES_TASK
 
 
 def _blocks(cfg):
@@ -97,3 +99,17 @@ def reference_logits(p, x, cfg, dtype):
 def program_forward():
     from repro.models import paper_models
     return paper_models.resnet18_forward
+
+
+def program_loss():
+    return tasks.classifier_loss(program_forward())
+
+
+def reference_loss(p, batch, cfg, dtype):
+    return tasks.cross_entropy(
+        reference_logits(p, batch["images"], cfg, dtype), batch["labels"])
+
+
+def small(cfg):
+    return dict(cfg, clients=16, examples_per_client=40, test_examples=64,
+                stem_channels=8, stages=[[8, 1], [16, 2], [64, 2], [64, 2]])

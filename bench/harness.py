@@ -4,7 +4,10 @@ A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration (``bench/configs/<name>.json`` with its module beside it)
 and a traffic mix (``bench/mixes/<traffic>.json``); its limits are in
 ``bench/limits/<cell>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``. Nothing here names a cell.
+``bench/metrics/<metric>.py``. Nothing here names a cell, and nothing
+here knows what an example holds: the configuration's module makes the
+data and states the loss (``bench/configs/tasks.py`` lists what it
+defines).
 
 A run makes the data and the weights from the seed on the device, then
 makes ONE ``repro.sim.grid.run_grid`` call, the entry every user of the
@@ -19,7 +22,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import gc
 import glob
 import importlib.util
@@ -29,10 +31,9 @@ import statistics
 import sys
 import tempfile
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 import counters
@@ -105,63 +106,7 @@ def load_cell(name: str, benchmark: Optional[dict] = None) -> Cell:
 
 
 # ---------------------------------------------------------------------------
-# inputs from the seed
-
-
-CHUNK = 50       # clients made per call, so set-up holds little HBM
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
-def _make_images(key, protos, clients, examples, alpha, noise):
-    """``clients`` clients' images and labels: Dirichlet(alpha) label
-    skew, each image its class prototype plus N(0, noise^2)."""
-    kd, kl, kn = jax.random.split(key, 3)
-    classes = protos.shape[0]
-    p = jax.random.dirichlet(kd, jnp.full((classes,), alpha, jnp.float32),
-                             (clients,))
-    labels = jax.random.categorical(kl, jnp.log(p + 1e-30)[:, None, :],
-                                    shape=(clients, examples))
-    images = protos[labels] + noise * jax.random.normal(
-        kn, (clients, examples) + protos.shape[1:], jnp.float32)
-    return images, labels.astype(jnp.int32)
-
-
-@dataclasses.dataclass
-class Images:
-    """The population, host-side, in the layout ``run_grid`` reads:
-    ``client_images[c]`` is (n, H, W, C) float32, ``client_labels[c]``
-    (n,) int32."""
-    client_images: list
-    client_labels: list
-    test_images: np.ndarray
-    test_labels: np.ndarray
-
-    @property
-    def num_clients(self) -> int:
-        return len(self.client_images)
-
-
-def make_data(cfg: dict, seed: int) -> Images:
-    key = jax.random.fold_in(jax.random.key(seed), 1)
-    kp, kt, kc = jax.random.split(key, 3)
-    shape = tuple(cfg["image_shape"])
-    protos = jax.random.normal(kp, (cfg["num_classes"],) + shape,
-                               jnp.float32)
-    alpha, noise = float(cfg["label_dirichlet_alpha"]), float(
-        cfg["image_noise"])
-    n, ex = cfg["clients"], cfg["examples_per_client"]
-    images = np.empty((n, ex) + shape, np.float32)
-    labels = np.empty((n, ex), np.int32)
-    for c0 in range(0, n, CHUNK):
-        k = min(CHUNK, n - c0)
-        im, lb = _make_images(jax.random.fold_in(kc, c0), protos, CHUNK, ex,
-                              alpha, noise)
-        images[c0:c0 + k], labels[c0:c0 + k] = np.asarray(im)[:k], \
-            np.asarray(lb)[:k]
-    timages, tlabels = _make_images(kt, protos, 1, cfg["test_examples"],
-                                    alpha, noise)
-    return Images(list(images), list(labels), np.asarray(timages)[0],
-                  np.asarray(tlabels)[0])
+# the weights from the seed (the data is the configuration module's)
 
 
 def make_params(cell: Cell, seed: int):
@@ -178,13 +123,6 @@ class _Hashable(dict):
 
 # ---------------------------------------------------------------------------
 # the program under test
-
-
-def loss_fn_for(forward: Callable):
-    def loss(params, b):
-        return ref_lib.cross_entropy(forward(params, b["images"]),
-                                     b["labels"]), {}
-    return loss
 
 
 def round_config(mix: dict):
@@ -300,8 +238,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     import jax.monitoring as monitoring
     from repro.sim import grid as simgrid
 
-    mix, cfg = cell.mix, cell.cfg
-    data = make_data(cfg, seed)
+    mix, cfg, model = cell.mix, cell.cfg, cell.model
+    data = model.TASK.make(cfg, seed)
     params = make_params(cell, seed)
     flat0 = common.flatten(params)
     trainable = trainable_paths(cell, params)
@@ -313,11 +251,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     try:
         with program_precision(cfg["matmul_precision"]):
             simgrid.run_grid(
-                lambda _seed: params,
-                loss_fn_for(cell.model.program_forward()), data,
+                lambda _seed: params, model.program_loss(), data,
                 round_config(mix), 10 ** 9, grid=grid_config(mix, trace),
-                freeze_spec=tuple(cell.freeze), seed=seed, eval_every=1,
-                eval_fn=clock)
+                freeze_spec=tuple(cell.freeze), seed=seed,
+                data_kind=model.TASK.kind, eval_every=1, eval_fn=clock)
         raise RuntimeError("run_grid returned before the window closed")
     except WindowClosed:
         pass
@@ -369,7 +306,7 @@ def p95(values) -> float:
 COMPARED = ("loss_gap", "grad_gap", "change_gap")
 
 
-def check(cell: Cell, data: Images, host0: dict, trainable: List[str],
+def check(cell: Cell, data, host0: dict, trainable: List[str],
           captured, seed: int) -> Dict[str, dict]:
     """The reference's first ``CHECK_STEPS`` rounds against the weights
     the window's own call produced; each number with its limit."""
@@ -397,7 +334,7 @@ def is_correct(checks: Dict[str, dict]) -> bool:
         for c in checks.values())
 
 
-def readings_against(cell: Cell, data: Images, host0: dict,
+def readings_against(cell: Cell, data, host0: dict,
                      trainable: List[str], captured, seed: int) -> dict:
     ref = ref_lib.Reference(cell.model, cell.cfg, cell.mix, data, host0,
                             trainable, seed)
@@ -405,7 +342,7 @@ def readings_against(cell: Cell, data: Images, host0: dict,
     y0 = {p: host0[p] for p in trainable}
     frozen = {p: v for p, v in host0.items() if p not in y0}
     return ref_lib.compare(cell.model, cell.cfg, y0, frozen, captured, ys,
-                           noise, data.test_images, data.test_labels)
+                           noise, cell.model.TASK.test(data))
 
 
 # ---------------------------------------------------------------------------

@@ -8,12 +8,14 @@ simulation promises for a synchronous round on a uniform fleet
 * cohort and minibatches from ``numpy.random.default_rng(seed + 77)``:
   each round draws ``choice(N, cohort, replace=False)``, then for each
   client in that order ``integers(0, n_client, (local_steps, batch))``;
-* every client runs ``local_steps`` of SGD on the trainable leaves, the
-  frozen ones held fixed, and uploads its delta;
+* every client runs ``local_steps`` of SGD of the configuration's
+  ``reference_loss`` on the trainable leaves, the frozen ones held
+  fixed, and uploads its delta;
 * with an int-k uplink, each (client, leaf) is fake-quantized with the
   scale max|delta| / (2^(k-1) - 1); with a DP clip the row is scaled to
   norm at most the clip; the mean is over the cohort (fixed denominator
-  under DP, example-count weights otherwise);
+  under DP, example-count weights otherwise), summed in cohort order as
+  each client's delta is computed;
 * DP noise ``sigma * normal(key(seed * 100003 + r), (size,))`` with
   ``sigma = z * clip / cohort`` over the flat buffer: leaves in the
   pytree order of nested dicts (sorted keys), each padded to 1024;
@@ -63,24 +65,21 @@ def fake_quantize(v, bits: int):
     return jnp.clip(jnp.round(v / s), -qmax, qmax) * s
 
 
-def cross_entropy(logits, labels):
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
-
-
 class Reference:
     """``steps`` synchronous rounds from the benchmark's own weights.
 
-    ``fault`` plants one of the faults the comparison must catch, in the
-    reference put in the program's place: ``"unchanged"`` (the step
-    returns its state), ``"halfbatch"`` (each minibatch's mean loss over
-    its first half only), ``"altered"`` (the first trainable leaf's
-    update applied twice)."""
+    ``model`` is the configuration's module: its ``reference_loss`` and
+    its ``TASK``'s ``examples`` and ``batch`` are all the reference knows
+    of the task. ``fault`` plants one of the faults the comparison must
+    catch, in the reference put in the program's place: ``"unchanged"``
+    (the step returns its state), ``"halfbatch"`` (each minibatch's mean
+    loss over the first half of its examples only), ``"altered"`` (the
+    first trainable leaf's update applied twice)."""
 
     def __init__(self, model, cfg: dict, mix: dict, data, params0: dict,
                  trainable: Sequence[str], seed: int,
                  precision: str = "highest", fault: Optional[str] = None):
-        self.mix, self.precision = mix, precision
+        self.model, self.mix, self.precision = model, mix, precision
         self.data, self.seed, self.fault = data, seed, fault
         self.trainable = list(trainable)
         self.y0 = {p: np.asarray(params0[p], np.float32)
@@ -93,42 +92,53 @@ class Reference:
         lr = mix["client_lr"]
         half = fault == "halfbatch"
 
-        def loss(y, z, x, labels):
-            if half:
-                x, labels = x[:x.shape[0] // 2], labels[:labels.shape[0] // 2]
-            logits = model.reference_logits({**y, **z}, x, cfg,
-                                            jnp.float32)
-            return cross_entropy(logits, labels)
+        def loss(y, z, batch):
+            return model.reference_loss({**y, **z}, batch, cfg, jnp.float32)
 
-        def client(y, z, xs, ls):
+        def client(y, z, batch):
             def step(yy, mb):
-                g = jax.grad(loss)(yy, z, *mb)
+                if half:
+                    mb = jax.tree.map(lambda v: v[:v.shape[0] // 2], mb)
+                g = jax.grad(loss)(yy, z, mb)
                 return {k: yy[k] - lr * g[k] for k in yy}, None
-            yt, _ = jax.lax.scan(step, y, (xs, ls))
+            yt, _ = jax.lax.scan(step, y, batch)
             return {k: yt[k] - y[k] for k in y}
 
         self._client = jax.jit(client)
+        self._fold = jax.jit(self._fold_delta)
         self._server = jax.jit(self._server_step)
 
-    def _server_step(self, y, m, mn, nchange, deltas, weights, key):
-        """Quantize, clip, mean, noise and the server optimizer, for one
-        round: ``deltas`` maps each path to its (cohort, ...) stack."""
-        mix = self.mix
-        bits, clip, K = mix["uplink_bits"], mix["dp_clip_norm"], mix["cohort"]
+    def _fold_delta(self, acc, delta, weight):
+        """One client's upload added into the round's sum: each leaf
+        fake-quantized with an int-k uplink; under a DP clip, the whole
+        delta scaled to norm at most the clip, else weighted by the
+        client's example count."""
+        bits, clip = self.mix["uplink_bits"], self.mix["dp_clip_norm"]
         if bits:
-            deltas = {k: jax.vmap(lambda v: fake_quantize(v, bits))(d)
-                      for k, d in deltas.items()}
-        w = weights
+            delta = {k: fake_quantize(d, bits) for k, d in delta.items()}
         if clip > 0:
-            sq = sum(jnp.sum(d.reshape(K, -1) ** 2, axis=1)
-                     for d in deltas.values())
-            w = jnp.ones((K,), jnp.float32) * jnp.minimum(
-                1.0, clip / jnp.maximum(jnp.sqrt(sq), 1e-12))
-            wsum = float(K)
-        else:
-            wsum = jnp.sum(weights)
-        agg = {k: jnp.tensordot(w, d, axes=1) / wsum
-               for k, d in deltas.items()}
+            sq = sum(jnp.sum(d ** 2) for d in delta.values())
+            weight = jnp.minimum(1.0, clip / jnp.maximum(jnp.sqrt(sq),
+                                                         1e-12))
+        return {k: acc[k] + weight * delta[k] for k in acc}
+
+    def aggregate(self, uploads):
+        """The round's mean upload from ``(delta, example count)`` pairs,
+        each folded in as it comes, so no (cohort, ...) stack is held: a
+        fixed denominator of the cohort under DP, the example counts
+        otherwise."""
+        acc = {k: jnp.zeros(v.shape, jnp.float32) for k, v in self.y0.items()}
+        total = 0.0
+        for delta, n in uploads:
+            acc = self._fold(acc, delta, jnp.float32(n))
+            total += n
+        if self.mix["dp_clip_norm"] > 0:
+            total = float(self.mix["cohort"])
+        return {k: v / total for k, v in acc.items()}
+
+    def _server_step(self, y, m, mn, nchange, agg, key):
+        """Noise and the server optimizer, for one round's mean upload."""
+        mix = self.mix
         noise = self._noise(key)
         mom = mix["server_momentum"] if mix["server_opt"] == "sgdm" else 0.0
         slr = mix["server_lr"]
@@ -161,53 +171,50 @@ class Reference:
                 OPERAND_TERMS[self.precision]):
             return self._run(steps)
 
+    def uploads(self, rng, y, cids):
+        """Each client's ``(delta, example count)`` in cohort order, its
+        minibatches drawn from ``rng`` as the program draws them."""
+        mix, task, data = self.mix, self.model.TASK, self.data
+        for cid in cids:
+            n = task.examples(data, cid)
+            idx = rng.integers(0, n, (mix["local_steps"], mix["local_batch"]))
+            batch = jax.tree.map(jnp.asarray, task.batch(data, cid, idx))
+            yield self._client(y, self.frozen, batch), float(n)
+
     def _run(self, steps: int):
-        mix, data = self.mix, self.data
         rng = np.random.default_rng(self.seed + 77)
-        N = len(data.client_images)
+        N = self.data.num_clients
         y = {k: jnp.asarray(v) for k, v in self.y0.items()}
         m = {k: jnp.zeros_like(v) for k, v in y.items()}
         mn = {k: jnp.zeros_like(v) for k, v in y.items()}
         nchange = {k: jnp.zeros_like(v) for k, v in y.items()}
         ys, ns = [], []
         for r in range(steps):
-            cids = rng.choice(N, size=mix["cohort"], replace=False)
-            deltas, weights = [], []
-            for cid in cids:
-                xs, ls = data.client_images[cid], data.client_labels[cid]
-                idx = rng.integers(0, len(ls),
-                                   (mix["local_steps"], mix["local_batch"]))
-                deltas.append(self._client(y, self.frozen,
-                                           jnp.asarray(xs[idx]),
-                                           jnp.asarray(ls[idx])))
-                weights.append(float(len(ls)))
+            cids = rng.choice(N, size=self.mix["cohort"], replace=False)
+            agg = self.aggregate(self.uploads(rng, y, cids))
             if self.fault != "unchanged":
-                stacked = {k: jnp.stack([d[k] for d in deltas]) for k in y}
-                del deltas
                 key = jax.random.key(self.seed * NOISE_KEY_STRIDE + r)
                 prev = y
-                y, m, mn, nchange = self._server(
-                    y, m, mn, nchange, stacked,
-                    jnp.asarray(weights, jnp.float32), key)
-                del stacked
+                y, m, mn, nchange = self._server(y, m, mn, nchange, agg, key)
                 if self.fault == "altered":
                     k = self.trainable[0]
                     y = dict(y, **{k: prev[k] + 2 * (y[k] - prev[k])})
+            del agg
             ys.append({k: np.asarray(v) for k, v in y.items()})
             ns.append({k: np.asarray(v) for k, v in nchange.items()})
         return ys, ns
 
 
 def eval_loss_fn(model, cfg: dict):
-    """Mean cross-entropy of a full parameter set (flat host dict) on
-    held-out examples, by the float32 reference forward."""
-    f = jax.jit(lambda p, x, labels: cross_entropy(
-        model.reference_logits(p, x, cfg, jnp.float32), labels))
+    """The task's mean loss of a full parameter set (flat host dict) on a
+    held-out batch, by the float32 reference forward."""
+    f = jax.jit(lambda p, batch: model.reference_loss(p, batch, cfg,
+                                                      jnp.float32))
 
-    def loss(params, x, labels):
+    def loss(params, batch):
         with jax.default_matmul_precision("highest"):
             return float(f({k: jnp.asarray(v) for k, v in params.items()},
-                           jnp.asarray(x), jnp.asarray(labels)))
+                           jax.tree.map(jnp.asarray, batch)))
     return loss
 
 
@@ -237,7 +244,7 @@ def moved_leaves(base, first, noise, rule: float = 1e-3) -> List[str]:
 
 def compare(model, cfg, y0, frozen, prog: List[Dict[str, np.ndarray]],
             ref: List[Dict[str, np.ndarray]],
-            noise: List[Dict[str, np.ndarray]], x_test, l_test) -> dict:
+            noise: List[Dict[str, np.ndarray]], test: dict) -> dict:
     """The numbers ``correct`` is decided on, for ``len(ref)`` steps:
 
     * ``loss_gap``: the largest relative gap, over the steps, between the
@@ -251,8 +258,8 @@ def compare(model, cfg, y0, frozen, prog: List[Dict[str, np.ndarray]],
     out = {}
     gaps = []
     for yp, yr in zip(prog, ref):
-        lp = loss({**frozen, **yp}, x_test, l_test)
-        lref = loss({**frozen, **yr}, x_test, l_test)
+        lp = loss({**frozen, **yp}, test)
+        lref = loss({**frozen, **yr}, test)
         gaps.append(abs(lp - lref) / abs(lref))
     out["loss_gap"] = max(gaps)
     out["loss_gaps"] = gaps
