@@ -8,6 +8,7 @@ from repro.configs.base import ModelConfig, get_config, list_configs, register
 _MODULES = (
     "mixtral_8x7b",
     "deepseek_v2_236b",
+    "deepseek_v2_lite",
     "qwen2_5_3b",
     "jamba_v0_1_52b",
     "mistral_nemo_12b",
@@ -34,6 +35,7 @@ def load_all():
 ARCH_IDS = {
     "mixtral-8x7b": "mixtral-8x7b",
     "deepseek-v2-236b": "deepseek-v2-236b",
+    "deepseek-v2-lite": "deepseek-v2-lite",
     "qwen2.5-3b": "qwen2.5-3b",
     "jamba-v0.1-52b": "jamba-v0.1-52b",
     "mistral-nemo-12b": "mistral-nemo-12b",

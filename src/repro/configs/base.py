@@ -22,6 +22,23 @@ SLSTM = "slstm"
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension of RoPE (arXiv:2309.00071), as DeepSeek-V2's
+    ``rope_scaling`` states it: ``factor`` over the
+    ``original_max_position_embeddings`` it was trained at, the
+    ``beta_fast``/``beta_slow`` rotation counts that bound the ramp
+    between extrapolated and interpolated frequencies, and the ``mscale``
+    and ``mscale_all_dim`` of the attention temperature
+    (``nn/attention.py`` writes the equations)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Configuration of a transformer-family model.
 
@@ -46,7 +63,18 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_d_ff: int = 0          # expert hidden dim (0 -> d_ff)
     router_aux_loss: float = 0.0
+    # > 0: each expert takes at most ceil(T*k/E * factor) tokens and the
+    # rest are dropped; 0: no capacity, no token dropped (the held experts
+    # run as grouped matmuls over exactly the slots routed to them)
     moe_capacity_factor: float = 1.25
+    norm_topk_prob: bool = True        # renormalise the top-k weights
+    routed_scaling_factor: float = 1.0  # scales them when not renormalised
+    # the experts this chip holds, experts [expert_offset, expert_offset +
+    # experts_held) of num_experts (0: all of them). The router still
+    # scores all num_experts; the layer returns its held experts' part
+    # (expert parallelism without the exchange; needs no-drop dispatch)
+    expert_offset: int = 0
+    experts_held: int = 0
     # perf knobs (hillclimb variants; 0/auto = paper-faithful baseline)
     moe_dispatch_groups: int = 0   # >1: group-local sort dispatch
     expert_shard: str = "auto"     # auto | model | 2d | 2d_swapped
@@ -64,6 +92,7 @@ class ModelConfig:
     qkv_bias: bool = False
     sliding_window: int = 0    # 0 = full attention
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
     use_rope: bool = True
     attn_logit_softcap: float = 0.0
 
@@ -85,6 +114,14 @@ class ModelConfig:
     is_encoder_decoder: bool = False
     num_prefix_tokens: int = 0   # VLM patch / audio frame embeddings (stub frontend)
     encoder_seq_len: int = 0     # fixed encoder length (audio)
+
+    # --- depth -----------------------------------------------------------------
+    # leading layers with a dense FFN, run unscanned before the periodic
+    # stack (DeepSeek's first_k_dense_replace); they count in num_layers
+    first_k_dense: int = 0
+    # recompute each layer's activations in the backward pass instead of
+    # keeping them (jax.checkpoint around every layer)
+    remat: bool = False
 
     # --- misc ------------------------------------------------------------------
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm
@@ -109,6 +146,10 @@ class ModelConfig:
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff if self.moe_d_ff else self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held if self.experts_held else self.num_experts
 
     @property
     def pdtype(self):
@@ -136,7 +177,7 @@ class ModelConfig:
         return kinds
 
     def layer_uses_moe(self, i: int) -> bool:
-        if self.num_experts <= 0:
+        if self.num_experts <= 0 or i < self.first_k_dense:
             return False
         return (i % self.moe_period) == (self.moe_period - 1)
 

@@ -1,17 +1,20 @@
 """DeepSeek-V2 236B [arXiv:2405.04434] — MLA (kv_lora 512) + 160 routed
-experts top-6 with 2 shared experts (expert FFN dim 1536)."""
+experts top-6 with 2 shared experts (expert FFN dim 1536); layer 0 keeps
+a dense FFN; routed weights are not renormalised and are scaled by 16.
+
+Departure: group-limited routing (8 device groups, top-3 groups) is not
+implemented; tokens route over all 160 experts (ROADMAP D6)."""
 from repro.configs.base import ModelConfig, register
 
 CONFIG = register(ModelConfig(
     name="deepseek-v2-236b", family="moe",
     num_layers=60, d_model=5120, num_heads=128, num_kv_heads=128,
-    d_ff=12288, vocab_size=102400,
+    d_ff=12288, vocab_size=102400, first_k_dense=1,
     num_experts=160, num_experts_per_tok=6, num_shared_experts=2,
     moe_d_ff=1536, moe_period=1, router_aux_loss=0.003,
+    norm_topk_prob=False, routed_scaling_factor=16.0,
     use_mla=True, kv_lora_rank=512, q_lora_rank=1536,
     qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-    # NOTE (DESIGN.md deviation log): the real model keeps layer 0 dense;
-    # we make all 60 layers MoE to keep the scan program homogeneous.
     freeze_spec=(r"/moe/(wi_gate|wi_up|wo)$",),
     source="arXiv:2405.04434",
 ))

@@ -61,7 +61,9 @@ def make_client_update(loss_fn: Callable, client_opt: opt_lib.Optimizer,
     """Returns f(y, frozen, client_batch[, grad_mask]) -> (delta, metrics).
 
     client_batch: pytree with leading axis tau (one microbatch per local
-    step). Gradients are taken wrt y only. ``grad_mask`` (optional 0/1
+    step). Gradients are taken wrt y only. The scalars of the loss's aux
+    dict (a model's counters, e.g. the MoE routing counts) come back
+    averaged over the local steps in ``metrics["client_aux"]``. ``grad_mask`` (optional 0/1
     tree over y) zeroes the gradient of frozen-for-this-tier leaves each
     local step — exact freezing under SGD-family ClientOpts — and the
     final delta is masked again (belt & braces) so a tiered client's
@@ -78,21 +80,25 @@ def make_client_update(loss_fn: Callable, client_opt: opt_lib.Optimizer,
                     jax.lax.stop_gradient, frozen))
                 out = loss_fn(full, mb)
                 return (out[0], out[1]) if isinstance(out, tuple) else (out, {})
-            (loss, _aux), grads = jax.value_and_grad(loss_of_y,
-                                                     has_aux=True)(y)
+            (loss, aux), grads = jax.value_and_grad(loss_of_y,
+                                                    has_aux=True)(y)
             if grad_mask is not None:
                 grads = jax.tree_util.tree_map(
                     lambda g, m: g * m.astype(g.dtype), grads, grad_mask)
             y, st = client_opt.update(y, grads, st)
-            return (y, st), loss
+            aux = {k: v for k, v in aux.items() if jnp.ndim(v) == 0}
+            return (y, st), (loss, aux)
 
-        (y_fin, _), losses = jax.lax.scan(local_step, (y0, opt_state),
-                                          client_batch)
+        (y_fin, _), (losses, auxs) = jax.lax.scan(
+            local_step, (y0, opt_state), client_batch)
         delta = opt_lib.tree_sub(y_fin, y0)
         if grad_mask is not None:
             delta = jax.tree_util.tree_map(
                 lambda d, m: d * m.astype(d.dtype), delta, grad_mask)
-        return delta, {"client_loss": jnp.mean(losses)}
+        metrics = {"client_loss": jnp.mean(losses)}
+        if auxs:
+            metrics["client_aux"] = {k: jnp.mean(v) for k, v in auxs.items()}
+        return delta, metrics
 
     return client_update
 
@@ -268,6 +274,10 @@ def make_round_fn(loss_fn: Callable, rc: RoundConfig,
                            "delta_norm": opt_lib.tree_global_norm(delta)
                            if noised else jnp.sqrt(
                                flat_lib.sumsq(flat_delta, layout.align))}
+        if "client_aux" in metrics:
+            # the loss's own counters, each the cohort's mean
+            out_metrics["client_aux"] = {
+                k: jnp.mean(v) for k, v in metrics["client_aux"].items()}
         if "update_norms" in ainfo:
             out_metrics["update_norm"] = jnp.mean(ainfo["update_norms"])
         if sanitize is not None:

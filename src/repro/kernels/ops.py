@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.kernels import agg_tail as _agg
 from repro.kernels import dp_clip as _dp
+from repro.kernels import moe_gmm as _gmm
 from repro.kernels import quantize as _q
 from repro.kernels import ref as _ref
 from repro.kernels import seed_reconstruct as _sr
@@ -217,3 +218,98 @@ def agg_tail(mat, weights, *, block_leaf, n_leaves: int, align: int = 1024,
     return _agg.compose(mat, weights, block_leaf=block_leaf, rng=rng,
                         bmask=bmask, constrain_fn=constrain_fn,
                         engine=engine, **kw)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matmul of an MoE layer's held experts (kernels/moe_gmm.py).
+#
+# Differentiable in the rows: the input gradient is the same kernel
+# against the transposed experts; the experts' own gradient is plain jnp
+# (``ref.moe_tgmm_ref``), which XLA drops where the experts are frozen,
+# so no weight-gradient kernel runs for FedPT's frozen experts. Under
+# ``jax.vmap`` (the round engine vmaps each client's step) the batch's
+# problems merge into one call over shared experts: rows are permuted to
+# expert-major order across the batch, so each expert's weights are read
+# once per call rather than once per client.
+
+
+def _gmm_call(transpose_rhs: bool):
+    @jax.custom_batching.custom_vmap
+    def gmm(lhs, rhs, group_sizes):
+        if use_kernels():
+            return _gmm.gmm(lhs, rhs, group_sizes,
+                            transpose_rhs=transpose_rhs)
+        return _ref.moe_gmm_ref(lhs, rhs, group_sizes, transpose_rhs)
+
+    @gmm.def_vmap
+    def _batched(axis_size, in_batched, lhs, rhs, group_sizes):
+        lb, rb, gb = in_batched
+        if not lb:
+            lhs = jnp.broadcast_to(lhs, (axis_size,) + lhs.shape)
+        if not gb:
+            group_sizes = jnp.broadcast_to(
+                group_sizes, (axis_size,) + group_sizes.shape)
+        if rb:       # experts of their own per problem: one call each
+            return jax.lax.map(lambda a: gmm(*a),
+                               (lhs, rhs, group_sizes)), True
+        B, m, k = lhs.shape
+        dest = _merged_rows(group_sizes.astype(jnp.int32), m)
+        src = jnp.zeros((B * m,), jnp.int32).at[dest].set(
+            jnp.arange(B * m, dtype=jnp.int32))
+        out = gmm(lhs.reshape(B * m, k)[src], rhs,
+                  jnp.sum(group_sizes, axis=0))
+        return out[dest].reshape(B, m, -1), True
+
+    return gmm
+
+
+def _merged_rows(group_sizes, m: int):
+    """Where each row of B problems (rows sorted by group, sizes
+    ``group_sizes`` (B, G)) goes in one problem of B*m rows sorted by
+    group: group g's rows of problem 0, then of problem 1, ...; the rows
+    past each problem's total go after every group, in problem order."""
+    B, G = group_sizes.shape
+    ends = jnp.cumsum(group_sizes, axis=1)
+    tot = ends[:, -1]
+    per_group = jnp.sum(group_sizes, axis=0)
+    base = jnp.cumsum(per_group) - per_group
+    before = jnp.cumsum(group_sizes, axis=0) - group_sizes
+    j = jnp.arange(m, dtype=jnp.int32)
+    gid = jnp.minimum(jax.vmap(lambda e: jnp.searchsorted(
+        e, j, side="right"))(ends), G - 1)
+    start = jnp.take_along_axis(ends - group_sizes, gid, axis=1)
+    routed = (base[gid] + jnp.take_along_axis(before, gid, axis=1)
+              + j[None] - start)
+    spare = m - tot
+    unrouted = (jnp.sum(tot) + (jnp.cumsum(spare) - spare)[:, None]
+                + j[None] - tot[:, None])
+    return jnp.where(j[None] < tot[:, None], routed,
+                     unrouted).reshape(-1).astype(jnp.int32)
+
+
+_gmm_fwd_call = _gmm_call(False)
+_gmm_t_call = _gmm_call(True)
+
+
+@jax.custom_vjp
+def moe_gmm(lhs, rhs, group_sizes):
+    """Grouped matmul: lhs (m, k) rows sorted by expert, rhs (g, k, n)
+    held experts, group_sizes (g,) rows per expert -> (m, n) float32, rows
+    past ``sum(group_sizes)`` zero. Pallas kernel on TPU (named
+    ``moe_gmm``), ``ref.moe_gmm_ref`` elsewhere."""
+    return _gmm_fwd_call(lhs, rhs, group_sizes)
+
+
+def _moe_gmm_fwd(lhs, rhs, group_sizes):
+    return _gmm_fwd_call(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _moe_gmm_bwd(res, grad):
+    lhs, rhs, group_sizes = res
+    dlhs = _gmm_t_call(grad, rhs, group_sizes).astype(lhs.dtype)
+    drhs = _ref.moe_tgmm_ref(lhs, grad, group_sizes,
+                             rhs.shape[0]).astype(rhs.dtype)
+    return dlhs, drhs, None
+
+
+moe_gmm.defvjp(_moe_gmm_fwd, _moe_gmm_bwd)

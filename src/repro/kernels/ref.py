@@ -341,3 +341,36 @@ def seed_reconstruct_ref(seed: int, shape, stddev: float):
     """
     return stddev * jax.random.normal(jax.random.key(seed), shape,
                                       jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matmul of the held experts (kernels/moe_gmm.py)
+
+
+def _row_groups(group_sizes, m: int):
+    """The group of each of ``m`` rows sorted by group; rows past the
+    groups' total get ``len(group_sizes)``."""
+    return jnp.searchsorted(jnp.cumsum(group_sizes), jnp.arange(m),
+                            side="right")
+
+
+def moe_gmm_ref(lhs, rhs, group_sizes, transpose_rhs: bool = False):
+    """Oracle for ``moe_gmm.gmm``: each row times its group's matrix (one
+    masked matmul per group, whose other rows add exact zeros); rows past
+    the groups' total are zero. float32 out."""
+    gid = _row_groups(group_sizes, lhs.shape[0])[:, None]
+    lhs = lhs.astype(jnp.float32)
+    out = None
+    for g in range(rhs.shape[0]):
+        w = rhs[g].astype(jnp.float32)
+        part = jnp.where(gid == g, lhs, 0.0) @ (w.T if transpose_rhs else w)
+        out = part if out is None else out + part
+    return out
+
+
+def moe_tgmm_ref(lhs, grad, group_sizes, groups: int):
+    """The weight gradient of ``moe_gmm_ref``: (groups, k, n) with
+    ``[g] = lhs[rows of g].T @ grad[rows of g]``."""
+    gid = _row_groups(group_sizes, lhs.shape[0])[:, None]
+    return jnp.stack([jnp.where(gid == g, lhs, 0.0).T @ grad
+                      for g in range(groups)])

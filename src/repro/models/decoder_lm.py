@@ -9,6 +9,25 @@ this keeps the HLO (and CPU compile time for 512-device dry-runs) bounded
 for 60-layer models, and the roofline accounting multiplies scan-body
 costs by the trip count.
 
+Leading layers unlike the period (``cfg.first_k_dense``: DeepSeek's
+dense layer 0) run unscanned before the stack, each an attention block
+with a dense FFN.
+
+Parameter layout (nested dicts; ``G`` = scanned groups):
+
+* ``embed/embedding`` (V, d); ``final_norm``; ``unembed/kernel`` (d, V)
+  unless tied;
+* ``lead/layer{i}`` for i < first_k_dense: one layer's ``ln1``,
+  ``attn``, ``ln2``, ``ffn``, unstacked;
+* ``layers/slot{j}``: slot j of every group, each leaf stacked on a
+  leading (G,) axis: ``ln1``, ``attn`` (or ``mamba`` / ``mlstm`` /
+  ``slstm``), ``ln2`` and ``ffn`` or ``moe`` (``router``, the held
+  experts' ``wi_gate``/``wi_up`` (E_held, d, ff) and ``wo`` (E_held, ff,
+  d), ``shared``).
+
+With ``cfg.remat`` every layer (a lead layer, a scanned group) keeps only
+its input for the backward pass and recomputes the rest.
+
 KV caches: full-length buffers for global attention, ring buffers of
 `sliding_window` size for SWA architectures (Mistral-style rolling
 cache) — the latter is what makes `long_500k` decode feasible.
@@ -37,8 +56,10 @@ class Slot(NamedTuple):
 
 
 def layer_program(cfg: ModelConfig) -> Tuple[Tuple[Slot, ...], int]:
-    """Returns (slots-per-group, n_groups)."""
+    """Returns (slots-per-group, n_groups) of the scanned stack, which
+    starts after the ``first_k_dense`` leading layers."""
     kinds = cfg.block_kinds()
+    lead = cfg.first_k_dense
     period = 1
     if cfg.family == "hybrid" and cfg.attn_period:
         period = cfg.attn_period
@@ -46,11 +67,19 @@ def layer_program(cfg: ModelConfig) -> Tuple[Tuple[Slot, ...], int]:
         period = cfg.slstm_every
     if cfg.num_experts > 0 and cfg.moe_period > 1:
         period = math.lcm(period, cfg.moe_period)
-    assert cfg.num_layers % period == 0, (cfg.name, cfg.num_layers, period)
+    n = cfg.num_layers - lead
+    assert n % period == 0, (cfg.name, n, period)
     slots = tuple(
-        Slot(kind=kinds[i], use_moe=cfg.layer_uses_moe(i))
+        Slot(kind=kinds[lead + i], use_moe=cfg.layer_uses_moe(lead + i))
         for i in range(period))
-    return slots, cfg.num_layers // period
+    return slots, n // period
+
+
+def lead_slots(cfg: ModelConfig) -> Tuple[Slot, ...]:
+    """The leading dense layers, in order."""
+    kinds = cfg.block_kinds()
+    return tuple(Slot(kind=kinds[i], use_moe=False)
+                 for i in range(cfg.first_k_dense))
 
 
 # ---------------------------------------------------------------------------
@@ -110,6 +139,12 @@ def init_model(cfg: ModelConfig, seed: int) -> Dict[str, Any]:
                                       cfg.norm_type),
         "layers": _init_stack(seed, cfg),
     }
+    if cfg.first_k_dense:
+        p["lead"] = {
+            f"layer{i}": _init_slot(
+                basic.path_key(seed, f"{cfg.name}/lead{i}"), cfg, slot, i,
+                False)
+            for i, slot in enumerate(lead_slots(cfg))}
     if not cfg.tie_embeddings:
         p["unembed"] = {"kernel": basic.normal_init(
             seed, "unembed/kernel", (cfg.d_model, cfg.vocab_size), dt,
@@ -142,25 +177,72 @@ def sinusoid_pos(positions, d_model, dtype):
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1).astype(dtype)
 
 
-def _apply_slot(x, sp, cfg: ModelConfig, slot: Slot, positions, aux,
+# ``jax.named_scope`` names (HLO metadata only) of the stages of the
+# MLA/MoE decoder that a device trace puts time on; the no-drop MoE
+# layer's own are in nn/moe.py
+SCOPE_MLA = "fedpt/mla"
+SCOPE_LEAD = "fedpt/lead_dense"
+SCOPE_HEAD = "fedpt/head"
+
+
+def _stats0(cfg: ModelConfig) -> Dict[str, Any]:
+    """The per-forward statistics the layers accumulate: the router aux
+    loss (summed over MoE layers) and, for the no-drop MoE layer, the
+    slots routed to held experts (summed) and the held experts' largest
+    load over their mean (the largest over layers)."""
+    stats = {"moe_aux_loss": jnp.zeros((), jnp.float32)}
+    if no_drop_moe(cfg):
+        stats["moe_routed_held"] = jnp.zeros((), jnp.float32)
+        stats["moe_load_max_over_mean"] = jnp.zeros((), jnp.float32)
+    return stats
+
+
+def no_drop_moe(cfg: ModelConfig) -> bool:
+    return cfg.num_experts > 0 and cfg.moe_capacity_factor == 0
+
+
+def _moe(h2, mp, cfg: ModelConfig, stats):
+    """The MoE FFN of (B, S, d) tokens, with ``stats`` updated."""
+    B, S, D = h2.shape
+    flat = h2.reshape(B * S, D)
+    if no_drop_moe(cfg):
+        y, aux_l, counts = moe_lib.moe_ffn_share(flat, mp, cfg, seqs=B)
+        stats = dict(
+            stats, moe_routed_held=stats["moe_routed_held"]
+            + counts["moe_routed_held"],
+            moe_load_max_over_mean=jnp.maximum(
+                stats["moe_load_max_over_mean"],
+                counts["moe_load_max_over_mean"]))
+    else:
+        y, aux_l = moe_lib.moe_ffn(flat, mp, cfg)
+    stats = dict(stats, moe_aux_loss=stats["moe_aux_loss"] + aux_l)
+    return y.reshape(B, S, D), stats
+
+
+def _apply_slot(x, sp, cfg: ModelConfig, slot: Slot, positions, stats,
                 encoder_out=None, prefix_len=0, causal=True):
-    """One residual block. Returns (x, aux, cache_entry)."""
+    """One residual block. Returns (x, stats, cache_entry)."""
     cd = cfg.cdtype
     h = basic.apply_norm(x, sp["ln1"], cfg.norm_type)
     cache = ()
     if slot.kind == ATTN:
         if cfg.use_mla:
-            q, k, v, (ckv, kpe) = attn_lib.mla_qkv(h, sp["attn"], cfg, positions)
-            o = attn_lib.flash_attention(q, k, v, cfg.with_(sliding_window=0),
-                                         causal=causal, prefix_len=prefix_len)
-            o = o.reshape(o.shape[0], o.shape[1], -1)
-            o = basic.dense(o, sp["attn"]["wo"], cd)
+            with jax.named_scope(SCOPE_MLA):
+                q, k, v, (ckv, kpe) = attn_lib.mla_qkv(h, sp["attn"], cfg,
+                                                       positions)
+                o = attn_lib.flash_attention(
+                    q, k, v, cfg.with_(sliding_window=0), causal=causal,
+                    prefix_len=prefix_len,
+                    scale=attn_lib.mla_softmax_scale(cfg))
+                o = o.reshape(o.shape[:2] + (o.shape[2] * o.shape[3],))
+                o = basic.dense(o, sp["attn"]["wo"], cd)
             cache = (ckv, kpe)
         else:
             q, k, v = attn_lib.qkv_project(h, sp["attn"], cfg)
             if cfg.use_rope:
                 cos, sin = attn_lib.rope_freqs(cfg.resolved_head_dim,
-                                               cfg.rope_theta, positions)
+                                               cfg.rope_theta, positions,
+                                               cfg.rope_scaling)
                 q = attn_lib.apply_rope(q, cos, sin)
                 k = attn_lib.apply_rope(k, cos, sin)
             o = attn_lib.flash_attention(q, k, v, cfg, causal=causal,
@@ -183,20 +265,21 @@ def _apply_slot(x, sp, cfg: ModelConfig, slot: Slot, positions, aux,
         cache = st
     elif slot.kind == MLSTM:
         o, st = ssm_lib.mlstm_forward(h, sp["mlstm"], cfg)
-        return x + o, aux, st
+        return x + o, stats, st
     elif slot.kind == SLSTM:
         o, st = ssm_lib.slstm_forward(h, sp["slstm"], cfg)
-        return x + o, aux, st
+        return x + o, stats, st
 
     h2 = basic.apply_norm(x, sp["ln2"], cfg.norm_type)
     if slot.use_moe:
-        B, S, D = h2.shape
-        y, aux_l = moe_lib.moe_ffn(h2.reshape(B * S, D), sp["moe"], cfg)
-        y = y.reshape(B, S, D)
-        aux = aux + aux_l
+        y, stats = _moe(h2, sp["moe"], cfg, stats)
     else:
         y = basic.mlp(h2, sp["ffn"], cfg.act, cd)
-    return x + y, aux, cache
+    return x + y, stats, cache
+
+
+def _maybe_remat(fn, cfg: ModelConfig):
+    return jax.checkpoint(fn) if cfg.remat else fn
 
 
 def forward(params, cfg: ModelConfig, tokens, prefix_embeds=None,
@@ -231,42 +314,56 @@ def forward(params, cfg: ModelConfig, tokens, prefix_embeds=None,
         encoder_out = basic.apply_norm(encoder_out, params["enc_norm"],
                                        cfg.norm_type)
 
-    x, aux, caches = _run_stack(params["layers"], cfg, x, positions,
-                                encoder_out=encoder_out,
-                                prefix_len=prefix_len,
-                                collect_caches=return_caches)[0:3]
+    stats = _stats0(cfg)
+    lead_caches = {}
+    for i, slot in enumerate(lead_slots(cfg)):
+        def lead_layer(x, stats, lp, slot=slot):
+            with jax.named_scope(SCOPE_LEAD):
+                return _apply_slot(x, lp, cfg, slot, positions, stats,
+                                   prefix_len=prefix_len)
+        x, stats, lead_caches[f"layer{i}"] = _maybe_remat(lead_layer, cfg)(
+            x, stats, params["lead"][f"layer{i}"])
 
-    x = basic.apply_norm(x, params["final_norm"], cfg.norm_type)
-    if cfg.tie_embeddings:
-        logits = basic.unembed(x, params["embed"], cd)
-    else:
-        logits = x @ params["unembed"]["kernel"].astype(cd)
-    metrics = {"moe_aux_loss": aux}
+    x, stats, caches = _run_stack(params["layers"], cfg, x, positions,
+                                  stats=stats, encoder_out=encoder_out,
+                                  prefix_len=prefix_len,
+                                  collect_caches=return_caches)
+
+    with jax.named_scope(SCOPE_HEAD):
+        x = basic.apply_norm(x, params["final_norm"], cfg.norm_type)
+        if cfg.tie_embeddings:
+            logits = basic.unembed(x, params["embed"], cd)
+        else:
+            logits = x @ params["unembed"]["kernel"].astype(cd)
     if return_caches:
-        return logits, metrics, caches
-    return logits, metrics
+        if lead_caches:
+            caches = {"lead": lead_caches, "stack": caches}
+        return logits, stats, caches
+    return logits, stats
 
 
 def _run_stack(stack_params, cfg: ModelConfig, x, positions, noncausal=False,
-               encoder_out=None, prefix_len=0, collect_caches=False):
+               stats=None, encoder_out=None, prefix_len=0,
+               collect_caches=False):
     slots, n_groups = layer_program(cfg)
 
     def group_body(carry, group_params):
-        x, aux = carry
+        x, stats = carry
         caches = []
         for si, slot in enumerate(slots):
-            x, aux, c = _apply_slot(x, group_params[f"slot{si}"], cfg, slot,
-                                    positions, aux, encoder_out=encoder_out,
-                                    prefix_len=prefix_len,
-                                    causal=not noncausal)
+            x, stats, c = _apply_slot(x, group_params[f"slot{si}"], cfg,
+                                      slot, positions, stats,
+                                      encoder_out=encoder_out,
+                                      prefix_len=prefix_len,
+                                      causal=not noncausal)
             caches.append(c)
         out = tuple(caches) if collect_caches else ()
-        return (x, aux), out
+        return (x, stats), out
 
-    (x, aux), caches = jax.lax.scan(group_body,
-                                    (x, jnp.zeros((), jnp.float32)),
-                                    stack_params)
-    return x, aux, caches
+    (x, stats), caches = jax.lax.scan(
+        _maybe_remat(group_body, cfg),
+        (x, _stats0(cfg) if stats is None else stats), stack_params)
+    return x, stats, caches
 
 
 # ---------------------------------------------------------------------------
@@ -319,39 +416,48 @@ def cache_capacity(cfg: ModelConfig, max_len: int) -> int:
     return max_len
 
 
+def _cache_entry(cfg: ModelConfig, slot: Slot, G: int, batch: int, S: int,
+                 cd):
+    """One slot's zero cache, stacked over G layers."""
+    kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    if slot.kind == ATTN and cfg.use_mla:
+        return {"ckv": jnp.zeros((G, batch, S, cfg.kv_lora_rank), cd),
+                "kpe": jnp.zeros((G, batch, S, cfg.qk_rope_head_dim), cd)}
+    if slot.kind == ATTN:
+        return {"k": jnp.zeros((G, batch, S, kvh, hd), cd),
+                "v": jnp.zeros((G, batch, S, kvh, hd), cd)}
+    if slot.kind == MAMBA:
+        di, _ = ssm_lib.mamba_dims(cfg)
+        return {"h": jnp.zeros((G, batch, di, cfg.mamba_d_state),
+                               jnp.float32),
+                "conv": jnp.zeros((G, batch, cfg.mamba_d_conv - 1, di), cd)}
+    d_in_x, nh_x, dh_x = ssm_lib.xlstm_dims(cfg)
+    if slot.kind == MLSTM:
+        return {"C": jnp.zeros((G, batch, nh_x, dh_x, dh_x), jnp.float32),
+                "n": jnp.zeros((G, batch, nh_x, dh_x), jnp.float32),
+                "conv": jnp.zeros((G, batch, 3, d_in_x), cd)}
+    dh_s = cfg.d_model // cfg.num_heads
+    z = jnp.zeros((G, batch, cfg.num_heads, dh_s), jnp.float32)
+    return {"c": z, "n": z, "h": z, "m": z - 30.0,
+            "conv": jnp.zeros((G, batch, 3, cfg.d_model), cd)}
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
     """Zero caches for decoding up to max_len tokens. Returns a pytree with
-    a per-slot entry stacked over groups plus a scalar cache_len."""
+    a per-slot entry stacked over groups, a per-leading-layer entry (with
+    a leading axis of 1, so every entry has the same layout) and a scalar
+    cache_len."""
     cd = dtype or cfg.cdtype
     slots, G = layer_program(cfg)
     S = cache_capacity(cfg, max_len)
     kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    d_in_x, nh_x, dh_x = ssm_lib.xlstm_dims(cfg)
-    di, _ = ssm_lib.mamba_dims(cfg)
-    K = cfg.mamba_d_conv
-    entries = []
-    for slot in slots:
-        if slot.kind == ATTN and cfg.use_mla:
-            e = {"ckv": jnp.zeros((G, batch, S, cfg.kv_lora_rank), cd),
-                 "kpe": jnp.zeros((G, batch, S, cfg.qk_rope_head_dim), cd)}
-        elif slot.kind == ATTN:
-            e = {"k": jnp.zeros((G, batch, S, kvh, hd), cd),
-                 "v": jnp.zeros((G, batch, S, kvh, hd), cd)}
-        elif slot.kind == MAMBA:
-            e = {"h": jnp.zeros((G, batch, di, cfg.mamba_d_state), jnp.float32),
-                 "conv": jnp.zeros((G, batch, K - 1, di), cd)}
-        elif slot.kind == MLSTM:
-            e = {"C": jnp.zeros((G, batch, nh_x, dh_x, dh_x), jnp.float32),
-                 "n": jnp.zeros((G, batch, nh_x, dh_x), jnp.float32),
-                 "conv": jnp.zeros((G, batch, 3, d_in_x), cd)}
-        elif slot.kind == SLSTM:
-            dh_s = cfg.d_model // cfg.num_heads
-            z = jnp.zeros((G, batch, cfg.num_heads, dh_s), jnp.float32)
-            e = {"c": z, "n": z, "h": z, "m": z - 30.0,
-                 "conv": jnp.zeros((G, batch, 3, cfg.d_model), cd)}
-        entries.append(e)
+    entries = [_cache_entry(cfg, slot, G, batch, S, cd) for slot in slots]
     cache = {"slots": {f"slot{i}": e for i, e in enumerate(entries)},
              "cache_len": jnp.zeros((), jnp.int32)}
+    if cfg.first_k_dense:
+        cache["lead"] = {f"layer{i}": _cache_entry(cfg, slot, 1, batch, S,
+                                                   cd)
+                         for i, slot in enumerate(lead_slots(cfg))}
     if cfg.is_encoder_decoder:
         E = cfg.encoder_seq_len
         cache["cross"] = {
@@ -410,7 +516,8 @@ def _decode_slot(x, sp, cfg: ModelConfig, slot: Slot, cache, cross,
             q, k, v = attn_lib.qkv_project(h, sp["attn"], cfg)
             if cfg.use_rope:
                 cos, sin = attn_lib.rope_freqs(cfg.resolved_head_dim,
-                                               cfg.rope_theta, pos[None, :])
+                                               cfg.rope_theta, pos[None, :],
+                                               cfg.rope_scaling)
                 q = attn_lib.apply_rope(q, cos, sin)
                 k = attn_lib.apply_rope(k, cos, sin)
             new_k = jax.lax.dynamic_update_slice(
@@ -448,9 +555,7 @@ def _decode_slot(x, sp, cfg: ModelConfig, slot: Slot, cache, cross,
 
     h2 = basic.apply_norm(x, sp["ln2"], cfg.norm_type)
     if slot.use_moe:
-        B = h2.shape[0]
-        y, _ = moe_lib.moe_ffn(h2.reshape(B, -1), sp["moe"], cfg)
-        y = y.reshape(B, 1, -1)
+        y, _ = _moe(h2, sp["moe"], cfg, _stats0(cfg))
     else:
         y = basic.mlp(h2, sp["ffn"], cfg.act, cd)
     return x + y, cache
@@ -465,6 +570,15 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
     x = basic.embed(tokens, params["embed"], cd)
     if not cfg.use_rope:
         x = x + sinusoid_pos(pos[None, :], cfg.d_model, cd)
+
+    new_lead = {}
+    for i, slot in enumerate(lead_slots(cfg)):
+        lc = jax.tree_util.tree_map(lambda a: a[0],
+                                    cache["lead"][f"layer{i}"])
+        x, lc = _decode_slot(x, params["lead"][f"layer{i}"], cfg, slot, lc,
+                             None, cache_len, pos)
+        new_lead[f"layer{i}"] = jax.tree_util.tree_map(lambda a: a[None],
+                                                       lc)
 
     def group_body(x, xs):
         gp, gc, gcross = xs
@@ -488,5 +602,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
         logits = x @ params["unembed"]["kernel"].astype(cd)
     new_cache = dict(cache)
     new_cache["slots"] = new_slots
+    if new_lead:
+        new_cache["lead"] = new_lead
     new_cache["cache_len"] = cache_len + 1
     return logits, new_cache

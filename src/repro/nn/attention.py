@@ -5,10 +5,12 @@ against a KV cache (including sequence-sharded caches for 500k context).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.nn import basic
 from repro.configs.base import ModelConfig
@@ -20,12 +22,75 @@ NEG_INF = -1e30
 # RoPE
 
 
-def rope_freqs(head_dim: int, theta: float, positions):
-    """positions: (..., seq) int32 -> cos/sin (..., seq, head_dim//2)."""
+def rope_freqs(head_dim: int, theta: float, positions, scaling=None):
+    """positions: (..., seq) int32 -> cos/sin (..., seq, head_dim//2).
+    ``scaling``: a ``configs.base.RopeScaling`` for YaRN (see
+    :func:`yarn_inv_freq`), else plain RoPE."""
     half = head_dim // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if scaling is None:
+        inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+        mscale = 1.0
+    else:
+        inv = jnp.asarray(yarn_inv_freq(head_dim, theta, scaling))
+        mscale = (yarn_mscale(scaling.factor, scaling.mscale)
+                  / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
     ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    if mscale == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * mscale, jnp.sin(ang) * mscale
+
+
+# YaRN (Peng et al., arXiv:2309.00071, §3.2-3.4) as DeepSeek-V2 applies it
+# to its rope dims (d = qk_rope_head_dim, theta, scale factor s, original
+# context L). For frequency index i < d/2:
+#
+#   extrap_i = theta^(-2i/d),   interp_i = extrap_i / s
+#   low  = max(floor(d ln(L / (beta_fast 2 pi)) / (2 ln theta)), 0)
+#   high = min(ceil (d ln(L / (beta_slow 2 pi)) / (2 ln theta)), d - 1)
+#   m_i  = 1 - clip((i - low) / (high - low), 0, 1)
+#   inv_freq_i = interp_i (1 - m_i) + extrap_i m_i
+#
+# (a dimension that turns more than beta_fast times over L keeps its
+# frequency, one that turns less than beta_slow times is interpolated by
+# s, a linear ramp between). cos and sin are scaled by
+# yarn_mscale(s, mscale) / yarn_mscale(s, mscale_all_dim), and the
+# softmax scale (qk_head_dim^-1/2) by yarn_mscale(s, mscale_all_dim)^2,
+# where yarn_mscale(s, m) = 0.1 m ln s + 1 for s > 1, else 1 (the
+# paper's sqrt(1/t) = 0.1 ln s + 1 at m = 1).
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling) -> np.ndarray:
+    """The (dim // 2,) float32 YaRN inverse frequencies (equations above)."""
+    def correction(rotations):
+        return (dim * math.log(scaling.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(dim // 2, dtype=np.float32)
+    extrap = 1.0 / theta ** (2 * i / dim)
+    interp = extrap / scaling.factor
+    m = 1.0 - np.clip((i - low) / (high - low), 0.0, 1.0)
+    return (interp * (1 - m) + extrap * m).astype(np.float32)
+
+
+def mla_softmax_scale(cfg: ModelConfig):
+    """MLA's softmax scale: (qk_nope + qk_rope)^-1/2, times
+    yarn_mscale(s, mscale_all_dim)^2 under YaRN."""
+    scale = 1.0 / jnp.sqrt(cfg.qk_nope_head_dim
+                           + cfg.qk_rope_head_dim).astype(jnp.float32)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale = scale * yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
 
 
 def apply_rope(x, cos, sin):
@@ -91,12 +156,14 @@ def _attend_chunk(q, k, v, qpos, kpos, window: int, softcap: float, scale,
 
 
 def flash_attention(q, k, v, cfg: ModelConfig, q_offset=0, chunk: int = 512,
-                    causal: bool = True, prefix_len: int = 0):
+                    causal: bool = True, prefix_len: int = 0,
+                    scale: Optional[float] = None):
     """Causal (optionally sliding-window) attention.
 
     q: (b, sq, h, hd);  k, v: (b, skv, kv_heads, hd_k); v may have a
     different per-head dim than q/k (MLA).
     q_offset: position of q[0] relative to k[0] (for prefill continuation).
+    scale: the softmax scale (default hd^-1/2).
     Returns (b, sq, h, dv).
     """
     b, sq, h, hd = q.shape
@@ -104,7 +171,8 @@ def flash_attention(q, k, v, cfg: ModelConfig, q_offset=0, chunk: int = 512,
     kvh = k.shape[2]
     dv = v.shape[3]
     rep = h // kvh
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     window = cfg.sliding_window
 
     qh = q.transpose(0, 2, 1, 3)  # b,h,sq,hd
@@ -250,7 +318,7 @@ def mla_qkv(x, p, cfg: ModelConfig, positions):
     k_nope = basic.dense(c_kv, p["wk_b"], cd).reshape(b, s, h, qn)
     v = basic.dense(c_kv, p["wv_b"], cd).reshape(b, s, h, vd)
 
-    cos, sin = rope_freqs(qr, cfg.rope_theta, positions)
+    cos, sin = rope_freqs(qr, cfg.rope_theta, positions, cfg.rope_scaling)
     q_nope, q_pe = q[..., :qn], q[..., qn:]
     q_pe = apply_rope(q_pe, cos, sin)
     k_pe_r = apply_rope(k_pe[..., None, :], cos, sin)  # single shared rope head
@@ -268,7 +336,7 @@ def mla_compress(x, p, cfg: ModelConfig, positions):
     kv = basic.dense(x, p["wkv_a"], cd)
     c_kv, k_pe = kv[..., :r], kv[..., r:]
     c_kv = basic.rmsnorm(c_kv, p["kv_norm"]["scale"])
-    cos, sin = rope_freqs(qr, cfg.rope_theta, positions)
+    cos, sin = rope_freqs(qr, cfg.rope_theta, positions, cfg.rope_scaling)
     k_pe = apply_rope(k_pe[..., None, :], cos, sin)[..., 0, :]
     return c_kv, k_pe
 
@@ -293,7 +361,7 @@ def mla_decode(x, p, cfg: ModelConfig, ckv_cache, kpe_cache, cache_len):
         q = basic.dense(x, p["wq"], cd).reshape(b, 1, h, qn + qr)
     cl = jnp.asarray(cache_len)
     pos = jnp.broadcast_to((cl - 1).reshape(-1, 1), (b, 1))
-    cos, sin = rope_freqs(qr, cfg.rope_theta, pos)
+    cos, sin = rope_freqs(qr, cfg.rope_theta, pos, cfg.rope_scaling)
     q_nope, q_pe = q[..., :qn], q[..., qn:]
     q_pe = apply_rope(q_pe, cos, sin)
 
@@ -301,7 +369,7 @@ def mla_decode(x, p, cfg: ModelConfig, ckv_cache, kpe_cache, cache_len):
     wkb = p["wk_b"]["kernel"].astype(cd).reshape(r, h, qn)
     q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, wkb)
 
-    scale = 1.0 / jnp.sqrt(qn + qr).astype(jnp.float32)
+    scale = mla_softmax_scale(cfg)
     s_lat = jnp.einsum("bqhr,bsr->bhqs", q_lat, ckv_cache.astype(cd),
                        preferred_element_type=jnp.float32)
     s_pe = jnp.einsum("bqhr,bsr->bhqs", q_pe, kpe_cache.astype(cd),

@@ -9,14 +9,31 @@ combined back with the router weights. Overflowing tokens beyond capacity
 are dropped (standard Switch/GShard semantics).
 
 Shared experts (DeepSeek-V2) run densely on every token.
+
+With ``cfg.moe_capacity_factor == 0`` the layer drops nothing
+(``moe_ffn_share``): it holds experts ``[expert_offset, expert_offset +
+experts_held)`` of ``num_experts`` (all by default), routes every token
+over all of them, and returns its held experts' part of the result plus
+the shared experts: the slots routed to held experts, sorted by expert,
+go through one grouped matmul per SwiGLU projection
+(``kernels/ops.moe_gmm``), and the slots routed elsewhere contribute
+nothing here (on a chip of an expert-parallel deployment, the chips
+holding those experts add them).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kernel_ops
 from repro.nn import basic
 from repro.configs.base import ModelConfig
+
+# ``jax.named_scope`` names of the no-drop layer's stages (HLO metadata
+# only; a device trace puts each operation's time on the innermost one)
+SCOPE_ROUTE = "fedpt/moe_route"
+SCOPE_HELD = "fedpt/moe_held_ffn"
+SCOPE_SHARED = "fedpt/moe_shared"
 
 
 def _maybe_constrain(x, spec):
@@ -24,14 +41,17 @@ def _maybe_constrain(x, spec):
 
 
 def init_moe(seed, path, cfg: ModelConfig, dtype):
+    """The router scores all ``num_experts``; the expert stacks hold the
+    ``held_experts`` this layer computes (all of them by default)."""
     d, e = cfg.d_model, cfg.num_experts
+    h = cfg.held_experts
     ff = cfg.expert_d_ff
     p = {
         "router": basic.init_dense(seed, f"{path}/router", d, e, dtype),
         # stacked expert weights: (E, d, ff) / (E, ff, d)
-        "wi_gate": basic.normal_init(seed, f"{path}/wi_gate", (e, d, ff), dtype, fan_in=d),
-        "wi_up": basic.normal_init(seed, f"{path}/wi_up", (e, d, ff), dtype, fan_in=d),
-        "wo": basic.normal_init(seed, f"{path}/wo", (e, ff, d), dtype, fan_in=ff),
+        "wi_gate": basic.normal_init(seed, f"{path}/wi_gate", (h, d, ff), dtype, fan_in=d),
+        "wi_up": basic.normal_init(seed, f"{path}/wi_up", (h, d, ff), dtype, fan_in=d),
+        "wo": basic.normal_init(seed, f"{path}/wo", (h, ff, d), dtype, fan_in=ff),
     }
     if cfg.num_shared_experts > 0:
         sff = cfg.expert_d_ff * cfg.num_shared_experts
@@ -45,7 +65,7 @@ def router_topk(x, p, cfg: ModelConfig):
     logits = basic.dense(x, p["router"], jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     w, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    w = _topk_weights(w, cfg)
     # load-balance aux loss (Switch): E * sum_e f_e * p_e
     e = cfg.num_experts
     me = jnp.mean(probs, axis=0)
@@ -53,6 +73,16 @@ def router_topk(x, p, cfg: ModelConfig):
         jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32), axis=1), axis=0)
     aux = e * jnp.sum(me * ce)
     return w.astype(x.dtype), idx.astype(jnp.int32), aux
+
+
+def _topk_weights(w, cfg: ModelConfig):
+    """Renormalised to sum 1 (``norm_topk_prob``), else scaled by
+    ``routed_scaling_factor``."""
+    if cfg.norm_topk_prob:
+        return w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling_factor != 1.0:
+        return w * cfg.routed_scaling_factor
+    return w
 
 
 def _sort_dispatch(x, w, idx, e: int, cap: int, cd):
@@ -95,6 +125,8 @@ def moe_ffn(x, p, cfg: ModelConfig):
     collectives are emitted — only the expert-parallel all-to-all.
     """
     T, d = x.shape
+    assert cfg.moe_capacity_factor > 0 and cfg.held_experts == \
+        cfg.num_experts, "capacity dispatch holds every expert"
     g = cfg.moe_dispatch_groups
     if g and g > 1 and T % g == 0 and T // g >= cfg.num_experts_per_tok:
         return _moe_ffn_grouped(x, p, cfg, g)
@@ -176,6 +208,62 @@ def _moe_ffn_grouped(x, p, cfg: ModelConfig, g: int):
     if cfg.num_shared_experts > 0:
         out = out + basic.mlp(x, p["shared"], "silu", cd)
     return out, jnp.mean(auxs)
+
+
+def moe_ffn_share(x, p, cfg: ModelConfig, seqs: int = 1):
+    """The no-drop layer over this chip's held experts (module doc).
+
+    x: (T, d), the tokens of ``seqs`` sequences of equal length. Returns
+    (out (T, d), aux, counters): ``aux`` the sequence-wise balance loss
+    over all ``num_experts`` (DeepSeek-V2's ``seq_aux``: per sequence,
+    sum_e f_e P_e with f_e the share of its top-k slots on expert e times
+    E / k and P_e its mean router probability; mean over sequences);
+    ``counters`` the slots routed to held experts (``moe_routed_held``)
+    and the largest held expert's slot count over the held experts' mean
+    (``moe_load_max_over_mean``; 0 when none is routed here)."""
+    T, d = x.shape
+    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    held, off = cfg.held_experts, cfg.expert_offset
+    cd = cfg.cdtype
+    with jax.named_scope(SCOPE_ROUTE):
+        logits = basic.dense(x, p["router"], jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, idx = jax.lax.top_k(probs, k)
+        w = _topk_weights(w, cfg)
+        per_seq = (seqs, T // max(seqs, 1), e)
+        counts = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32),
+                         axis=1).reshape(per_seq)
+        f = jnp.mean(counts, axis=1) * (e / k)
+        aux = jnp.mean(jnp.sum(
+            f * jnp.mean(probs.reshape(per_seq), axis=1), -1))
+        # the T*k slots sorted by held expert; the slots routed elsewhere
+        # (key ``held``) go last and are computed by nothing
+        local = idx.reshape(-1) - off
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+        xs = x.astype(cd)[order // k]
+    with jax.named_scope(SCOPE_HELD):
+        gate = kernel_ops.moe_gmm(xs, p["wi_gate"].astype(cd), sizes)
+        up = kernel_ops.moe_gmm(xs, p["wi_up"].astype(cd), sizes)
+        ys = kernel_ops.moe_gmm((jax.nn.silu(gate) * up).astype(cd),
+                                p["wo"].astype(cd), sizes)
+        # back to (token, slot) order; each token's slots weighted
+        slots = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=order.dtype))
+        y = jnp.einsum("tkd,tk->td", ys[slots].reshape(T, k, d),
+                       w.astype(ys.dtype)).astype(cd)
+    out = y
+    if cfg.num_shared_experts > 0:
+        with jax.named_scope(SCOPE_SHARED):
+            out = out + basic.mlp(x, p["shared"], "silu", cd)
+    routed = jnp.sum(sizes)
+    counters = {
+        "moe_routed_held": routed.astype(jnp.float32),
+        "moe_load_max_over_mean": jnp.where(
+            routed > 0, jnp.max(sizes) * held / jnp.maximum(routed, 1),
+            0.0).astype(jnp.float32)}
+    return out, aux, counters
 
 
 def moe_ffn_dense_fallback(x, p, cfg: ModelConfig):

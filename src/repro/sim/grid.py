@@ -726,6 +726,9 @@ def _run_sync(y, frozen, loss_fn, dataset, rc, rounds, grid, server_opt, *,
                     round_rec.payload["loss"] = loss
 
             rec = {"round": r, "loss": loss}
+            # the loss's own counters (fedpt.make_client_update)
+            rec.update({k: float(v) for k, v in
+                        rmetrics.get("client_aux", {}).items()})
             if eval_fn and eval_every and (r + 1) % eval_every == 0:
                 with prof_lib.span("grid/eval_fn"):
                     rec.update(eval_fn(part.merge(y, frozen)))

@@ -109,3 +109,43 @@ def test_decode_attention_ignores_unwritten_slots():
     v2 = v.at[:, 3:].set(-99.0)
     o2 = att.decode_attention(q, k2, v2, 3, CFG.with_(num_kv_heads=2, num_heads=2))
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
+
+
+def test_yarn_frequencies_and_softmax_scale_match_the_equations():
+    """DeepSeek-V2-Lite's YaRN (factor 40 over 4,096 positions, beta_fast
+    32, beta_slow 1, mscale = mscale_all_dim = 0.707) on its 64 rope dims,
+    against a direct numpy transcription of arXiv:2309.00071 as
+    DeepSeek's modeling code applies it."""
+    from repro.configs.base import get_config
+    cfg = get_config("deepseek-v2-lite")
+    rs = cfg.rope_scaling
+    d, theta, s = 64, 1e4, 40.0
+    assert (cfg.qk_rope_head_dim, cfg.rope_theta, rs.factor) == (d, theta, s)
+    low = np.floor(d * np.log(4096 / (32 * 2 * np.pi)) / (2 * np.log(theta)))
+    high = np.ceil(d * np.log(4096 / (1 * 2 * np.pi)) / (2 * np.log(theta)))
+    assert (low, high) == (10, 23)
+    i = np.arange(d // 2)
+    extrap = theta ** (-2 * i / d)
+    m = 1 - np.clip((i - low) / (high - low), 0, 1)
+    inv = extrap / s * (1 - m) + extrap * m
+    np.testing.assert_allclose(att.yarn_inv_freq(d, theta, rs), inv,
+                               rtol=1e-6)
+    # the cell's positions; a float32 angle under 1024 is within 1.2e-4
+    pos = jnp.arange(0, 1024, 7)[None, :]
+    cos, sin = att.rope_freqs(d, theta, pos, rs)
+    ang = np.asarray(pos, np.float64)[..., None] * inv
+    # cos and sin scaled by yarn_mscale(40, 0.707) / itself, that is 1
+    np.testing.assert_allclose(np.asarray(cos), np.cos(ang), atol=2e-4)
+    np.testing.assert_allclose(np.asarray(sin), np.sin(ang), atol=2e-4)
+    mscale = 0.1 * 0.707 * np.log(s) + 1
+    assert abs(mscale ** 2 - 1.5896) < 1e-4
+    np.testing.assert_allclose(float(att.mla_softmax_scale(cfg)),
+                               192 ** -0.5 * mscale ** 2, rtol=1e-6)
+    # without scaling, the plain RoPE frequencies and 192^-1/2
+    plain = cfg.with_(rope_scaling=None)
+    np.testing.assert_allclose(float(att.mla_softmax_scale(plain)),
+                               192 ** -0.5, rtol=1e-6)
+    cos0, _ = att.rope_freqs(d, theta, pos)
+    np.testing.assert_allclose(np.asarray(cos0),
+                               np.cos(np.asarray(pos)[..., None] * extrap),
+                               atol=2e-4)

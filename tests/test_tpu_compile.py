@@ -4,8 +4,10 @@ The TPU compiler is installed with libtpu, and it compiles for a chip
 that is only described (``get_topology_desc``): it refuses tiling, SMEM
 and memory violations that interpret mode never sees. Each case lowers
 one kernel at the real width of the CIFAR ResNet-18 trainable slice
-(2,914,634 parameters in 2,874 align-blocks) with K=16 client rows, and
-checks that the compiled program calls the kernel by its name.
+(2,914,634 parameters in 2,874 align-blocks) with K=16 client rows (the
+grouped matmul at DeepSeek-V2-Lite's expert widths, float32 at
+``highest``), and checks that the compiled program calls the kernel by
+its name.
 
 The kernel functions are called directly (not through ``kernels/ops``),
 because the dispatchers choose the ref path whenever the trace is not for
@@ -18,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 import repro.core.partition as part
 from repro.core import flat as flat_lib
-from repro.kernels import agg_tail, dp_clip, quantize
+from repro.kernels import agg_tail, dp_clip, moe_gmm, quantize
 from repro.models import paper_models as pm
 
 K = 16
@@ -78,21 +80,34 @@ def _case(name, layout):
     if name == "clip_flat":
         return (lambda x: dp_clip.clip_flat(x, 1.0), [((N,), f32)],
                 "dp_clip_scale")
-    # the async lane step vmaps the per-client clip over its lane
-    assert name == "clip_flat_vmapped"
-    return (jax.vmap(lambda x: dp_clip.clip_flat(x, 1.0)), [((K, N), f32)],
-            "dp_clip_scale")
+    if name == "clip_flat_vmapped":
+        # the async lane step vmaps the per-client clip over its lane
+        return (jax.vmap(lambda x: dp_clip.clip_flat(x, 1.0)),
+                [((K, N), f32)], "dp_clip_scale")
+    # DeepSeek-V2-Lite's held experts at their published widths (8
+    # experts, d 2048, width 1408) over a cohort of 8 x 1024 tokens' top-6
+    # slots: the forward projections and the input gradient
+    rows, d, ff, g = 8 * 1024 * 6, 2048, 1408, 8
+    gmm_args = [((rows, d), f32), ((g, d, ff), f32), ((g,), jnp.int32)]
+    if name == "moe_gmm":
+        return moe_gmm.gmm, gmm_args, name
+    assert name == "moe_gmm_transposed"
+    return (lambda a, b, c: moe_gmm.gmm(a, b, c, transpose_rhs=True),
+            [((rows, ff), f32), ((g, d, ff), f32), ((g,), jnp.int32)],
+            "moe_gmm")
 
 
 @pytest.mark.parametrize("name", [
     "agg_tail_stats", "agg_tail_pack", "agg_tail_apply",
-    "fake_quantize_flat", "clip_flat", "clip_flat_vmapped"])
+    "fake_quantize_flat", "clip_flat", "clip_flat_vmapped", "moe_gmm",
+    "moe_gmm_transposed"])
 def test_kernel_compiles_for_v5e(name, layout, one_chip,
                                  no_persistent_cache):
     fn, shapes, kernel = _case(name, layout)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
     assert kernel in text          # the pallas_call's name scope
