@@ -5,7 +5,8 @@ expert), ``lhs[rows of g] @ rhs[g]``, where the rows of ``lhs`` are
 sorted by group and group ``g`` holds ``group_sizes[g]`` consecutive rows
 starting at ``sum(group_sizes[:g])``. Rows at or past
 ``sum(group_sizes)`` (the slots routed to experts held elsewhere) are
-not computed and come out zero. ``transpose_rhs`` multiplies by
+not computed: the kernel never writes them, so they hold whatever the
+output buffer held, and a caller selects them away. ``transpose_rhs`` multiplies by
 ``rhs[g].T`` instead: the input gradient through a frozen expert, so the
 backward pass needs no weight-gradient kernel.
 
@@ -69,7 +70,8 @@ def _kernel(meta, lhs_ref, rhs_ref, out_ref, acc_ref, *, tm: int, tn: int,
 def gmm(lhs, rhs, group_sizes, *, transpose_rhs: bool = False,
         interpret: bool = False):
     """lhs (m, k), rhs (g, k, n) (``(g, n, k)`` with ``transpose_rhs``),
-    group_sizes (g,) int32 -> (m, n) float32 (see the module doc)."""
+    group_sizes (g,) int32 -> (m, n) float32, the rows past
+    ``sum(group_sizes)`` unwritten (see the module doc)."""
     m, k = lhs.shape
     groups = rhs.shape[0]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
@@ -114,6 +116,4 @@ def gmm(lhs, rhs, group_sizes, *, transpose_rhs: bool = False,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(meta, lhs, rhs)
-    rows = lax.broadcasted_iota(jnp.int32, (mp, 1), 0)
-    out = jnp.where(rows < jnp.sum(group_sizes), out, 0.0)
     return out[:m] if pad else out

@@ -221,95 +221,142 @@ def agg_tail(mat, weights, *, block_leaf, n_leaves: int, align: int = 1024,
 
 
 # ---------------------------------------------------------------------------
-# Grouped matmul of an MoE layer's held experts (kernels/moe_gmm.py).
+# The held experts of an MoE layer, through the grouped matmul of
+# kernels/moe_gmm.py.
 #
-# Differentiable in the rows: the input gradient is the same kernel
+# A problem's (T, k) slots go to expert-major rows (a stable sort of each
+# slot's held expert, the slots routed elsewhere last); one gather brings
+# the tokens in, the SwiGLU's three grouped matmuls run back to back on
+# those rows, and one gather takes them back to slot order. The kernel
+# leaves the rows past the held slots unwritten, so every read of them is
+# a select on the small held mask. The input gradient runs the kernel
 # against the transposed experts; the experts' own gradient is plain jnp
-# (``ref.moe_tgmm_ref``), which XLA drops where the experts are frozen,
-# so no weight-gradient kernel runs for FedPT's frozen experts. Under
-# ``jax.vmap`` (the round engine vmaps each client's step) the batch's
-# problems merge into one call over shared experts: rows are permuted to
-# expert-major order across the batch, so each expert's weights are read
-# once per call rather than once per client.
+# (``ref.moe_tgmm_ref``), which XLA drops where the experts are frozen, so
+# no weight-gradient kernel runs for FedPT's frozen experts. Under
+# ``jax.vmap`` (the round engine vmaps each client's step) the clients'
+# tokens over shared experts merge into one problem: one sort, one gather
+# in and one out per pass, and each expert's weights read once per call.
 
 
-def _gmm_call(transpose_rhs: bool):
-    @jax.custom_batching.custom_vmap
-    def gmm(lhs, rhs, group_sizes):
-        if use_kernels():
-            return _gmm.gmm(lhs, rhs, group_sizes,
-                            transpose_rhs=transpose_rhs)
-        return _ref.moe_gmm_ref(lhs, rhs, group_sizes, transpose_rhs)
-
-    @gmm.def_vmap
-    def _batched(axis_size, in_batched, lhs, rhs, group_sizes):
-        lb, rb, gb = in_batched
-        if not lb:
-            lhs = jnp.broadcast_to(lhs, (axis_size,) + lhs.shape)
-        if not gb:
-            group_sizes = jnp.broadcast_to(
-                group_sizes, (axis_size,) + group_sizes.shape)
-        if rb:       # experts of their own per problem: one call each
-            return jax.lax.map(lambda a: gmm(*a),
-                               (lhs, rhs, group_sizes)), True
-        B, m, k = lhs.shape
-        dest = _merged_rows(group_sizes.astype(jnp.int32), m)
-        src = jnp.zeros((B * m,), jnp.int32).at[dest].set(
-            jnp.arange(B * m, dtype=jnp.int32))
-        out = gmm(lhs.reshape(B * m, k)[src], rhs,
-                  jnp.sum(group_sizes, axis=0))
-        return out[dest].reshape(B, m, -1), True
-
-    return gmm
+def _grouped(lhs, rhs, sizes, transpose_rhs: bool = False):
+    if use_kernels():
+        return _gmm.gmm(lhs, rhs, sizes, transpose_rhs=transpose_rhs)
+    return _ref.moe_gmm_ref(lhs, rhs, sizes, transpose_rhs)
 
 
-def _merged_rows(group_sizes, m: int):
-    """Where each row of B problems (rows sorted by group, sizes
-    ``group_sizes`` (B, G)) goes in one problem of B*m rows sorted by
-    group: group g's rows of problem 0, then of problem 1, ...; the rows
-    past each problem's total go after every group, in problem order."""
-    B, G = group_sizes.shape
-    ends = jnp.cumsum(group_sizes, axis=1)
-    tot = ends[:, -1]
-    per_group = jnp.sum(group_sizes, axis=0)
-    base = jnp.cumsum(per_group) - per_group
-    before = jnp.cumsum(group_sizes, axis=0) - group_sizes
-    j = jnp.arange(m, dtype=jnp.int32)
-    gid = jnp.minimum(jax.vmap(lambda e: jnp.searchsorted(
-        e, j, side="right"))(ends), G - 1)
-    start = jnp.take_along_axis(ends - group_sizes, gid, axis=1)
-    routed = (base[gid] + jnp.take_along_axis(before, gid, axis=1)
-              + j[None] - start)
-    spare = m - tot
-    unrouted = (jnp.sum(tot) + (jnp.cumsum(spare) - spare)[:, None]
-                + j[None] - tot[:, None])
-    return jnp.where(j[None] < tot[:, None], routed,
-                     unrouted).reshape(-1).astype(jnp.int32)
+def _swiglu(gate, up, dtype):
+    return (jax.nn.silu(gate) * up).astype(dtype)
 
 
-_gmm_fwd_call = _gmm_call(False)
-_gmm_t_call = _gmm_call(True)
+def _slot_rows(rows, pos, held):
+    """Rows in slot order, (P, T, k, n): the slots routed elsewhere are
+    zero whatever their row holds."""
+    P, T, k = held.shape
+    return jnp.where(held[..., None], rows[pos].reshape(P, T, k, -1), 0.0)
+
+
+def _merging(fn):
+    """``fn(wi_gate, wi_up, wo, *stacks)`` takes stacks of P problems
+    over the shared experts and returns stacks of P. Under ``jax.vmap``
+    the batch's stacks merge into one over shared experts; experts of
+    their own per problem run one stack at a time."""
+    fn = jax.custom_batching.custom_vmap(fn)
+
+    @fn.def_vmap
+    def _batched(axis_size, in_batched, *args):
+        def batched(a, b):
+            return a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+
+        experts, stacks = args[:3], [batched(a, b) for a, b in
+                                     zip(args[3:], in_batched[3:])]
+        if any(in_batched[:3]):
+            out = jax.lax.map(lambda a: fn(*a), (*map(
+                batched, experts, in_batched[:3]), *stacks))
+        else:
+            out = fn(*experts,
+                     *[a.reshape((-1,) + a.shape[2:]) for a in stacks])
+            out = jax.tree.map(
+                lambda o: o.reshape((axis_size, -1) + o.shape[1:]), out)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return fn
+
+
+@_merging
+def _held_fwd(wi_gate, wi_up, wo, x, idx, w):
+    P, T, d = x.shape
+    k, groups = idx.shape[-1], wi_gate.shape[0]
+    key = jnp.where((idx >= 0) & (idx < groups), idx, groups)
+    held = key < groups
+    sizes = jnp.sum(jax.nn.one_hot(key, groups, dtype=jnp.int32),
+                    axis=(1, 2))
+    total = jnp.sum(sizes, axis=0)
+    # expert g's rows of problem 0, of problem 1, ..., then the slots
+    # routed elsewhere
+    order = jnp.argsort(key.reshape(-1), stable=True)
+    pos = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.size, dtype=order.dtype), unique_indices=True)
+    xs = x.reshape(P * T, d)[order // k]
+    gate = _grouped(xs, wi_gate, total)
+    up = _grouped(xs, wi_up, total)
+    yk = _slot_rows(_grouped(_swiglu(gate, up, x.dtype), wo, total), pos,
+                    held)
+    out = jnp.einsum("ptkd,ptk->ptd", yk, w.astype(yk.dtype))
+    res = [a.reshape((P, -1) + a.shape[1:]) for a in (order, pos, xs, gate,
+                                                      up)]
+    return out, (held, sizes, *res, yk)
+
+
+@_merging
+def _held_bwd(wi_gate, wi_up, wo, held, sizes, order, pos, xs, gate, up,
+              yk, w, g):
+    P, T, k = held.shape
+    groups = wi_gate.shape[0]
+    order, pos = order.reshape(-1), pos.reshape(-1)
+    xs, gate, up = (a.reshape((-1,) + a.shape[2:]) for a in (xs, gate, up))
+    total = jnp.sum(sizes, axis=0)
+    g = g.astype(yk.dtype)
+    dw = jnp.einsum("ptkd,ptd->ptk", yk, g)
+    dys = (g.reshape(P * T, -1)[order // k]
+           * w.reshape(-1)[order, None].astype(g.dtype))
+    h, swiglu_vjp = jax.vjp(lambda a, b: _swiglu(a, b, xs.dtype), gate, up)
+    dgate, dup = swiglu_vjp(_grouped(dys, wo, total, True).astype(h.dtype))
+    dxs = (_grouped(dgate, wi_gate, total, True).astype(xs.dtype)
+           + _grouped(dup, wi_up, total, True).astype(xs.dtype))
+    dx = jnp.sum(_slot_rows(dxs, pos, held), axis=2)
+    # expert g's rows of problem p are group g * P + p of the merged rows
+    per = sizes.T.reshape(-1)
+
+    def dexperts(lhs, grad):
+        out = _ref.moe_tgmm_ref(lhs, grad, per, groups * P)
+        return out.reshape((groups, P) + out.shape[1:]).swapaxes(0, 1)
+
+    return (dexperts(xs, dgate), dexperts(xs, dup), dexperts(h, dys), dx,
+            dw.astype(w.dtype))
 
 
 @jax.custom_vjp
-def moe_gmm(lhs, rhs, group_sizes):
-    """Grouped matmul: lhs (m, k) rows sorted by expert, rhs (g, k, n)
-    held experts, group_sizes (g,) rows per expert -> (m, n) float32, rows
-    past ``sum(group_sizes)`` zero. Pallas kernel on TPU (named
-    ``moe_gmm``), ``ref.moe_gmm_ref`` elsewhere."""
-    return _gmm_fwd_call(lhs, rhs, group_sizes)
+def moe_held_ffn(x, idx, w, wi_gate, wi_up, wo):
+    """The held experts' weighted SwiGLU of one problem's (T, d) tokens:
+    ``out[t] = sum_j w[t, j] * ffn_{idx[t, j]}(x[t])`` over the slots j
+    whose ``idx`` names a held expert (``0 <= idx < wi_gate.shape[0]``);
+    the other slots are routed elsewhere and add nothing. Experts
+    ``wi_gate``, ``wi_up`` (G, d, f) and ``wo`` (G, f, d); float32 out.
+    The Pallas kernel ``moe_gmm`` on TPU, ``ref.moe_gmm_ref``
+    elsewhere."""
+    return _held_fwd(wi_gate, wi_up, wo, x[None], idx[None], w[None])[0][0]
 
 
-def _moe_gmm_fwd(lhs, rhs, group_sizes):
-    return _gmm_fwd_call(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+def _held_ffn_fwd(x, idx, w, wi_gate, wi_up, wo):
+    out, res = _held_fwd(wi_gate, wi_up, wo, x[None], idx[None], w[None])
+    return out[0], (wi_gate, wi_up, wo, res, w[None])
 
 
-def _moe_gmm_bwd(res, grad):
-    lhs, rhs, group_sizes = res
-    dlhs = _gmm_t_call(grad, rhs, group_sizes).astype(lhs.dtype)
-    drhs = _ref.moe_tgmm_ref(lhs, grad, group_sizes,
-                             rhs.shape[0]).astype(rhs.dtype)
-    return dlhs, drhs, None
+def _held_ffn_bwd(res, g):
+    wi_gate, wi_up, wo, res, w = res
+    grads = _held_bwd(wi_gate, wi_up, wo, *res, w, g[None])
+    dwg, dwu, dwo, dx, dw = (a[0] for a in grads)
+    return dx, None, dw, dwg, dwu, dwo
 
 
-moe_gmm.defvjp(_moe_gmm_fwd, _moe_gmm_bwd)
+moe_held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)

@@ -370,7 +370,9 @@ def moe_gmm_ref(lhs, rhs, group_sizes, transpose_rhs: bool = False):
 
 def moe_tgmm_ref(lhs, grad, group_sizes, groups: int):
     """The weight gradient of ``moe_gmm_ref``: (groups, k, n) with
-    ``[g] = lhs[rows of g].T @ grad[rows of g]``."""
+    ``[g] = lhs[rows of g].T @ grad[rows of g]``; rows in no group may
+    hold anything, even NaN."""
     gid = _row_groups(group_sizes, lhs.shape[0])[:, None]
-    return jnp.stack([jnp.where(gid == g, lhs, 0.0).T @ grad
+    return jnp.stack([jnp.where(gid == g, lhs, 0.0).T
+                      @ jnp.where(gid == g, grad, 0.0)
                       for g in range(groups)])

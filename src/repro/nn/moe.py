@@ -14,9 +14,9 @@ With ``cfg.moe_capacity_factor == 0`` the layer drops nothing
 (``moe_ffn_share``): it holds experts ``[expert_offset, expert_offset +
 experts_held)`` of ``num_experts`` (all by default), routes every token
 over all of them, and returns its held experts' part of the result plus
-the shared experts: the slots routed to held experts, sorted by expert,
-go through one grouped matmul per SwiGLU projection
-(``kernels/ops.moe_gmm``), and the slots routed elsewhere contribute
+the shared experts: the slots routed to held experts go through
+``kernels/ops.moe_held_ffn`` (one grouped matmul per SwiGLU projection,
+the slots sorted by expert), and the slots routed elsewhere contribute
 nothing here (on a chip of an expert-parallel deployment, the chips
 holding those experts add them).
 """
@@ -221,7 +221,7 @@ def moe_ffn_share(x, p, cfg: ModelConfig, seqs: int = 1):
     ``counters`` the slots routed to held experts (``moe_routed_held``)
     and the largest held expert's slot count over the held experts' mean
     (``moe_load_max_over_mean``; 0 when none is routed here)."""
-    T, d = x.shape
+    T = x.shape[0]
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     held, off = cfg.held_experts, cfg.expert_offset
     cd = cfg.cdtype
@@ -236,24 +236,13 @@ def moe_ffn_share(x, p, cfg: ModelConfig, seqs: int = 1):
         f = jnp.mean(counts, axis=1) * (e / k)
         aux = jnp.mean(jnp.sum(
             f * jnp.mean(probs.reshape(per_seq), axis=1), -1))
-        # the T*k slots sorted by held expert; the slots routed elsewhere
-        # (key ``held``) go last and are computed by nothing
-        local = idx.reshape(-1) - off
-        key = jnp.where((local >= 0) & (local < held), local, held)
-        order = jnp.argsort(key, stable=True)
-        sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
-        xs = x.astype(cd)[order // k]
+        local = idx - off
+        sizes = jnp.sum(jax.nn.one_hot(local, held, dtype=jnp.int32),
+                        axis=(0, 1))
     with jax.named_scope(SCOPE_HELD):
-        gate = kernel_ops.moe_gmm(xs, p["wi_gate"].astype(cd), sizes)
-        up = kernel_ops.moe_gmm(xs, p["wi_up"].astype(cd), sizes)
-        ys = kernel_ops.moe_gmm((jax.nn.silu(gate) * up).astype(cd),
-                                p["wo"].astype(cd), sizes)
-        # back to (token, slot) order; each token's slots weighted
-        slots = jnp.zeros_like(order).at[order].set(
-            jnp.arange(T * k, dtype=order.dtype))
-        y = jnp.einsum("tkd,tk->td", ys[slots].reshape(T, k, d),
-                       w.astype(ys.dtype)).astype(cd)
-    out = y
+        out = kernel_ops.moe_held_ffn(
+            x.astype(cd), local, w, p["wi_gate"].astype(cd),
+            p["wi_up"].astype(cd), p["wo"].astype(cd)).astype(cd)
     if cfg.num_shared_experts > 0:
         with jax.named_scope(SCOPE_SHARED):
             out = out + basic.mlp(x, p["shared"], "silu", cd)

@@ -23,3 +23,17 @@ except ImportError:
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(params=["ref", "interpret"])
+def gmm_path(request, monkeypatch):
+    """The grouped matmul ``ops.moe_held_ffn`` runs: the jnp ref, or the
+    Pallas kernel in interpret mode, which leaves NaN in every row past
+    the routed ones, so a read of such a row that is not selected away
+    shows."""
+    from repro.kernels import moe_gmm, ops
+    if request.param == "interpret":
+        monkeypatch.setattr(ops, "_grouped", lambda a, b, c, t=False:
+                            moe_gmm.gmm(a, b, c, transpose_rhs=t,
+                                        interpret=True))
+    return request.param

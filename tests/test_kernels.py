@@ -281,44 +281,76 @@ def test_moe_gmm_kernel_matches_ref(m, k, n, sizes, transpose):
                           interpret=True)
         want = ref.moe_gmm_ref(lhs, rhs, gs, transpose)
     assert got.shape == (m, n) and got.dtype == jnp.float32
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    # the rows past the groups' total are left unwritten
+    routed = sum(sizes)
+    np.testing.assert_allclose(np.asarray(got)[:routed],
+                               np.asarray(want)[:routed],
                                rtol=1e-5, atol=1e-4)
-    assert not np.any(np.asarray(got)[sum(sizes):])
 
 
-def test_moe_gmm_gradients_and_batching():
-    """``ops.moe_gmm``: its input gradient is the grouped matmul against
-    the transposed experts and its expert gradient the per-group outer
-    products; under ``vmap`` the problems merge into one call over the
-    shared experts (or map one by one over experts of their own), and
-    every problem gets what it gets alone."""
+def _held_oracle(x, idx, w, wg, wu, wo):
+    """``ops.moe_held_ffn`` in plain jnp: every held expert on every
+    token, each slot taking its own expert's row."""
+    y = jnp.stack([(jax.nn.silu(x @ wg[g]) * (x @ wu[g])) @ wo[g]
+                   for g in range(wg.shape[0])], axis=1)        # (T, G, d)
+    sel = jax.nn.one_hot(idx, wg.shape[0], dtype=x.dtype)     # (T, k, G)
+    return jnp.einsum("tgd,tkg,tk->td", y, sel, w)
+
+
+def _held_case(T=24, d=8, f=6, G=3, k=2, seed=0):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(T, d)), jnp.float32)
+    # slots on experts 0..G-1 and, as -1 or G+1, on experts held elsewhere
+    idx = jnp.asarray(rng.integers(-1, G + 2, size=(T, k)), jnp.int32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, k)), jnp.float32)
+    experts = [jnp.asarray(rng.normal(size=s) / np.sqrt(s[1]), jnp.float32)
+               for s in ((G, d, f), (G, d, f), (G, f, d))]
+    return x, idx, w, experts
+
+
+def test_moe_gmm_gradients_and_batching(gmm_path):
+    """The grouped matmuls of ``ops.moe_held_ffn``: their gradients in
+    the tokens, the weights and the experts are the plain oracle's;
+    under ``vmap`` the problems merge into one call over the shared
+    experts (or map one by one over experts of their own, or merge
+    again under a second ``vmap``), and every problem gets what it gets
+    alone, gradients included."""
     from repro.kernels import ops
-    lhs, rhs, gs = _gmm_case(24, 8, 6, [5, 0, 9], False)
+    x, idx, w, experts = _held_case()
+    tol = dict(rtol=1e-5, atol=1e-5)
     with jax.default_matmul_precision("highest"):
         def f(fn):
-            return lambda a, b: jnp.sum(jnp.sin(fn(a, b, gs)))
-        got = jax.grad(f(ops.moe_gmm), (0, 1))(lhs, rhs)
-        want = jax.grad(f(ref.moe_gmm_ref), (0, 1))(lhs, rhs)
+            return lambda x, w, *e: jnp.sum(jnp.sin(fn(x, idx, w, *e)))
+        got = jax.grad(f(ops.moe_held_ffn), range(5))(x, w, *experts)
+        want = jax.grad(f(_held_oracle), range(5))(x, w, *experts)
         for a, b in zip(got, want):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-5, atol=1e-6)
-        lhs_b = jnp.stack([lhs, 2 * lhs, lhs[::-1]])
-        gs_b = jnp.asarray([[5, 0, 9], [24, 0, 0], [0, 3, 1]], jnp.int32)
-        loop = [ref.moe_gmm_ref(lhs_b[i], rhs, gs_b[i]) for i in range(3)]
-        merged = jax.vmap(ops.moe_gmm, (0, None, 0))(lhs_b, rhs, gs_b)
-        np.testing.assert_allclose(np.asarray(merged), np.stack(loop),
-                                   rtol=1e-5, atol=1e-6)
-        rhs_b = jnp.stack([rhs, -rhs, 3 * rhs])
-        own = jax.vmap(ops.moe_gmm)(lhs_b, rhs_b, gs_b)
-        loop = [ref.moe_gmm_ref(lhs_b[i], rhs_b[i], gs_b[i])
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+        xb = jnp.stack([x, 2 * x, x[::-1]])
+        ib = jnp.stack([idx, jnp.zeros_like(idx), jnp.full_like(idx, -1)])
+        wb = jnp.stack([w, w, 3 * w])
+        loop = [_held_oracle(xb[i], ib[i], wb[i], *experts)
                 for i in range(3)]
-        np.testing.assert_allclose(np.asarray(own), np.stack(loop),
-                                   rtol=1e-5, atol=1e-6)
-        # the batched input gradient, as the round engine takes it
-        gb = jax.vmap(jax.grad(lambda a, g: jnp.sum(
-            ops.moe_gmm(a, rhs, g) ** 2)))(lhs_b, gs_b)
-        gl = [jax.grad(lambda a: jnp.sum(
-            ref.moe_gmm_ref(a, rhs, gs_b[i]) ** 2))(lhs_b[i])
-            for i in range(3)]
-        np.testing.assert_allclose(np.asarray(gb), np.stack(gl),
-                                   rtol=1e-5, atol=1e-5)
+        merged = jax.vmap(ops.moe_held_ffn, (0, 0, 0, None, None, None))(
+            xb, ib, wb, *experts)
+        np.testing.assert_allclose(np.asarray(merged), np.stack(loop), **tol)
+        nested = jax.vmap(jax.vmap(ops.moe_held_ffn,
+                                   (0, 0, 0, None, None, None)),
+                          (0, 0, 0, None, None, None))(
+            xb[None], ib[None], wb[None], *experts)
+        np.testing.assert_allclose(np.asarray(nested[0]), np.stack(loop),
+                                   **tol)
+        own = [jnp.stack([e, -e, 3 * e]) for e in experts]
+        got = jax.vmap(ops.moe_held_ffn)(xb, ib, wb, *own)
+        loop = [_held_oracle(xb[i], ib[i], wb[i], *(e[i] for e in own))
+                for i in range(3)]
+        np.testing.assert_allclose(np.asarray(got), np.stack(loop), **tol)
+        # the batched gradients, as the round engine takes them: each
+        # problem's own gradient in the shared experts too
+        def g(fn):
+            return jax.vmap(jax.grad(lambda x, i, w, *e: jnp.sum(
+                fn(x, i, w, *e) ** 2), (0, 2, 3, 4, 5)),
+                (0, 0, 0, None, None, None))
+        got = g(ops.moe_held_ffn)(xb, ib, wb, *experts)
+        want = g(_held_oracle)(xb, ib, wb, *experts)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)

@@ -1,5 +1,6 @@
 """MoE dispatch: sort-based capacity dispatch vs the dense oracle,
-capacity-drop semantics, and router invariants.
+capacity-drop semantics, router invariants, and the no-drop layer's
+held experts, one client and a cohort merged under ``vmap``.
 """
 import jax
 import jax.numpy as jnp
@@ -7,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ref
 from repro.nn import basic, moe as moe_lib
 
 CFG = ModelConfig(name="m", family="moe", num_layers=1, d_model=32,
@@ -126,3 +128,147 @@ def test_no_slot_dropped_when_every_token_routes_to_one_held_expert():
     # expert 0's part is really there: without it only the shared remain
     shared = basic.mlp(x, p["shared"], "silu", jnp.float32)
     assert float(jnp.min(jnp.linalg.norm(out - shared, axis=-1))) > 1e-3
+
+
+# the merged dispatch of the held experts (kernels/ops.moe_held_ffn)
+# under the round engine's vmap over clients: 4 clients of 32 tokens,
+# experts 4-7 held; client 0 routes no slot here, client 1 all three of
+# every token's (the no-drop worst case), clients 2 and 3 as they fall
+HELD_CFG = SHARE.with_(expert_offset=4, experts_held=4)
+
+
+def _cohort():
+    p = moe_lib.init_moe(0, "moe", SHARE, jnp.float32)
+    router = 0.1 * np.array(p["router"]["kernel"])
+    router[0, 4:7] = [3.0, 2.5, 2.0]
+    router[1, 10:13] = [3.0, 2.5, 2.0]
+    p = dict(p, router={"kernel": jnp.asarray(router)})
+    x = np.array(jax.random.normal(jax.random.key(7), (4, 32, 32)))
+    x[0, :, :2] = [0.0, 5.0]
+    x[1, :, :2] = [5.0, 0.0]
+    return jnp.asarray(x), p
+
+
+def _share_and_oracle(x, p):
+    """Per client: the share through ``moe_ffn_share`` on experts 4-7,
+    and the dense oracle of the whole layer with every other expert
+    zeroed (the shared experts in both)."""
+    share, _ = _held(p, HELD_CFG, 4, 4)
+    mine = ((jnp.arange(16) >= 4) & (jnp.arange(16) < 8))[:, None, None]
+    zeroed = {k: (v * mine if k in ("wi_gate", "wi_up", "wo") else v)
+              for k, v in p.items()}
+    return (moe_lib.moe_ffn_share(x, share, HELD_CFG)[0],
+            moe_lib.moe_ffn_dense_fallback(x, zeroed, SHARE)[0])
+
+
+def _old_formula(x, p):
+    """The held share as the layer computed it before the merged
+    dispatch: the slots sorted by held expert, one grouped matmul per
+    projection, back to slot order by the inverse permutation, each
+    token's slots weighted."""
+    T, d = x.shape
+    k = SHARE.num_experts_per_tok
+    w, idx, _ = moe_lib.router_topk(x, p, SHARE)
+    local = idx.reshape(-1) - 4
+    key = jnp.where((local >= 0) & (local < 4), local, 4)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, 4, dtype=jnp.int32), axis=0)
+    xs = x[order // k]
+    gate = ref.moe_gmm_ref(xs, p["wi_gate"][4:8], sizes)
+    up = ref.moe_gmm_ref(xs, p["wi_up"][4:8], sizes)
+    ys = ref.moe_gmm_ref(jax.nn.silu(gate) * up, p["wo"][4:8], sizes)
+    slots = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+    y = jnp.einsum("tkd,tk->td", ys[slots].reshape(T, k, d), w)
+    return y + basic.mlp(x, p["shared"], "silu", jnp.float32)
+
+
+def _rel_close(got, want, rtol=1e-5):
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=rtol,
+                               atol=rtol * np.max(np.abs(want)))
+
+
+def test_merged_dispatch_matches_each_client_alone(gmm_path):
+    """Under ``vmap`` over 4 clients of unequal loads the held share
+    equals, client by client, the old formula and the dense oracle; its
+    gradients in the tokens, the router and the held experts equal the
+    dense oracle's."""
+    x, p = _cohort()
+    with jax.default_matmul_precision("highest"):
+        loads = [int(jnp.sum(jnp.isin(moe_lib.router_topk(xi, p, SHARE)[1],
+                                      jnp.arange(4, 8)))) for xi in x]
+        assert loads[0] == 0 and loads[1] == 32 * 3, loads
+        got, want = jax.vmap(_share_and_oracle, (0, None))(x, p)
+        for i in range(4):
+            _rel_close(got[i], want[i])
+            _rel_close(got[i], _old_formula(x[i], p))
+
+        def grads(which):
+            def loss(x, router, wg, wu, wo):
+                q = dict(p, router=router, wi_gate=p["wi_gate"].at[4:8].set(wg),
+                         wi_up=p["wi_up"].at[4:8].set(wu),
+                         wo=p["wo"].at[4:8].set(wo))
+                return jnp.sum(jnp.sin(_share_and_oracle(x, q)[which]))
+            return jax.vmap(jax.grad(loss, range(5)),
+                            (0, None, None, None, None))(
+                x, p["router"], p["wi_gate"][4:8], p["wi_up"][4:8],
+                p["wo"][4:8])
+
+        for a, b in zip(jax.tree.leaves(grads(0)), jax.tree.leaves(grads(1))):
+            _rel_close(a, b)
+
+
+def test_merged_dispatch_moves_each_row_once(monkeypatch):
+    """``vmap(grad(moe_ffn_share))`` lowered for a TPU (kernels on, no
+    chip needed) moves the held slots' rows at most four times: one
+    gather in and one out in each pass, none by a float scatter. The
+    router's top-k gradient scatters into (B, T, experts) and the
+    kernel's group metadata into a few floats; neither is a row of the
+    slots."""
+    from jax._src.lib.mlir import ir
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "use_kernels", lambda: True)
+    B, T, d, f = 4, 128, 256, 128
+    cfg = HELD_CFG.with_(d_model=d, moe_d_ff=f)
+    k = cfg.num_experts_per_tok
+    p = jax.eval_shape(lambda: moe_lib.init_moe(0, "moe", cfg, jnp.float32))
+
+    def loss(x, router, p):
+        out, aux, _ = moe_lib.moe_ffn_share(x, dict(p, router=router), cfg)
+        return jnp.sum(out ** 2) + aux
+
+    x = jax.ShapeDtypeStruct((B, T, d), jnp.float32)
+    fn = jax.vmap(jax.grad(loss, (0, 1)), (0, None, None))
+    with jax.default_matmul_precision("highest"):
+        module = jax.jit(fn).trace(x, p["router"], p).lower(
+            lowering_platforms=("tpu",)).compiler_ir()
+    funcs = {op.attributes["sym_name"].value: op
+             for op in module.body.operations}
+
+    def census(func):
+        """(op name, float?, elements) of every op ``func`` runs, the
+        functions it calls followed."""
+        for block in (b for r in func.regions for b in r.blocks):
+            for o in block.operations:
+                o = o.operation
+                if o.name == "func.call":
+                    callee = str(o.attributes["callee"]).lstrip("@")
+                    yield from census(funcs[callee])
+                    continue
+                t = o.results[0].type if o.results else None
+                if isinstance(t, ir.RankedTensorType):
+                    yield (o.name, isinstance(t.element_type, ir.FloatType),
+                           int(np.prod(t.shape)))
+                yield from census(o)
+
+    ops_run = list(census(funcs["main"]))
+    rows = B * T * k * min(d, f)
+    gathers = [n for op, fl, n in ops_run
+               if op == "stablehlo.gather" and fl and n >= rows]
+    scatters = [n for op, fl, n in ops_run
+                if op == "stablehlo.scatter" and fl and n >= B * T * f]
+    kernels = [n for op, fl, n in ops_run if op == "stablehlo.custom_call"
+               and fl and n >= B * T * k * f]
+    assert len(gathers) <= 4, gathers
+    assert not scatters, scatters
+    assert len(kernels) == 6, kernels      # 3 forward, 3 input gradients
