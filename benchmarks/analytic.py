@@ -46,8 +46,7 @@ def active_params(cfg: ModelConfig, counts) -> float:
     return counts["total"] - n_experts_params * (1.0 - frac)
 
 
-def attention_flops(cfg: ModelConfig, seq: int, batch: int,
-                    cache_len: int = 0, decode: bool = False) -> float:
+def attention_flops(cfg: ModelConfig, seq: int, batch: int) -> float:
     """Score+PV flops for all attention layers (excl. projections, which
     live in 2*N*D)."""
     slots, G = dlm.layer_program(cfg)
@@ -56,9 +55,6 @@ def attention_flops(cfg: ModelConfig, seq: int, batch: int,
           if cfg.use_mla else cfg.resolved_head_dim)
     vd = cfg.v_head_dim if cfg.use_mla else cfg.resolved_head_dim
     h = cfg.num_heads
-    if decode:
-        kv = min(cache_len, cfg.sliding_window) if cfg.sliding_window else cache_len
-        return 2.0 * batch * h * (hd + vd) * kv * n_attn
     if cfg.sliding_window and cfg.sliding_window < seq:
         pairs = seq * cfg.sliding_window - cfg.sliding_window ** 2 / 2
     else:
@@ -100,37 +96,18 @@ class StepModel:
 
 def analytic_step(cfg: ModelConfig, shape: str, mesh_devices: int = 256,
                   model_axis: int = 16) -> StepModel:
-    from repro.launch.specs import SHAPES, serving_config
+    from repro.launch.specs import SHAPES
     info = SHAPES[shape]
-    cfg = serving_config(cfg, shape)
     seq, gb = info["seq"], info["global_batch"]
     counts = param_counts(cfg)
     n_act = active_params(cfg, counts)
     pb = counts["total"] * 2.0  # bf16 weight bytes (global)
 
-    if info["kind"] == "train":
-        tokens = gb * seq
-        mf = 6.0 * n_act * tokens
-        fl = mf + 3.0 * attention_flops(cfg, seq, gb) + 3.0 * ssm_flops(cfg, seq, gb)
-        # fwd+bwd reads weights ~3x; trainable also written; activations ~
-        # 2 bytes x tokens x d x layers x ~12 tensors, sharded over devices
-        act = 12.0 * 2.0 * tokens * cfg.d_model * cfg.num_layers / mesh_devices
-        by = 3.0 * pb / model_axis + act
-        return StepModel(fl, mf, by)
-
-    if info["kind"] == "prefill":
-        tokens = gb * seq
-        mf = 2.0 * n_act * tokens
-        fl = mf + attention_flops(cfg, seq, gb) + ssm_flops(cfg, seq, gb)
-        act = 2.0 * 2.0 * tokens * cfg.d_model * cfg.num_layers / mesh_devices
-        by = pb / model_axis + act
-        return StepModel(fl, mf, by)
-
-    # decode: one token against the cache
-    cache_struct = jax.eval_shape(
-        lambda: dlm.init_cache(cfg, gb, seq, dtype=jnp.bfloat16))
-    cache_bytes = basic.tree_bytes(cache_struct["slots"])
-    mf = 2.0 * n_act * gb
-    fl = mf + attention_flops(cfg, seq, gb, cache_len=seq, decode=True)
-    by = pb / model_axis + cache_bytes / mesh_devices
+    tokens = gb * seq
+    mf = 6.0 * n_act * tokens
+    fl = mf + 3.0 * attention_flops(cfg, seq, gb) + 3.0 * ssm_flops(cfg, seq, gb)
+    # fwd+bwd reads weights ~3x; trainable also written; activations ~
+    # 2 bytes x tokens x d x layers x ~12 tensors, sharded over devices
+    act = 12.0 * 2.0 * tokens * cfg.d_model * cfg.num_layers / mesh_devices
+    by = 3.0 * pb / model_axis + act
     return StepModel(fl, mf, by)

@@ -37,10 +37,8 @@ def roofline_terms(arch: str, shape: str, dry: dict, analytic) -> dict:
     # trip-count scaling for loop collectives
     from repro.configs.base import get_config
     from repro.models.decoder_lm import layer_program
-    from repro.launch.specs import SHAPES, serving_config
-    cfg = serving_config(get_config(arch), shape)
-    _, G = layer_program(cfg)
-    tau = 2 if SHAPES[shape]["kind"] == "train" else 1
+    _, G = layer_program(get_config(arch))
+    tau = 2  # local steps of the train step
     coll_bytes = base + in_loop * G * tau
     t_comp = analytic.flops_global / (CHIPS * PEAK)
     t_mem = analytic.bytes_per_device / HBM
@@ -65,7 +63,7 @@ def roofline_terms(arch: str, shape: str, dry: dict, analytic) -> dict:
 def build_table(dryrun_path: str):
     from repro.configs import load_all, ARCH_IDS
     from repro.configs.base import get_config
-    from repro.launch.specs import SHAPES, skip_reason
+    from repro.launch.specs import SHAPES
     from benchmarks import analytic as ana
 
     load_all()
@@ -75,10 +73,6 @@ def build_table(dryrun_path: str):
     for arch in ARCH_IDS:
         for shape in SHAPES:
             d = dry.get((arch, shape), {"status": "missing"})
-            if d.get("status") == "skip":
-                rows.append({"arch": arch, "shape": shape, "status": "skip",
-                             "reason": d.get("reason")})
-                continue
             if d.get("status") != "ok":
                 rows.append({"arch": arch, "shape": shape,
                              "status": d.get("status")})

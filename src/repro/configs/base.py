@@ -78,7 +78,6 @@ class ModelConfig:
     # perf knobs (hillclimb variants; 0/auto = paper-faithful baseline)
     moe_dispatch_groups: int = 0   # >1: group-local sort dispatch
     expert_shard: str = "auto"     # auto | model | 2d | 2d_swapped
-    decode_seq_parallel: bool = False  # flash-decoding style cache attn
 
     # --- MLA (DeepSeek-V2) ----------------------------------------------------
     use_mla: bool = False

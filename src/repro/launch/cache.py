@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache for the entry points.
 
-Called from the ``main()`` of ``launch/train.py`` and ``launch/serve.py``
-and from ``chip_smoke.py`` — never at import. ``JAX_COMPILATION_CACHE_DIR``,
+Called from the ``main()`` of ``launch/train.py`` and from
+``chip_smoke.py`` — never at import. ``JAX_COMPILATION_CACHE_DIR``,
 when set, is JAX's own setting and wins; otherwise the cache lives at the
 fixed, git-ignored ``<checkout>/.jax_cache``. The path is part of the
 cache key, so it never depends on a pid, a clock or a temp directory.

@@ -20,7 +20,6 @@ falls back to replication on that axis (e.g. whisper's 51866 vocab).
 from __future__ import annotations
 
 import re
-from typing import Optional
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -221,54 +220,3 @@ def batch_sharding(tree_struct, mesh, batch_axes=("pod", "data"),
 
     return jax.tree_util.tree_map(one, tree_struct)
 
-
-def cache_shardings(cache_struct, cfg: ModelConfig, mesh, long_context: bool):
-    """KV-cache / SSM-state shardings for serving.
-
-    decode_32k: batch over ("pod","data"), cache seq over "model".
-    long_500k (batch=1): cache seq over ("data","model"); SSM states shard
-    their channel dim.
-    """
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    dax = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-    def one_path(path, leaf):
-        shp = leaf.shape
-        spec = [None] * len(shp)
-        if path.endswith("cache_len"):
-            return NamedSharding(mesh, P())
-        is_seq_cache = any(path.endswith(s) for s in
-                           ("/k", "/v", "/ckv", "/kpe"))
-        if is_seq_cache:
-            # (G, B, S, ...)
-            if long_context:
-                want = sizes.get("data", 1) * sizes.get("model", 1)
-                if shp[2] % want == 0:
-                    spec[2] = ("data", "model")
-                elif shp[2] % sizes.get("model", 1) == 0:
-                    spec[2] = "model"
-            else:
-                total = 1
-                for a in dax:
-                    total *= sizes[a]
-                if shp[1] % total == 0:
-                    spec[1] = dax if len(dax) > 1 else dax[0]
-                if shp[2] % sizes.get("model", 1) == 0:
-                    spec[2] = "model"
-            return NamedSharding(mesh, P(*spec))
-        # SSM states: (G, B, channels, ...) — shard the channel dim
-        for d in range(2, len(shp)):
-            if shp[d] % sizes.get("model", 1) == 0 and shp[d] >= sizes.get("model", 1):
-                spec[d] = "model"
-                break
-        if not long_context:
-            total = 1
-            for a in dax:
-                total *= sizes[a]
-            if shp[1] % total == 0:
-                spec[1] = dax if len(dax) > 1 else dax[0]
-        return NamedSharding(mesh, P(*spec))
-
-    flat = dict(basic.flatten_params(cache_struct))
-    out = {p: one_path(p, l) for p, l in flat.items()}
-    return basic.unflatten_params(out)

@@ -1,30 +1,22 @@
-"""Input specifications and step builders for every (architecture x
-input-shape) pair — the substrate of the multi-pod dry-run.
+"""Input specifications and the round-step builder for every
+(architecture x input-shape) pair — the substrate of the multi-pod
+dry-run.
 
 All inputs are ShapeDtypeStructs (no allocation); params come from
 jax.eval_shape over the real init. The FROZEN tree is bf16 (read-only
 weights), the TRAINABLE tree stays f32 (master copy) — the standard
 mixed-precision split.
 
-Shapes (assignment):
+Shapes:
   train_4k     seq 4,096   global_batch 256   -> fedpt_round_step
-  prefill_32k  seq 32,768  global_batch 32    -> prefill_step
-  decode_32k   seq 32,768  global_batch 128   -> serve_step (1 token)
-  long_500k    seq 524,288 global_batch 1     -> serve_step (1 token)
-
-`long_500k` is only lowered for sub-quadratic-capable architectures
-(SSM / hybrid / sliding-window); see SKIPS.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
-import math
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 import repro.core.partition as part
 from repro.configs.base import ModelConfig, get_config
@@ -32,39 +24,15 @@ from repro.core import fedpt
 from repro.launch import mesh as mesh_lib
 from repro.launch import sharding as shard_lib
 from repro.models import decoder_lm as dlm
-from repro.nn import basic
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
 I32 = jnp.int32
 
 SHAPES = {
-    "train_4k": dict(seq=4096, global_batch=256, kind="train"),
-    "prefill_32k": dict(seq=32768, global_batch=32, kind="prefill"),
-    "decode_32k": dict(seq=32768, global_batch=128, kind="decode"),
-    "long_500k": dict(seq=524288, global_batch=1, kind="decode"),
+    "train_4k": dict(seq=4096, global_batch=256),
 }
-
-# Principled skips (DESIGN.md §shape-coverage): long_500k needs
-# sub-quadratic attention. SWA archs get it natively; mistral-nemo gets
-# our beyond-paper SWA serving variant; pure full-attention archs skip.
-LONG_OK = {"mixtral-8x7b", "jamba-v0.1-52b", "xlstm-350m", "mistral-nemo-12b"}
-# serving SWA window applied to nemo for long_500k only:
-NEMO_SERVE_WINDOW = 8192
 VISION_TOWER_DIM = 1152
-
-
-def skip_reason(arch: str, shape: str) -> Optional[str]:
-    if shape == "long_500k" and arch not in LONG_OK:
-        return "pure full-attention arch: 500k decode excluded by design"
-    return None
-
-
-def serving_config(cfg: ModelConfig, shape: str) -> ModelConfig:
-    if shape == "long_500k" and cfg.name == "mistral-nemo-12b":
-        # beyond-paper serving adaptation: rolling-buffer SWA cache
-        return cfg.with_(sliding_window=NEMO_SERVE_WINDOW)
-    return cfg
 
 
 # ---------------------------------------------------------------------------
@@ -85,7 +53,7 @@ def _sds(shape, dtype):
 
 
 # ---------------------------------------------------------------------------
-# Input specs per shape kind
+# Input specs
 
 
 def train_specs(cfg: ModelConfig, mesh, seq: int, global_batch: int,
@@ -112,27 +80,8 @@ def train_specs(cfg: ModelConfig, mesh, seq: int, global_batch: int,
     return batch, weights, clients
 
 
-def prefill_specs(cfg: ModelConfig, seq: int, global_batch: int):
-    tok_seq = seq - cfg.num_prefix_tokens if cfg.family == "vlm" else seq
-    batch = {"tokens": _sds((global_batch, tok_seq), I32)}
-    if cfg.family == "vlm":
-        batch["prefix_embeds"] = _sds(
-            (global_batch, cfg.num_prefix_tokens, VISION_TOWER_DIM), BF16)
-    if cfg.is_encoder_decoder:
-        batch["encoder_embeds"] = _sds(
-            (global_batch, cfg.encoder_seq_len, cfg.d_model), BF16)
-    return batch
-
-
-def decode_specs(cfg: ModelConfig, seq: int, global_batch: int):
-    cache = jax.eval_shape(
-        lambda: dlm.init_cache(cfg, global_batch, seq, dtype=BF16))
-    tokens = _sds((global_batch, 1), I32)
-    return cache, tokens
-
-
 # ---------------------------------------------------------------------------
-# Step builders
+# Step builder
 
 
 def make_train_step(cfg: ModelConfig, mesh, y_struct):
@@ -175,28 +124,6 @@ def make_train_step(cfg: ModelConfig, mesh, y_struct):
     return train_step, server_opt
 
 
-def make_prefill_step(cfg: ModelConfig):
-    def prefill_step(y, frozen, batch):
-        params = part.merge(y, frozen)
-        kw = {}
-        if cfg.family == "vlm":
-            kw["prefix_embeds"] = batch["prefix_embeds"]
-        if cfg.is_encoder_decoder:
-            kw["encoder_embeds"] = batch["encoder_embeds"]
-        logits, metrics = dlm.forward(params, cfg, batch["tokens"], **kw)
-        return logits
-
-    return prefill_step
-
-
-def make_decode_step(cfg: ModelConfig):
-    def serve_step(y, frozen, cache, tokens):
-        params = part.merge(y, frozen)
-        return dlm.decode_step(params, cfg, cache, tokens)
-
-    return serve_step
-
-
 # ---------------------------------------------------------------------------
 # Assembled lowering spec per (arch, shape, mesh)
 
@@ -213,47 +140,23 @@ class LoweringJob:
 
 
 def build_job(arch: str, shape: str, mesh, cfg_override=None) -> LoweringJob:
-    base_cfg = cfg_override if cfg_override is not None else get_config(arch)
+    cfg = cfg_override if cfg_override is not None else get_config(arch)
     info = SHAPES[shape]
-    cfg = serving_config(base_cfg, shape)
     y_struct, z_struct = param_structs(cfg)
     shard_y = shard_lib.param_shardings(y_struct, cfg, mesh)
     shard_z = shard_lib.param_shardings(z_struct, cfg, mesh)
     from jax.sharding import NamedSharding, PartitionSpec as P
     rep = NamedSharding(mesh, P())
 
-    if info["kind"] == "train":
-        batch, weights, clients = train_specs(cfg, mesh, info["seq"],
-                                              info["global_batch"])
-        train_step, server_opt = make_train_step(cfg, mesh, y_struct)
-        sstate_struct = jax.eval_shape(server_opt.init, y_struct)
-        shard_ss = jax.tree_util.tree_map(
-            lambda s: shard_lib.param_shardings(y_struct, cfg, mesh), ())
-        # sgdm state mirrors y's structure -> same shardings
-        shard_sstate = shard_lib.param_shardings(sstate_struct, cfg, mesh)
-        shard_batch = shard_lib.batch_sharding(batch, mesh)
-        seed = _sds((1,), I32)
-        args = (y_struct, sstate_struct, z_struct, batch,
-                _sds((clients,), F32), seed)
-        inshard = (shard_y, shard_sstate, shard_z, shard_batch,
-                   shard_lib.batch_sharding(_sds((clients,), F32), mesh), rep)
-        return LoweringJob(arch, shape, train_step, args, inshard, cfg,
-                           clients)
-
-    if info["kind"] == "prefill":
-        batch = prefill_specs(cfg, info["seq"], info["global_batch"])
-        fn = make_prefill_step(cfg)
-        args = (y_struct, z_struct, batch)
-        inshard = (shard_y, shard_z, shard_lib.batch_sharding(batch, mesh))
-        return LoweringJob(arch, shape, fn, args, inshard, cfg)
-
-    # decode
-    cache, tokens = decode_specs(cfg, info["seq"], info["global_batch"])
-    fn = make_decode_step(cfg)
-    long_ctx = shape == "long_500k"
-    shard_cache = shard_lib.cache_shardings(cache, cfg, mesh, long_ctx)
-    tok_shard = (shard_lib.batch_sharding(tokens, mesh)
-                 if not long_ctx else rep)
-    args = (y_struct, z_struct, cache, tokens)
-    inshard = (shard_y, shard_z, shard_cache, tok_shard)
-    return LoweringJob(arch, shape, fn, args, inshard, cfg)
+    batch, weights, clients = train_specs(cfg, mesh, info["seq"],
+                                          info["global_batch"])
+    train_step, server_opt = make_train_step(cfg, mesh, y_struct)
+    sstate_struct = jax.eval_shape(server_opt.init, y_struct)
+    # sgdm state mirrors y's structure -> same shardings
+    shard_sstate = shard_lib.param_shardings(sstate_struct, cfg, mesh)
+    shard_batch = shard_lib.batch_sharding(batch, mesh)
+    seed = _sds((1,), I32)
+    args = (y_struct, sstate_struct, z_struct, batch, weights, seed)
+    inshard = (shard_y, shard_sstate, shard_z, shard_batch,
+               shard_lib.batch_sharding(weights, mesh), rep)
+    return LoweringJob(arch, shape, train_step, args, inshard, cfg, clients)

@@ -27,15 +27,11 @@ Parameter layout (nested dicts; ``G`` = scanned groups):
 
 With ``cfg.remat`` every layer (a lead layer, a scanned group) keeps only
 its input for the backward pass and recomputes the rest.
-
-KV caches: full-length buffers for global attention, ring buffers of
-`sliding_window` size for SWA architectures (Mistral-style rolling
-cache) — the latter is what makes `long_500k` decode feasible.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -165,7 +161,7 @@ def init_model(cfg: ModelConfig, seed: int) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# Forward (training / prefill)
+# Forward (training)
 
 
 def sinusoid_pos(positions, d_model, dtype):
@@ -221,22 +217,19 @@ def _moe(h2, mp, cfg: ModelConfig, stats):
 
 def _apply_slot(x, sp, cfg: ModelConfig, slot: Slot, positions, stats,
                 encoder_out=None, prefix_len=0, causal=True):
-    """One residual block. Returns (x, stats, cache_entry)."""
+    """One residual block. Returns (x, stats)."""
     cd = cfg.cdtype
     h = basic.apply_norm(x, sp["ln1"], cfg.norm_type)
-    cache = ()
     if slot.kind == ATTN:
         if cfg.use_mla:
             with jax.named_scope(SCOPE_MLA):
-                q, k, v, (ckv, kpe) = attn_lib.mla_qkv(h, sp["attn"], cfg,
-                                                       positions)
+                q, k, v = attn_lib.mla_qkv(h, sp["attn"], cfg, positions)
                 o = attn_lib.flash_attention(
                     q, k, v, cfg.with_(sliding_window=0), causal=causal,
                     prefix_len=prefix_len,
                     scale=attn_lib.mla_softmax_scale(cfg))
                 o = o.reshape(o.shape[:2] + (o.shape[2] * o.shape[3],))
                 o = basic.dense(o, sp["attn"]["wo"], cd)
-            cache = (ckv, kpe)
         else:
             q, k, v = attn_lib.qkv_project(h, sp["attn"], cfg)
             if cfg.use_rope:
@@ -249,7 +242,6 @@ def _apply_slot(x, sp, cfg: ModelConfig, slot: Slot, positions, stats,
                                          prefix_len=prefix_len)
             o = o.reshape(o.shape[0], o.shape[1], -1)
             o = basic.dense(o, sp["attn"]["wo"], cd)
-            cache = (k, v)
         x = x + o
         if "cross_attn" in sp and encoder_out is not None:
             hc = basic.apply_norm(x, sp["ln_cross"], cfg.norm_type)
@@ -260,22 +252,21 @@ def _apply_slot(x, sp, cfg: ModelConfig, slot: Slot, positions, stats,
             oc = oc.reshape(oc.shape[0], oc.shape[1], -1)
             x = x + basic.dense(oc, sp["cross_attn"]["wo"], cd)
     elif slot.kind == MAMBA:
-        o, st = ssm_lib.mamba_forward(h, sp["mamba"], cfg)
+        o, _ = ssm_lib.mamba_forward(h, sp["mamba"], cfg)
         x = x + o
-        cache = st
     elif slot.kind == MLSTM:
-        o, st = ssm_lib.mlstm_forward(h, sp["mlstm"], cfg)
-        return x + o, stats, st
+        o, _ = ssm_lib.mlstm_forward(h, sp["mlstm"], cfg)
+        return x + o, stats
     elif slot.kind == SLSTM:
-        o, st = ssm_lib.slstm_forward(h, sp["slstm"], cfg)
-        return x + o, stats, st
+        o, _ = ssm_lib.slstm_forward(h, sp["slstm"], cfg)
+        return x + o, stats
 
     h2 = basic.apply_norm(x, sp["ln2"], cfg.norm_type)
     if slot.use_moe:
         y, stats = _moe(h2, sp["moe"], cfg, stats)
     else:
         y = basic.mlp(h2, sp["ffn"], cfg.act, cd)
-    return x + y, stats, cache
+    return x + y, stats
 
 
 def _maybe_remat(fn, cfg: ModelConfig):
@@ -283,14 +274,13 @@ def _maybe_remat(fn, cfg: ModelConfig):
 
 
 def forward(params, cfg: ModelConfig, tokens, prefix_embeds=None,
-            encoder_embeds=None, return_caches: bool = False):
+            encoder_embeds=None):
     """tokens: (B, S) int32. prefix_embeds: (B, P, 1152) VLM stub input.
     encoder_embeds: (B, E, d_model) audio stub input (enc-dec only).
 
-    Returns (logits, metrics[, caches]).
+    Returns (logits, metrics).
     """
     cd = cfg.cdtype
-    slots, n_groups = layer_program(cfg)
     x = basic.embed(tokens, params["embed"], cd)
     prefix_len = 0
     if cfg.family == "vlm" and prefix_embeds is not None:
@@ -315,19 +305,16 @@ def forward(params, cfg: ModelConfig, tokens, prefix_embeds=None,
                                        cfg.norm_type)
 
     stats = _stats0(cfg)
-    lead_caches = {}
     for i, slot in enumerate(lead_slots(cfg)):
         def lead_layer(x, stats, lp, slot=slot):
             with jax.named_scope(SCOPE_LEAD):
                 return _apply_slot(x, lp, cfg, slot, positions, stats,
                                    prefix_len=prefix_len)
-        x, stats, lead_caches[f"layer{i}"] = _maybe_remat(lead_layer, cfg)(
+        x, stats = _maybe_remat(lead_layer, cfg)(
             x, stats, params["lead"][f"layer{i}"])
 
-    x, stats, caches = _run_stack(params["layers"], cfg, x, positions,
-                                  stats=stats, encoder_out=encoder_out,
-                                  prefix_len=prefix_len,
-                                  collect_caches=return_caches)
+    x, stats = _run_stack(params["layers"], cfg, x, positions, stats=stats,
+                          encoder_out=encoder_out, prefix_len=prefix_len)
 
     with jax.named_scope(SCOPE_HEAD):
         x = basic.apply_norm(x, params["final_norm"], cfg.norm_type)
@@ -335,35 +322,26 @@ def forward(params, cfg: ModelConfig, tokens, prefix_embeds=None,
             logits = basic.unembed(x, params["embed"], cd)
         else:
             logits = x @ params["unembed"]["kernel"].astype(cd)
-    if return_caches:
-        if lead_caches:
-            caches = {"lead": lead_caches, "stack": caches}
-        return logits, stats, caches
     return logits, stats
 
 
 def _run_stack(stack_params, cfg: ModelConfig, x, positions, noncausal=False,
-               stats=None, encoder_out=None, prefix_len=0,
-               collect_caches=False):
+               stats=None, encoder_out=None, prefix_len=0):
     slots, n_groups = layer_program(cfg)
 
     def group_body(carry, group_params):
         x, stats = carry
-        caches = []
         for si, slot in enumerate(slots):
-            x, stats, c = _apply_slot(x, group_params[f"slot{si}"], cfg,
-                                      slot, positions, stats,
-                                      encoder_out=encoder_out,
-                                      prefix_len=prefix_len,
-                                      causal=not noncausal)
-            caches.append(c)
-        out = tuple(caches) if collect_caches else ()
-        return (x, stats), out
+            x, stats = _apply_slot(x, group_params[f"slot{si}"], cfg, slot,
+                                   positions, stats, encoder_out=encoder_out,
+                                   prefix_len=prefix_len,
+                                   causal=not noncausal)
+        return (x, stats), ()
 
-    (x, stats), caches = jax.lax.scan(
+    (x, stats), _ = jax.lax.scan(
         _maybe_remat(group_body, cfg),
         (x, _stats0(cfg) if stats is None else stats), stack_params)
-    return x, stats, caches
+    return x, stats
 
 
 # ---------------------------------------------------------------------------
@@ -399,210 +377,3 @@ def train_loss(params, cfg: ModelConfig, batch):
     if cfg.router_aux_loss and cfg.num_experts:
         loss = loss + cfg.router_aux_loss * metrics["moe_aux_loss"]
     return loss, metrics
-
-
-# ---------------------------------------------------------------------------
-# Decode (serving): single-token step against per-layer caches.
-#
-# Attention layers use a full-length cache, or a Mistral-style ring buffer
-# of `sliding_window` entries for SWA architectures (RoPE is applied at
-# absolute positions on write, so relative geometry survives the ring).
-# SSM layers carry constant-size recurrent states.
-
-
-def cache_capacity(cfg: ModelConfig, max_len: int) -> int:
-    if cfg.sliding_window and cfg.sliding_window < max_len:
-        return cfg.sliding_window
-    return max_len
-
-
-def _cache_entry(cfg: ModelConfig, slot: Slot, G: int, batch: int, S: int,
-                 cd):
-    """One slot's zero cache, stacked over G layers."""
-    kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    if slot.kind == ATTN and cfg.use_mla:
-        return {"ckv": jnp.zeros((G, batch, S, cfg.kv_lora_rank), cd),
-                "kpe": jnp.zeros((G, batch, S, cfg.qk_rope_head_dim), cd)}
-    if slot.kind == ATTN:
-        return {"k": jnp.zeros((G, batch, S, kvh, hd), cd),
-                "v": jnp.zeros((G, batch, S, kvh, hd), cd)}
-    if slot.kind == MAMBA:
-        di, _ = ssm_lib.mamba_dims(cfg)
-        return {"h": jnp.zeros((G, batch, di, cfg.mamba_d_state),
-                               jnp.float32),
-                "conv": jnp.zeros((G, batch, cfg.mamba_d_conv - 1, di), cd)}
-    d_in_x, nh_x, dh_x = ssm_lib.xlstm_dims(cfg)
-    if slot.kind == MLSTM:
-        return {"C": jnp.zeros((G, batch, nh_x, dh_x, dh_x), jnp.float32),
-                "n": jnp.zeros((G, batch, nh_x, dh_x), jnp.float32),
-                "conv": jnp.zeros((G, batch, 3, d_in_x), cd)}
-    dh_s = cfg.d_model // cfg.num_heads
-    z = jnp.zeros((G, batch, cfg.num_heads, dh_s), jnp.float32)
-    return {"c": z, "n": z, "h": z, "m": z - 30.0,
-            "conv": jnp.zeros((G, batch, 3, cfg.d_model), cd)}
-
-
-def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
-    """Zero caches for decoding up to max_len tokens. Returns a pytree with
-    a per-slot entry stacked over groups, a per-leading-layer entry (with
-    a leading axis of 1, so every entry has the same layout) and a scalar
-    cache_len."""
-    cd = dtype or cfg.cdtype
-    slots, G = layer_program(cfg)
-    S = cache_capacity(cfg, max_len)
-    kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    entries = [_cache_entry(cfg, slot, G, batch, S, cd) for slot in slots]
-    cache = {"slots": {f"slot{i}": e for i, e in enumerate(entries)},
-             "cache_len": jnp.zeros((), jnp.int32)}
-    if cfg.first_k_dense:
-        cache["lead"] = {f"layer{i}": _cache_entry(cfg, slot, 1, batch, S,
-                                                   cd)
-                         for i, slot in enumerate(lead_slots(cfg))}
-    if cfg.is_encoder_decoder:
-        E = cfg.encoder_seq_len
-        cache["cross"] = {
-            f"slot{i}": {"k": jnp.zeros((G, batch, E, kvh, hd), cd),
-                         "v": jnp.zeros((G, batch, E, kvh, hd), cd)}
-            for i, slot in enumerate(slots) if slot.kind == ATTN}
-    return cache
-
-
-def build_cross_cache(params, cfg: ModelConfig, encoder_embeds):
-    """Precompute encoder K/V for every decoder cross-attention slot."""
-    cd = cfg.cdtype
-    slots, G = layer_program(cfg)
-    enc_pos = jnp.arange(encoder_embeds.shape[1])[None, :]
-    enc_x = encoder_embeds.astype(cd)
-    if not cfg.use_rope:
-        enc_x = enc_x + sinusoid_pos(enc_pos, cfg.d_model, cd)
-    enc_cfg = cfg.with_(num_layers=cfg.encoder_layers or cfg.num_layers,
-                        sliding_window=0)
-    enc = _run_stack(params["enc_layers"], enc_cfg, enc_x, enc_pos,
-                     noncausal=True)[0]
-    enc = basic.apply_norm(enc, params["enc_norm"], cfg.norm_type)
-
-    def per_group(gp):
-        out = {}
-        for i, slot in enumerate(slots):
-            if slot.kind != ATTN:
-                continue
-            sp = gp[f"slot{i}"]
-            _, kc, vc = attn_lib.qkv_project(enc, sp["cross_attn"], cfg)
-            out[f"slot{i}"] = {"k": kc, "v": vc}
-        return out
-
-    return jax.vmap(per_group, in_axes=0, out_axes=0)(params["layers"])
-
-
-def _decode_slot(x, sp, cfg: ModelConfig, slot: Slot, cache, cross,
-                 cache_len, pos):
-    """x: (B,1,d). Returns (x, new_cache)."""
-    cd = cfg.cdtype
-    h = basic.apply_norm(x, sp["ln1"], cfg.norm_type)
-    if slot.kind == ATTN:
-        S = cache["k"].shape[1] if "k" in cache else cache["ckv"].shape[1]
-        widx = jnp.mod(cache_len, S)                      # ring write index
-        cl_eff = jnp.minimum(cache_len + 1, S)
-        if cfg.use_mla:
-            ckv, kpe = attn_lib.mla_compress(h, sp["attn"], cfg, pos[None, :])
-            new_ckv = jax.lax.dynamic_update_slice(
-                cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, widx, 0))
-            new_kpe = jax.lax.dynamic_update_slice(
-                cache["kpe"], kpe.astype(cache["kpe"].dtype), (0, widx, 0))
-            o = attn_lib.mla_decode(h, sp["attn"], cfg, new_ckv, new_kpe,
-                                    cl_eff)
-            cache = {"ckv": new_ckv, "kpe": new_kpe}
-        else:
-            q, k, v = attn_lib.qkv_project(h, sp["attn"], cfg)
-            if cfg.use_rope:
-                cos, sin = attn_lib.rope_freqs(cfg.resolved_head_dim,
-                                               cfg.rope_theta, pos[None, :],
-                                               cfg.rope_scaling)
-                q = attn_lib.apply_rope(q, cos, sin)
-                k = attn_lib.apply_rope(k, cos, sin)
-            new_k = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, widx, 0, 0))
-            new_v = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, widx, 0, 0))
-            o = attn_lib.decode_attention(q, new_k, new_v, cl_eff,
-                                          cfg.with_(sliding_window=0))
-            o = basic.dense(o.reshape(o.shape[0], 1, -1), sp["attn"]["wo"], cd)
-            cache = {"k": new_k, "v": new_v}
-        x = x + o
-        if cross is not None and "cross_attn" in sp:
-            hc = basic.apply_norm(x, sp["ln_cross"], cfg.norm_type)
-            qc, _, _ = attn_lib.qkv_project(hc, sp["cross_attn"], cfg)
-            oc = attn_lib.decode_attention(
-                qc, cross["k"], cross["v"], cross["k"].shape[1],
-                cfg.with_(sliding_window=0))
-            x = x + basic.dense(oc.reshape(oc.shape[0], 1, -1),
-                                sp["cross_attn"]["wo"], cd)
-    elif slot.kind == MAMBA:
-        o, (hh, conv) = ssm_lib.mamba_step(h[:, 0, :], sp["mamba"], cfg,
-                                           (cache["h"], cache["conv"]))
-        x = x + o[:, None, :]
-        cache = {"h": hh, "conv": conv}
-    elif slot.kind == MLSTM:
-        o, (C, n, conv) = ssm_lib.mlstm_step(
-            h[:, 0, :], sp["mlstm"], cfg, (cache["C"], cache["n"], cache["conv"]))
-        return x + o[:, None, :], {"C": C, "n": n, "conv": conv}
-    elif slot.kind == SLSTM:
-        cell = (cache["c"], cache["n"], cache["h"], cache["m"])
-        o, (cell, conv) = ssm_lib.slstm_step(h[:, 0, :], sp["slstm"], cfg,
-                                             (cell, cache["conv"]))
-        return x + o[:, None, :], {"c": cell[0], "n": cell[1], "h": cell[2],
-                                   "m": cell[3], "conv": conv}
-
-    h2 = basic.apply_norm(x, sp["ln2"], cfg.norm_type)
-    if slot.use_moe:
-        y, _ = _moe(h2, sp["moe"], cfg, _stats0(cfg))
-    else:
-        y = basic.mlp(h2, sp["ffn"], cfg.act, cd)
-    return x + y, cache
-
-
-def decode_step(params, cfg: ModelConfig, cache, tokens):
-    """tokens: (B, 1) int32 -> (logits (B, 1, V), new cache)."""
-    cd = cfg.cdtype
-    slots, G = layer_program(cfg)
-    cache_len = cache["cache_len"]
-    pos = cache_len[None]  # absolute position of this token
-    x = basic.embed(tokens, params["embed"], cd)
-    if not cfg.use_rope:
-        x = x + sinusoid_pos(pos[None, :], cfg.d_model, cd)
-
-    new_lead = {}
-    for i, slot in enumerate(lead_slots(cfg)):
-        lc = jax.tree_util.tree_map(lambda a: a[0],
-                                    cache["lead"][f"layer{i}"])
-        x, lc = _decode_slot(x, params["lead"][f"layer{i}"], cfg, slot, lc,
-                             None, cache_len, pos)
-        new_lead[f"layer{i}"] = jax.tree_util.tree_map(lambda a: a[None],
-                                                       lc)
-
-    def group_body(x, xs):
-        gp, gc, gcross = xs
-        new_caches = {}
-        for si, slot in enumerate(slots):
-            key = f"slot{si}"
-            cr = gcross.get(key) if gcross else None
-            x, nc = _decode_slot(x, gp[key], cfg, slot, gc[key], cr,
-                                 cache_len, pos)
-            new_caches[key] = nc
-        return x, new_caches
-
-    cross = cache.get("cross")
-    (x, new_slots) = jax.lax.scan(
-        group_body, x, (params["layers"], cache["slots"], cross))
-
-    x = basic.apply_norm(x, params["final_norm"], cfg.norm_type)
-    if cfg.tie_embeddings:
-        logits = basic.unembed(x, params["embed"], cd)
-    else:
-        logits = x @ params["unembed"]["kernel"].astype(cd)
-    new_cache = dict(cache)
-    new_cache["slots"] = new_slots
-    if new_lead:
-        new_cache["lead"] = new_lead
-    new_cache["cache_len"] = cache_len + 1
-    return logits, new_cache

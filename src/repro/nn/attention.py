@@ -1,7 +1,6 @@
 """Attention: RoPE, GQA multi-head attention with chunked online-softmax
 (flash-style, bounded memory at 32k+ sequence lengths), sliding windows,
-MLA (DeepSeek-V2 multi-head latent attention), and single-token decode
-against a KV cache (including sequence-sharded caches for 500k context).
+and MLA (DeepSeek-V2 multi-head latent attention).
 """
 from __future__ import annotations
 
@@ -155,14 +154,13 @@ def _attend_chunk(q, k, v, qpos, kpos, window: int, softcap: float, scale,
     return jnp.where(mask[None, None], s, NEG_INF)
 
 
-def flash_attention(q, k, v, cfg: ModelConfig, q_offset=0, chunk: int = 512,
+def flash_attention(q, k, v, cfg: ModelConfig, chunk: int = 512,
                     causal: bool = True, prefix_len: int = 0,
                     scale: Optional[float] = None):
     """Causal (optionally sliding-window) attention.
 
     q: (b, sq, h, hd);  k, v: (b, skv, kv_heads, hd_k); v may have a
     different per-head dim than q/k (MLA).
-    q_offset: position of q[0] relative to k[0] (for prefill continuation).
     scale: the softmax scale (default hd^-1/2).
     Returns (b, sq, h, dv).
     """
@@ -190,7 +188,7 @@ def flash_attention(q, k, v, cfg: ModelConfig, q_offset=0, chunk: int = 512,
     kh = kh.reshape(b, h, nchunks, chunk, hd).transpose(2, 0, 1, 3, 4)
     vh = vh.reshape(b, h, nchunks, chunk, dv).transpose(2, 0, 1, 3, 4)
 
-    qpos = q_offset + jnp.arange(sq)
+    qpos = jnp.arange(sq)
 
     def body(carry, xs):
         m, l, acc = carry
@@ -218,56 +216,11 @@ def flash_attention(q, k, v, cfg: ModelConfig, q_offset=0, chunk: int = 512,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len, cfg: ModelConfig):
-    """One-token decode: q (b, 1, h, hd) against caches (b, S, kvh, hd).
-
-    cache_len: scalar or (b,) number of valid cache positions. Works with a
-    sequence-sharded cache under GSPMD (the softmax is numerically global —
-    computed via max/sum reductions XLA turns into cross-shard psums).
-    """
-    b, _, h, hd = q.shape
-    S, kvh = k_cache.shape[1], k_cache.shape[2]
-    rep = h // kvh
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
-    kh = k_cache
-    vh = v_cache
-    if rep > 1:
-        kh = jnp.repeat(kh, rep, axis=2)
-        vh = jnp.repeat(vh, rep, axis=2)
-    if cfg.decode_seq_parallel:
-        # flash-decoding layout (perf variant, DESIGN.md §Perf/H2): the
-        # tiny q replicates across "model"; the huge cache stays
-        # sequence-sharded; softmax/PV reduce over the sharded S axis
-        # (GSPMD emits psum of (b,h,1,dv) partials instead of
-        # all-gathering the cache).
-        q = basic.maybe_constrain(q, (("pod", "data"), None, None, None))
-        kh = basic.maybe_constrain(kh, (("pod", "data"), "model", None, None))
-        vh = basic.maybe_constrain(vh, (("pod", "data"), "model", None, None))
-    s = jnp.einsum("bqhd,bshd->bhqs", q, kh,
-                   preferred_element_type=jnp.float32) * scale
-    if cfg.decode_seq_parallel:
-        s = basic.maybe_constrain(s, (("pod", "data"), None, None, "model"))
-    if cfg.attn_logit_softcap > 0:
-        s = cfg.attn_logit_softcap * jnp.tanh(s / cfg.attn_logit_softcap)
-    pos = jnp.arange(S)
-    cl = jnp.asarray(cache_len)
-    cl = cl[:, None, None, None] if cl.ndim else cl
-    mask = pos[None, None, None, :] < cl
-    if cfg.sliding_window > 0:
-        mask = mask & (pos[None, None, None, :] >= cl - cfg.sliding_window)
-    s = jnp.where(mask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(vh.dtype)
-    out = jnp.einsum("bhqs,bshd->bqhd", p, vh,
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
-
-
 # ---------------------------------------------------------------------------
 # MLA — DeepSeek-V2 multi-head latent attention (arXiv:2405.04434).
 #
 # KV is compressed to a kv_lora_rank latent c_kv plus a shared rope key
-# k_pe; decode caches only (c_kv, k_pe) — 576 dims instead of
-# 2*num_heads*head_dim — and uses the absorbed-matmul form.
+# k_pe, and up-projected to per-head keys and values.
 
 
 def init_mla(seed, path, cfg: ModelConfig, dtype):
@@ -293,11 +246,10 @@ def init_mla(seed, path, cfg: ModelConfig, dtype):
 
 
 def mla_qkv(x, p, cfg: ModelConfig, positions):
-    """Full (non-absorbed) MLA for train/prefill.
+    """Full (non-absorbed) MLA.
 
     Returns q, k, v shaped (b, s, h, dim) with rope applied; k/v have
-    per-head dims qn+qr and v_head_dim. Also returns the compressed
-    (c_kv, k_pe) pair for cache write.
+    per-head dims qn+qr and v_head_dim.
     """
     b, s, _ = x.shape
     h = cfg.num_heads
@@ -325,66 +277,4 @@ def mla_qkv(x, p, cfg: ModelConfig, positions):
     k_pe_b = jnp.broadcast_to(k_pe_r, (b, s, h, qr))
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, k_pe_b], axis=-1)
-    return q, k, v, (c_kv, k_pe_r[..., 0, :])
-
-
-def mla_compress(x, p, cfg: ModelConfig, positions):
-    """Compute only the compressed cache entries (c_kv, roped k_pe) for a
-    new token. x: (b, s, d) -> ckv (b, s, r), kpe (b, s, qr)."""
-    r, qr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    cd = cfg.cdtype
-    kv = basic.dense(x, p["wkv_a"], cd)
-    c_kv, k_pe = kv[..., :r], kv[..., r:]
-    c_kv = basic.rmsnorm(c_kv, p["kv_norm"]["scale"])
-    cos, sin = rope_freqs(qr, cfg.rope_theta, positions, cfg.rope_scaling)
-    k_pe = apply_rope(k_pe[..., None, :], cos, sin)[..., 0, :]
-    return c_kv, k_pe
-
-
-def mla_decode(x, p, cfg: ModelConfig, ckv_cache, kpe_cache, cache_len):
-    """Absorbed-form decode: score via latent space, cache is (c_kv, k_pe).
-
-    x: (b, 1, d).  ckv_cache: (b, S, r). kpe_cache: (b, S, qr).
-    """
-    b, _, _ = x.shape
-    h = cfg.num_heads
-    qn, qr, vd, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                     cfg.v_head_dim, cfg.kv_lora_rank)
-    cd = cfg.cdtype
-    S = ckv_cache.shape[1]
-
-    if "wq_a" in p:
-        qc = basic.dense(x, p["wq_a"], cd)
-        qc = basic.rmsnorm(qc, p["q_norm"]["scale"])
-        q = basic.dense(qc, p["wq_b"], cd).reshape(b, 1, h, qn + qr)
-    else:
-        q = basic.dense(x, p["wq"], cd).reshape(b, 1, h, qn + qr)
-    cl = jnp.asarray(cache_len)
-    pos = jnp.broadcast_to((cl - 1).reshape(-1, 1), (b, 1))
-    cos, sin = rope_freqs(qr, cfg.rope_theta, pos, cfg.rope_scaling)
-    q_nope, q_pe = q[..., :qn], q[..., qn:]
-    q_pe = apply_rope(q_pe, cos, sin)
-
-    # absorb W_UK into q: q_lat (b,1,h,r) = q_nope @ W_kb^T (per head)
-    wkb = p["wk_b"]["kernel"].astype(cd).reshape(r, h, qn)
-    q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, wkb)
-
-    scale = mla_softmax_scale(cfg)
-    s_lat = jnp.einsum("bqhr,bsr->bhqs", q_lat, ckv_cache.astype(cd),
-                       preferred_element_type=jnp.float32)
-    s_pe = jnp.einsum("bqhr,bsr->bhqs", q_pe, kpe_cache.astype(cd),
-                      preferred_element_type=jnp.float32)
-    s = (s_lat + s_pe) * scale
-    spos = jnp.arange(S)
-    clb = cl if cl.ndim else cl[None]
-    mask = spos[None, None, None, :] < clb[:, None, None, None]
-    s = jnp.where(mask, s, NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1)
-
-    # attention over latents, then up-project with absorbed W_UV
-    o_lat = jnp.einsum("bhqs,bsr->bqhr", pr.astype(cd), ckv_cache.astype(cd),
-                       preferred_element_type=jnp.float32).astype(cd)
-    wvb = p["wv_b"]["kernel"].astype(cd).reshape(r, h, vd)
-    o = jnp.einsum("bqhr,rhv->bqhv", o_lat, wvb)
-    o = o.reshape(b, 1, h * vd)
-    return basic.dense(o, p["wo"], cd)
+    return q, k, v

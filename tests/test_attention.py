@@ -1,5 +1,5 @@
 """Attention-layer unit properties: RoPE algebra, flash-vs-dense oracle,
-GQA head grouping, MLA compressed-cache equivalence.
+GQA head grouping, YaRN frequencies.
 """
 import jax
 import jax.numpy as jnp
@@ -74,41 +74,6 @@ def test_gqa_grouping_matches_explicit_repeat():
     vrep = jnp.repeat(v, 2, axis=2)
     want = att.flash_attention(q, krep, vrep, CFG)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
-
-
-def test_mla_decode_matches_full_form():
-    """Absorbed-matmul decode over the compressed (c_kv, k_pe) cache must
-    equal full-form attention over up-projected K/V."""
-    cfg = CFG.with_(use_mla=True, kv_lora_rank=24, q_lora_rank=0,
-                    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
-    p = att.init_mla(0, "attn", cfg, jnp.float32)
-    B, T = 2, 5
-    x = jax.random.normal(jax.random.key(5), (B, T, cfg.d_model))
-    pos = jnp.arange(T)[None, :]
-    q, k, v, (ckv, kpe) = att.mla_qkv(x, p, cfg, pos)
-    full = att.flash_attention(q, k, v, cfg)
-    full = full.reshape(B, T, -1)
-    from repro.nn import basic
-    full_o = basic.dense(full, p["wo"], jnp.float32)
-
-    # decode the last token against the compressed cache
-    got = att.mla_decode(x[:, T - 1:T], p, cfg, ckv, kpe, T)
-    np.testing.assert_allclose(np.asarray(got[:, 0]),
-                               np.asarray(full_o[:, T - 1]),
-                               atol=3e-5, rtol=3e-5)
-
-
-def test_decode_attention_ignores_unwritten_slots():
-    b, S, h, hd = 1, 8, 2, 16
-    ks = jax.random.split(jax.random.key(6), 3)
-    q = jax.random.normal(ks[0], (b, 1, h, hd))
-    k = jax.random.normal(ks[1], (b, S, h, hd))
-    v = jax.random.normal(ks[2], (b, S, h, hd))
-    o1 = att.decode_attention(q, k, v, 3, CFG.with_(num_kv_heads=2, num_heads=2))
-    k2 = k.at[:, 3:].set(99.0)
-    v2 = v.at[:, 3:].set(-99.0)
-    o2 = att.decode_attention(q, k2, v2, 3, CFG.with_(num_kv_heads=2, num_heads=2))
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
 
 
 def test_yarn_frequencies_and_softmax_scale_match_the_equations():

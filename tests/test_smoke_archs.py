@@ -79,19 +79,3 @@ def test_one_federated_train_step(arch):
         jax.tree_util.tree_map(lambda a, b: a - b, y2, y), 0.0)
     assert moved > 0.0
 
-
-@pytest.mark.parametrize("arch", ["mixtral-8x7b", "xlstm-350m",
-                                  "deepseek-v2-236b", "whisper-large-v3",
-                                  "jamba-v0.1-52b"])
-def test_one_decode_step(arch):
-    cfg = reduced_config(get_config(arch))
-    params = dlm.init_model(cfg, 0)
-    cache = dlm.init_cache(cfg, 2, 8)
-    if cfg.is_encoder_decoder:
-        cache["cross"] = dlm.build_cross_cache(
-            params, cfg, jnp.zeros((2, cfg.encoder_seq_len, cfg.d_model)))
-    toks = jnp.ones((2, 1), jnp.int32)
-    logits, cache = dlm.decode_step(params, cfg, cache, toks)
-    assert logits.shape == (2, 1, cfg.vocab_size)
-    assert bool(jnp.isfinite(logits).all())
-    assert int(cache["cache_len"]) == 1

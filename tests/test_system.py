@@ -1,5 +1,5 @@
 """End-to-end system behaviour: federated FedPT training actually learns,
-decode matches prefill for every family, and the serving path generates.
+and the training forward is causal for every family and architecture.
 """
 import jax
 import jax.numpy as jnp
@@ -9,9 +9,13 @@ import pytest
 import repro.core.partition as part
 from repro.core import fedpt
 from repro.data import synthetic as syn
-from repro.configs.base import ModelConfig
+from repro.configs import load_all, ARCH_IDS
+from repro.configs.base import ModelConfig, get_config
+from repro.launch.train import reduced_config
 from repro.models import decoder_lm as dlm
 from repro.models import paper_models as pm
+
+load_all()
 
 
 def test_fedpt_learns_synthetic_emnist():
@@ -41,48 +45,57 @@ def test_fedpt_learns_synthetic_emnist():
     assert acc > 0.10  # >6x chance after 8 rounds
 
 
-@pytest.mark.parametrize("family_cfg", [
-    dict(name="t-dense", family="dense"),
-    dict(name="t-swa", family="dense", sliding_window=4),
-    dict(name="t-moe", family="moe", num_experts=4, num_experts_per_tok=2,
-         moe_capacity_factor=8.0),
-    dict(name="t-mla", family="dense", use_mla=True, kv_lora_rank=32,
-         q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
-         v_head_dim=16),
-    dict(name="t-hybrid", family="hybrid", num_layers=4, attn_period=4,
-         use_rope=False),
-    dict(name="t-ssm", family="ssm", num_layers=4, d_ff=0, slstm_every=4,
-         use_rope=False, tie_embeddings=True),
-])
-def test_decode_matches_prefill(family_cfg):
-    """The strongest serving invariant: token-by-token decode with caches
-    reproduces the teacher-forced forward pass."""
-    kw = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
-              d_ff=128, vocab_size=64, compute_dtype="float32")
-    kw.update(family_cfg)
-    cfg = ModelConfig(**kw)
-    p = dlm.init_model(cfg, 0)
-    B, T = 2, 8
-    toks = jax.random.randint(jax.random.key(1), (B, T), 0, cfg.vocab_size)
-    cache = dlm.init_cache(cfg, B, 16)
-    outs = []
-    for t in range(T):
-        lg, cache = dlm.decode_step(p, cfg, cache, toks[:, t:t + 1])
-        outs.append(lg[:, 0])
-    dec = jnp.stack(outs, 1)
-    full, _ = dlm.forward(p, cfg, toks)
-    np.testing.assert_allclose(np.asarray(dec), np.asarray(full),
-                               atol=2e-4, rtol=2e-4)
+FAMILY_CFGS = {
+    "t-dense": dict(family="dense"),
+    "t-swa": dict(family="dense", sliding_window=4),
+    "t-moe": dict(family="moe", num_experts=4, num_experts_per_tok=2,
+                  moe_capacity_factor=8.0),
+    "t-mla": dict(family="dense", use_mla=True, kv_lora_rank=32,
+                  q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                  v_head_dim=16),
+    "t-hybrid": dict(family="hybrid", num_layers=4, attn_period=4,
+                     use_rope=False),
+    "t-ssm": dict(family="ssm", num_layers=4, d_ff=0, slstm_every=4,
+                  use_rope=False, tie_embeddings=True),
+}
 
 
-def test_generation_runs_and_is_deterministic():
-    from repro.launch.serve import generate
-    cfg = ModelConfig(name="g", family="dense", num_layers=2, d_model=64,
-                      num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=64,
-                      compute_dtype="float32")
+def _causal_case(case):
+    """(cfg, batch, seq) of one case: a family config at the small sizes
+    (batch 2, 8 tokens), or a reduced architecture (batch 2, 16 tokens)
+    whose capacity-limited MoE runs at a factor that drops no slot."""
+    if case in FAMILY_CFGS:
+        kw = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
+                  d_ff=128, vocab_size=64, compute_dtype="float32")
+        kw.update(FAMILY_CFGS[case])
+        return ModelConfig(name=case, **kw), 2, 8
+    cfg = reduced_config(get_config(case))
+    if cfg.num_experts and cfg.moe_capacity_factor > 0:
+        cfg = cfg.with_(moe_capacity_factor=8.0)
+    return cfg, 2, 16
+
+
+@pytest.mark.parametrize("case", list(FAMILY_CFGS) + list(ARCH_IDS))
+def test_forward_is_causal(case):
+    """Perturbing every token from position t on leaves the logits before
+    t as they were, and moves the logits at t."""
+    cfg, B, T = _causal_case(case)
     p = dlm.init_model(cfg, 0)
-    prompt = jnp.ones((2, 4), jnp.int32)
-    a = generate(p, cfg, prompt, steps=8, max_len=16)
-    b = generate(p, cfg, prompt, steps=8, max_len=16)
-    assert a.shape == (2, 12)
-    assert bool((a == b).all())
+    keys = jax.random.split(jax.random.key(1), 3)
+    toks = jax.random.randint(keys[0], (B, T), 0, cfg.vocab_size)
+    kw, P = {}, 0
+    if cfg.family == "vlm":
+        P = cfg.num_prefix_tokens
+        kw["prefix_embeds"] = jax.random.normal(keys[1], (B, P, 1152))
+    if cfg.is_encoder_decoder:
+        kw["encoder_embeds"] = jax.random.normal(
+            keys[2], (B, cfg.encoder_seq_len, cfg.d_model))
+    fwd = jax.jit(lambda tk: dlm.forward(p, cfg, tk, **kw)[0])
+    full = np.asarray(fwd(toks))
+    assert full.shape == (B, P + T, cfg.vocab_size)
+    for t in (1, T // 2, T - 1):
+        moved = toks.at[:, t:].set((toks[:, t:] + 1) % cfg.vocab_size)
+        got = np.asarray(fwd(moved))
+        np.testing.assert_allclose(got[:, :P + t], full[:, :P + t],
+                                   atol=1e-5, rtol=1e-5)
+        assert np.abs(got[:, P + t] - full[:, P + t]).max() > 1e-4
