@@ -14,11 +14,11 @@ import jax.numpy as jnp
 
 from repro.kernels import agg_tail as _agg
 from repro.kernels import dp_clip as _dp
+from repro.kernels import flash_attention as _fa
 from repro.kernels import moe_gmm as _gmm
 from repro.kernels import quantize as _q
 from repro.kernels import ref as _ref
 from repro.kernels import seed_reconstruct as _sr
-from repro.kernels import swa_attention as _swa
 
 
 def use_kernels() -> bool:
@@ -50,12 +50,50 @@ def use_kernels() -> bool:
 AGG_FUSE_THRESHOLD = 4 << 20
 
 
-@functools.partial(jax.jit, static_argnames=("window", "causal", "bq", "bk"))
-def swa_attention(q, k, v, window: int = 0, causal: bool = True,
-                  bq: int = 128, bk: int = 128):
-    """(B, H, S, D) sliding-window flash attention (see swa_attention.py)."""
-    return _swa.swa_attention(q, k, v, window=window, causal=causal,
-                              bq=bq, bk=bk, interpret=not use_kernels())
+# ---------------------------------------------------------------------------
+# Causal self-attention through the flash kernels of
+# kernels/flash_attention.py, forward and backward.
+
+
+def flash_block(seq: int) -> int:
+    """The kernels' query and key block for a sequence of ``seq``: 256
+    where it divides, else 128; 0 where neither does (no kernel)."""
+    for block in (256, 128):
+        if seq % block == 0:
+            return block
+    return 0
+
+
+def flash_tiles(seq: int, d: int, dv: int) -> bool:
+    """Whether the flash kernels tile a sequence of ``seq`` with q/k head
+    dim ``d`` and v head dim ``dv``."""
+    return bool(flash_block(seq)) and d % 8 == 0 and dv % 8 == 0 \
+        and max(d, dv) <= 256
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def causal_attention(q, k, v, scale: float):
+    """Causal softmax attention of (B, H, S, D) q and k and (B, H, S, Dv)
+    v at softmax scale ``scale`` -> (B, H, S, Dv); S a multiple of 128.
+    The Pallas kernels on TPU, interpreted elsewhere."""
+    return _causal_fwd(q, k, v, scale)[0]
+
+
+def _causal_fwd(q, k, v, scale):
+    block = flash_block(q.shape[2])
+    o, lse = _fa.flash_fwd(q, k, v, scale=scale, bq=block, bk=block,
+                           interpret=not use_kernels())
+    return o, (q, k, v, o, lse)
+
+
+def _causal_bwd(scale, res, do):
+    q = res[0]
+    block = flash_block(q.shape[2])
+    return _fa.flash_bwd(*res, do, scale=scale, bq=block, bk=block,
+                         interpret=not use_kernels())
+
+
+causal_attention.defvjp(_causal_fwd, _causal_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("clip_norm",))

@@ -11,14 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def swa_attention_ref(q, k, v, window: int, causal: bool = True):
-    """Dense sliding-window attention oracle.
-
-    q, k, v: (B, H, S, D). window: number of past positions visible
-    (window <= 0 means full causal attention). Returns (B, H, S, D) f32.
-    """
-    B, H, S, D = q.shape
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+def _attention_scores(q, k, window: int, causal: bool, scale):
+    """Masked scaled scores (B, H, S, S) f32, -1e30 where hidden."""
+    S, D = q.shape[2], q.shape[3]
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     qpos = jnp.arange(S)[:, None]
@@ -28,9 +25,27 @@ def swa_attention_ref(q, k, v, window: int, causal: bool = True):
         mask = qpos >= kpos
     if window > 0:
         mask = mask & (qpos - kpos < window)
-    s = jnp.where(mask[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
+    return jnp.where(mask[None, None], s, -1e30)
+
+
+def attention_ref(q, k, v, window: int = 0, causal: bool = True,
+                  scale=None):
+    """Dense softmax attention oracle.
+
+    q, k: (B, H, S, D); v: (B, H, S, Dv). window: number of past
+    positions visible (window <= 0 means full causal attention). scale:
+    the softmax scale (default D^-1/2). Returns (B, H, S, Dv) f32.
+    """
+    p = jax.nn.softmax(_attention_scores(q, k, window, causal, scale),
+                       axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
+
+
+def attention_lse_ref(q, k, window: int = 0, causal: bool = True,
+                      scale=None):
+    """Each query's log-sum-exp of its visible scores, (B, H, S) f32."""
+    return jax.nn.logsumexp(_attention_scores(q, k, window, causal, scale),
+                            axis=-1)
 
 
 def dp_clip_accumulate_ref(acc, x, clip_norm: float):

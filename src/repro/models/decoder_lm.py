@@ -177,6 +177,7 @@ def sinusoid_pos(positions, d_model, dtype):
 # MLA/MoE decoder that a device trace puts time on; the no-drop MoE
 # layer's own are in nn/moe.py
 SCOPE_MLA = "fedpt/mla"
+SCOPE_MLA_ATTN = "fedpt/mla_attn"
 SCOPE_LEAD = "fedpt/lead_dense"
 SCOPE_HEAD = "fedpt/head"
 
@@ -224,11 +225,12 @@ def _apply_slot(x, sp, cfg: ModelConfig, slot: Slot, positions, stats,
         if cfg.use_mla:
             with jax.named_scope(SCOPE_MLA):
                 q, k, v = attn_lib.mla_qkv(h, sp["attn"], cfg, positions)
-                o = attn_lib.flash_attention(
-                    q, k, v, cfg.with_(sliding_window=0), causal=causal,
-                    prefix_len=prefix_len,
-                    scale=attn_lib.mla_softmax_scale(cfg))
-                o = o.reshape(o.shape[:2] + (o.shape[2] * o.shape[3],))
+                with jax.named_scope(SCOPE_MLA_ATTN):
+                    o = attn_lib.flash_attention(
+                        q, k, v, cfg.with_(sliding_window=0), causal=causal,
+                        prefix_len=prefix_len,
+                        scale=attn_lib.mla_softmax_scale(cfg))
+                    o = o.reshape(o.shape[:2] + (o.shape[2] * o.shape[3],))
                 o = basic.dense(o, sp["attn"]["wo"], cd)
         else:
             q, k, v = attn_lib.qkv_project(h, sp["attn"], cfg)

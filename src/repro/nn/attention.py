@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops as kernel_ops
 from repro.nn import basic
 from repro.configs.base import ModelConfig
 
@@ -81,15 +82,21 @@ def yarn_inv_freq(dim: int, theta: float, scaling) -> np.ndarray:
     return (interp * (1 - m) + extrap * m).astype(np.float32)
 
 
-def mla_softmax_scale(cfg: ModelConfig):
+def softmax_scale(head_dim: int) -> float:
+    """head_dim^-1/2, rounded to float32 as the scores are."""
+    return float(np.float32(1.0) / np.sqrt(np.float32(head_dim)))
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
     """MLA's softmax scale: (qk_nope + qk_rope)^-1/2, times
     yarn_mscale(s, mscale_all_dim)^2 under YaRN."""
-    scale = 1.0 / jnp.sqrt(cfg.qk_nope_head_dim
-                           + cfg.qk_rope_head_dim).astype(jnp.float32)
+    scale = np.float32(softmax_scale(cfg.qk_nope_head_dim
+                                     + cfg.qk_rope_head_dim))
     rs = cfg.rope_scaling
     if rs is not None and rs.mscale_all_dim:
-        scale = scale * yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
-    return scale
+        scale = scale * np.float32(
+            yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2)
+    return float(scale)
 
 
 def apply_rope(x, cos, sin):
@@ -130,10 +137,12 @@ def qkv_project(x, p, cfg: ModelConfig):
 # Chunked (flash-style) causal attention.
 #
 # Memory stays O(seq * chunk) instead of O(seq^2): we scan over KV chunks
-# carrying the online-softmax running (max, sum, acc). Sliding windows skip
-# out-of-window chunks entirely via lax.cond-free masking (masked chunks
-# contribute exp(-inf)=0; XLA still executes them, the Pallas kernel in
-# kernels/swa_attention.py skips them structurally on TPU).
+# carrying the online-softmax running (max, sum, acc). Masked positions
+# contribute exp(-inf)=0, but XLA still computes every chunk, the ones the
+# causal mask or a window hides entirely too. On one TPU, plain causal
+# self-attention takes the flash kernels of kernels/flash_attention.py
+# instead (forward and backward, masked blocks skipped, scores kept in
+# VMEM): see ``takes_kernel``.
 
 
 def _attend_chunk(q, k, v, qpos, kpos, window: int, softcap: float, scale,
@@ -154,6 +163,18 @@ def _attend_chunk(q, k, v, qpos, kpos, window: int, softcap: float, scale,
     return jnp.where(mask[None, None], s, NEG_INF)
 
 
+def takes_kernel(sq: int, skv: int, hd: int, dv: int, cfg: ModelConfig,
+                 causal: bool, prefix_len: int) -> bool:
+    """Whether :func:`flash_attention` runs the flash kernels
+    (``kernel_ops.causal_attention``) rather than the chunked scan: on
+    one TPU, for causal self-attention with no window, softcap or
+    bidirectional prefix, over a sequence and head dims the kernels
+    tile."""
+    return (kernel_ops.use_kernels() and causal and cfg.sliding_window == 0
+            and not cfg.attn_logit_softcap and prefix_len == 0 and sq == skv
+            and kernel_ops.flash_tiles(sq, hd, dv))
+
+
 def flash_attention(q, k, v, cfg: ModelConfig, chunk: int = 512,
                     causal: bool = True, prefix_len: int = 0,
                     scale: Optional[float] = None):
@@ -170,7 +191,7 @@ def flash_attention(q, k, v, cfg: ModelConfig, chunk: int = 512,
     dv = v.shape[3]
     rep = h // kvh
     if scale is None:
-        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+        scale = softmax_scale(hd)
     window = cfg.sliding_window
 
     qh = q.transpose(0, 2, 1, 3)  # b,h,sq,hd
@@ -179,6 +200,9 @@ def flash_attention(q, k, v, cfg: ModelConfig, chunk: int = 512,
     if rep > 1:
         kh = jnp.repeat(kh, rep, axis=1)
         vh = jnp.repeat(vh, rep, axis=1)
+    if takes_kernel(sq, skv, hd, dv, cfg, causal, prefix_len):
+        out = kernel_ops.causal_attention(qh, kh, vh, scale)
+        return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
     nchunks = max(1, (skv + chunk - 1) // chunk)
     pad = nchunks * chunk - skv

@@ -72,6 +72,39 @@ def test_program_matches_the_plain_reference(bench_model):
                                    rtol=2e-5, atol=2e-5 * scale, err_msg=k)
 
 
+def test_program_through_the_flash_kernels_matches_the_reference(
+        bench_model, monkeypatch):
+    """The same comparison with MLA's attention on the route one TPU
+    takes (``ops.causal_attention``, the kernels interpreted here), under
+    the layers' remat, at a sequence of 128 the kernels tile."""
+    from repro.nn import attention as att
+    mod, cfg = bench_model
+    cfg = dict(mod.small(cfg), seq_len=128)
+    params = mod.init_params(cfg, jax.random.key(3))
+    flat = mod.common.flatten(params)
+    batch = {"tokens": jnp.asarray(
+        mod.TASK.test(mod.TASK.make(cfg, 2))["tokens"][:2])}
+    routed = []
+
+    def takes_kernel(sq, skv, hd, dv, *_):
+        routed.append((sq, hd, dv))
+        return True
+
+    monkeypatch.setattr(att, "takes_kernel", takes_kernel)
+    with jax.default_matmul_precision("highest"):
+        lp, gp = jax.value_and_grad(
+            lambda p: mod.program_loss()(p, batch)[0])(params)
+        lr, gr = jax.value_and_grad(
+            lambda p: mod.reference_loss(p, batch, cfg, jnp.float32))(flat)
+    assert routed and set(routed) == {(128, 192, 128)}
+    np.testing.assert_allclose(float(lp), float(lr), rtol=2e-5)
+    gp = mod.common.flatten(gp)
+    for k, g in gr.items():
+        scale = float(np.abs(g).max())
+        np.testing.assert_allclose(np.asarray(gp[k]), np.asarray(g),
+                                   rtol=2e-5, atol=2e-5 * scale, err_msg=k)
+
+
 def test_routing_counters_count_every_held_slot(bench_model):
     """The loss's counters: each MoE layer's top-6 slots that land on the
     held experts, summed over the layers, as the reference's routing

@@ -2,6 +2,8 @@
 asserting allclose against the pure-jnp oracles in kernels/ref.py, plus
 hypothesis property sweeps.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,10 +12,11 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import sanitize as sanitize_lib
 from repro.kernels import agg_tail
+from repro.kernels import ops as kernel_ops
 from repro.kernels import ref
+from repro.kernels.flash_attention import flash_bwd, flash_fwd
 from repro.kernels.dp_clip import clip_accumulate, sumsq
 from repro.kernels.seed_reconstruct import seed_reconstruct
-from repro.kernels.swa_attention import swa_attention
 
 
 # ---------------------------------------------------------------------------
@@ -34,9 +37,9 @@ def test_swa_attention_matches_oracle(B, H, S, D, window, dtype):
     q = jax.random.normal(ks[0], (B, H, S, D), jnp.float32).astype(dtype)
     k = jax.random.normal(ks[1], (B, H, S, D), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (B, H, S, D), jnp.float32).astype(dtype)
-    out = swa_attention(q, k, v, window=window, interpret=True)
-    want = ref.swa_attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
-                                 v.astype(jnp.float32), window)
+    out, _ = flash_fwd(q, k, v, window=window, interpret=True)
+    want = ref.attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
+                             v.astype(jnp.float32), window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want), atol=tol, rtol=tol)
@@ -52,8 +55,8 @@ def test_swa_attention_property_sweep(B, H, S, window):
     q = jax.random.normal(ks[0], (B, H, S, D))
     k = jax.random.normal(ks[1], (B, H, S, D))
     v = jax.random.normal(ks[2], (B, H, S, D))
-    out = swa_attention(q, k, v, window=window, bq=64, bk=64, interpret=True)
-    want = ref.swa_attention_ref(q, k, v, window)
+    out, _ = flash_fwd(q, k, v, window=window, bq=64, bk=64, interpret=True)
+    want = ref.attention_ref(q, k, v, window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=3e-5,
                                rtol=3e-5)
 
@@ -66,13 +69,134 @@ def test_swa_window_actually_windows():
     q = jax.random.normal(ks[0], (B, H, S, D))
     k = jax.random.normal(ks[1], (B, H, S, D))
     v = jax.random.normal(ks[2], (B, H, S, D))
-    out1 = swa_attention(q, k, v, window=W, interpret=True)
+    out1, _ = flash_fwd(q, k, v, window=W, interpret=True)
     # perturb keys/values far outside the window of the last query
     k2 = k.at[:, :, :S - W - 8].set(0.0)
     v2 = v.at[:, :, :S - W - 8].set(0.0)
-    out2 = swa_attention(q, k2, v2, window=W, interpret=True)
+    out2, _ = flash_fwd(q, k2, v2, window=W, interpret=True)
     np.testing.assert_allclose(np.asarray(out1[:, :, -1]),
                                np.asarray(out2[:, :, -1]), atol=1e-6)
+
+
+# The causal flash kernels as training runs them (ops.causal_attention,
+# interpreted off-TPU): MLA's head dims (192 for q/k, 128 for v) and a
+# 128/128 case, one to three blocks of queries and keys, with and
+# without the round engine's vmap over clients.
+
+FLASH_CASES = [(S, D, DV, batched) for S in (128, 256, 384)
+               for D, DV in ((192, 128), (128, 128))
+               for batched in (False, True)]
+FLASH_SCALE = 0.1147           # MLA's YaRN-scaled scale, not D^-1/2
+
+
+def _flash_inputs(S, D, DV, batched):
+    ks = jax.random.split(jax.random.key(S + D + DV + batched), 4)
+    lead = (2, 1, 2) if batched else (1, 2)
+    q, k = (jax.random.normal(kk, lead + (S, D)) for kk in ks[:2])
+    v, w = (jax.random.normal(kk, lead + (S, DV)) for kk in ks[2:])
+    return q, k, v, w
+
+
+def _maybe_vmap(fn, batched):
+    return jax.vmap(fn) if batched else fn
+
+
+@pytest.mark.interpret
+@pytest.mark.parametrize("S,D,DV,batched", FLASH_CASES)
+def test_flash_forward_and_lse_match_oracle(S, D, DV, batched):
+    q, k, v, _ = _flash_inputs(S, D, DV, batched)
+    block = kernel_ops.flash_block(S)
+    out, lse = _maybe_vmap(lambda q, k, v: flash_fwd(
+        q, k, v, scale=FLASH_SCALE, bq=block, bk=block, interpret=True),
+        batched)(q, k, v)
+    want = _maybe_vmap(lambda q, k, v: ref.attention_ref(
+        q, k, v, scale=FLASH_SCALE), batched)(q, k, v)
+    want_lse = _maybe_vmap(lambda q, k: ref.attention_lse_ref(
+        q, k, scale=FLASH_SCALE), batched)(q, k)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    # lse is (B*H, S, lanes), every lane the same
+    lse = np.asarray(lse)
+    np.testing.assert_array_equal(lse, np.broadcast_to(lse[..., :1],
+                                                       lse.shape))
+    np.testing.assert_allclose(lse[..., 0].reshape(want_lse.shape),
+                               np.asarray(want_lse), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.interpret
+@pytest.mark.parametrize("S,D,DV,batched", FLASH_CASES)
+def test_flash_grads_match_oracle(S, D, DV, batched):
+    """dq, dk, dv of ``ops.causal_attention`` (both backward kernels)
+    against ``jax.grad`` of the dense oracle."""
+    q, k, v, w = _flash_inputs(S, D, DV, batched)
+
+    def grads(attn):
+        def loss(q, k, v, w):
+            return jnp.sum(attn(q, k, v) * w)
+        return _maybe_vmap(jax.grad(loss, argnums=(0, 1, 2)), batched)(
+            q, k, v, w)
+
+    got = grads(lambda q, k, v: kernel_ops.causal_attention(
+        q, k, v, FLASH_SCALE))
+    want = grads(lambda q, k, v: ref.attention_ref(q, k, v,
+                                                   scale=FLASH_SCALE))
+    for g, gw in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(gw),
+                                   atol=5e-5, rtol=5e-5)
+
+
+def test_flash_kernels_contract_at_ambient_precision():
+    """Every contraction inside the three kernels (2 forward, 3 in dq, 4
+    in dk/dv) takes the ambient ``jax.default_matmul_precision``: the
+    cell's ``highest``."""
+    qk = jax.ShapeDtypeStruct((1, 2, 256, 192), jnp.float32)
+    v = jax.ShapeDtypeStruct((1, 2, 256, 128), jnp.float32)
+    lse = jax.ShapeDtypeStruct((2, 256, 128), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        text = str(jax.make_jaxpr(
+            lambda q, k, v, lse: (flash_fwd(q, k, v, scale=0.1),
+                                  flash_bwd(q, k, v, v, lse, v, scale=0.1)))(
+            qk, qk, v, lse))
+    assert text.count("dot_general[") == 9
+    assert len(re.findall(r"precision=\(Precision.HIGHEST, "
+                          r"Precision.HIGHEST\)", text)) == 9
+
+
+ROUTE_CFG = dict(name="r", family="dense", num_layers=1, d_model=32,
+                 num_heads=2, num_kv_heads=2, d_ff=64, vocab_size=8,
+                 compute_dtype="float32")
+
+
+@pytest.mark.parametrize("case,tpu,S,D,DV,flags,kernel", [
+    ("mla-dims", True, 256, 192, 128, {}, True),
+    ("square", True, 128, 128, 128, {}, True),
+    ("off-tpu", False, 256, 192, 128, {}, False),
+    ("non-causal", True, 256, 128, 128, {"causal": False}, False),
+    ("window", True, 256, 128, 128, {"sliding_window": 64}, False),
+    ("softcap", True, 256, 128, 128, {"attn_logit_softcap": 30.0}, False),
+    ("prefix", True, 256, 128, 128, {"prefix_len": 16}, False),
+    ("cross", True, 256, 128, 128, {"skv": 128}, False),
+    ("ragged-seq", True, 200, 128, 128, {}, False),
+    ("wide-head", True, 256, 512, 128, {}, False),
+])
+def test_flash_attention_route(monkeypatch, case, tpu, S, D, DV, flags,
+                               kernel):
+    """``nn/attention.flash_attention`` takes the flash kernels exactly
+    when everything it observes allows them, else the chunked scan."""
+    from repro.configs.base import ModelConfig
+    from repro.nn import attention as att
+    monkeypatch.setattr(kernel_ops, "use_kernels", lambda: tpu)
+    flags = dict(flags)
+    skv = flags.pop("skv", S)
+    call = {k: flags.pop(k) for k in ("causal", "prefix_len") if k in flags}
+    cfg = ModelConfig(**ROUTE_CFG, **flags)
+    q = jax.ShapeDtypeStruct((1, S, 2, D), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, skv, 2, D), jnp.float32)
+    v = jax.ShapeDtypeStruct((1, skv, 2, DV), jnp.float32)
+    jaxpr = str(jax.make_jaxpr(lambda q, k, v: att.flash_attention(
+        q, k, v, cfg, **call))(q, k, v))
+    assert ("pallas_call" in jaxpr) == kernel
+    assert ("scan" in jaxpr) == (not kernel)
 
 
 # ---------------------------------------------------------------------------

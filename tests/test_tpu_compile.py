@@ -5,9 +5,9 @@ that is only described (``get_topology_desc``): it refuses tiling, SMEM
 and memory violations that interpret mode never sees. Each case lowers
 one kernel at the real width of the CIFAR ResNet-18 trainable slice
 (2,914,634 parameters in 2,874 align-blocks) with K=16 client rows (the
-grouped matmul at DeepSeek-V2-Lite's expert widths, float32 at
-``highest``), and checks that the compiled program calls the kernel by
-its name.
+grouped matmul at DeepSeek-V2-Lite's expert widths, and its causal flash
+attention at its MLA widths, float32 at ``highest``), and checks that the
+compiled program calls the kernel by its name.
 
 The kernel functions are called directly (not through ``kernels/ops``),
 because the dispatchers choose the ref path whenever the trace is not for
@@ -20,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 import repro.core.partition as part
 from repro.core import flat as flat_lib
-from repro.kernels import agg_tail, dp_clip, moe_gmm, quantize
+from repro.kernels import agg_tail, dp_clip, flash_attention, moe_gmm, quantize
 from repro.models import paper_models as pm
 
 K = 16
@@ -91,6 +91,21 @@ def _case(name, layout):
     gmm_args = [((rows, d), f32), ((g, d, ff), f32), ((g,), jnp.int32)]
     if name == "moe_gmm":
         return moe_gmm.gmm, gmm_args, name
+    if name.startswith("flash_"):
+        # MLA's attention in the DeepSeek-V2-Lite cell: the cohort's 8 x
+        # 16 heads over 1,024 tokens, q/k 192 wide, v 128, blocks of 256
+        b, s, dk, dv = 8 * 16, 1024, 192, 128
+        kw = dict(scale=0.1147, bq=256, bk=256)
+        qkv = [((1, b, s, dk), f32), ((1, b, s, dk), f32),
+               ((1, b, s, dv), f32)]
+        if name == "flash_fwd":
+            return (lambda q, k, v: flash_attention.flash_fwd(
+                q, k, v, **kw)[0], qkv, name)
+        grads = slice(0, 1) if name == "flash_dq" else slice(1, 3)
+        return (lambda q, k, v, o, lse, do: flash_attention.flash_bwd(
+            q, k, v, o, lse, do, **kw)[grads],
+            qkv + [((1, b, s, dv), f32), ((b, s, flash_attention.LANES), f32),
+                   ((1, b, s, dv), f32)], name)
     assert name == "moe_gmm_transposed"
     return (lambda a, b, c: moe_gmm.gmm(a, b, c, transpose_rhs=True),
             [((rows, ff), f32), ((g, d, ff), f32), ((g,), jnp.int32)],
@@ -100,7 +115,7 @@ def _case(name, layout):
 @pytest.mark.parametrize("name", [
     "agg_tail_stats", "agg_tail_pack", "agg_tail_apply",
     "fake_quantize_flat", "clip_flat", "clip_flat_vmapped", "moe_gmm",
-    "moe_gmm_transposed"])
+    "moe_gmm_transposed", "flash_fwd", "flash_dq", "flash_dkv"])
 def test_kernel_compiles_for_v5e(name, layout, one_chip,
                                  no_persistent_cache):
     fn, shapes, kernel = _case(name, layout)
